@@ -11,7 +11,7 @@ Classic three-state machine (closed → open → half-open → closed):
   it for another cooldown.
 
 Used by hashgraph/accel.py to gate the device sweep path: a flapping
-accelerator (tunnel resets, OOMs) degrades to the oracle for a cooldown
+accelerator (runtime errors, OOMs) degrades to the oracle for a cooldown
 instead of eating a dispatch failure per flush, and — unlike a sticky
 kill-switch — the probe sweep re-enables the device once it recovers.
 

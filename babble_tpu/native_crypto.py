@@ -1,16 +1,24 @@
 """ctypes loader for the native batch crypto library (native/secp256k1.cc).
 
-The shared object is built lazily with g++ on first use and cached next to
-the source; every consumer degrades gracefully to the OpenSSL / pure-Python
-paths in babble_tpu.crypto.keys when no compiler or prebuilt library is
-available. The batch entry points exist so the gossip sync path can verify
-a whole sync's worth of event signatures in ONE foreign call (reference hot
-loop: src/hashgraph/hashgraph.go:672-687 verifying per event).
+The shared object is built with g++ on first use, next to the source, under
+a name that carries the source's content hash
+(``libbabble_crypto.<sha256[:16]>.so``): a copied or checked-out tree has
+no meaningful mtimes, and a library built from other source can never be
+loaded for this one. The ``.so`` is never committed. Consumers degrade to
+the OpenSSL / pure-Python paths in babble_tpu.crypto.keys when no compiler
+is available — ~100x slower on the path every event takes, so a failed
+build is logged as a warning, and chip_smoke.py refuses to run without
+the library. The batch entry points exist so the gossip sync path can
+verify a whole sync's worth of event signatures in ONE foreign call
+(reference hot loop: src/hashgraph/hashgraph.go:672-687 verifying per
+event).
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -21,9 +29,8 @@ logger = logging.getLogger(__name__)
 
 _PKG_DIR = os.path.dirname(os.path.abspath(__file__))
 _REPO_ROOT = os.path.dirname(_PKG_DIR)
-_SO_NAME = "libbabble_crypto.so"
 
-# Source / shared-object search order:
+# Source search order:
 # 1. repo layout (native/ next to the package — development checkouts);
 # 2. installed package data (babble_tpu/_native/, shipped in the wheel;
 #    the wheel build pre-compiles the .so there when a compiler exists).
@@ -33,17 +40,18 @@ _SRC_CANDIDATES = [
 ]
 _SRC = next((p for p in _SRC_CANDIDATES if os.path.exists(p)),
             _SRC_CANDIDATES[0])
-# Build output goes next to the source when that directory is writable
-# (dev checkouts, wheel builds), else to a per-user cache — site-packages
-# is often read-only at runtime.
-_SO = os.path.join(os.path.dirname(_SRC), _SO_NAME)
-_SO_FALLBACK = os.path.join(
-    os.path.expanduser("~"), ".cache", "babble_tpu", "native", _SO_NAME
-)
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 _lock = threading.Lock()
+
+
+def so_name(src_path: str) -> str:
+    """The library's file name for a given source file: keyed on the
+    source's CONTENT (setup.py's wheel build uses the same rule)."""
+    with open(src_path, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return f"libbabble_crypto.{digest}.so"
 
 
 def _build_at(so_path: str) -> bool:
@@ -51,60 +59,51 @@ def _build_at(so_path: str) -> bool:
     # POSIX, so concurrent node processes never dlopen a half-written .so.
     tmp = f"{so_path}.tmp.{os.getpid()}"
     try:
-        os.makedirs(os.path.dirname(so_path), exist_ok=True)
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", tmp, _SRC],
             check=True,
             capture_output=True,
-            timeout=60,
+            timeout=120,
         )
         os.replace(tmp, so_path)
-        return True
     except (OSError, subprocess.SubprocessError) as err:
-        logger.info("native crypto build unavailable at %s: %s",
-                    so_path, err)
+        logger.warning(
+            "native crypto build failed at %s (%s): signature checks fall "
+            "back to OpenSSL / pure Python, ~100x slower", so_path, err,
+        )
         try:
             os.unlink(tmp)
         except OSError:
             pass
         return False
-
-
-def _build() -> bool:
-    global _SO
-    if _build_at(_SO):
-        return True
-    # read-only install dir: build into the user cache instead
-    if _SO != _SO_FALLBACK and _build_at(_SO_FALLBACK):
-        _SO = _SO_FALLBACK
-        return True
-    return False
-
-
-def _stale(so_path: str) -> bool:
-    return not os.path.exists(so_path) or (
-        os.path.exists(_SRC)
-        and os.path.getmtime(_SRC) > os.path.getmtime(so_path)
-    )
+    # libraries built from older source are dead weight now
+    for old in glob.glob(
+        os.path.join(os.path.dirname(so_path), "libbabble_crypto.*.so")
+    ):
+        if old != so_path:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return True
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried, _SO
+    global _lib, _tried
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        if _stale(_SO) and not _stale(_SO_FALLBACK):
-            # a prior run already built into the user cache
-            _SO = _SO_FALLBACK
-        if _stale(_SO):
-            if not (os.path.exists(_SRC) and _build()):
-                if not os.path.exists(_SO):
-                    return None
+        if not os.path.exists(_SRC):
+            logger.warning("native crypto source missing at %s", _SRC)
+            return None
+        so = os.path.join(os.path.dirname(_SRC), so_name(_SRC))
+        if not os.path.exists(so) and not _build_at(so):
+            return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError as err:
-            logger.info("native crypto load failed: %s", err)
+            logger.warning("native crypto load failed: %s", err)
             return None
         lib.bt_has_native.restype = ctypes.c_int
         lib.bt_verify_batch.argtypes = [

@@ -71,7 +71,8 @@ from babble_tpu.ops.voting import (
 )
 
 # CPU XLA ignores buffer donation (it still runs correctly, copy-on-write);
-# the per-compile warning would otherwise spam every CPU-fallback node.
+# the per-compile warning would otherwise spam every node under a cpu pin.
+# On a TPU donation is real (chip_smoke.py checks the inputs are deleted).
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable"
 )

@@ -11,9 +11,11 @@ app proxy; a dummy chat-app client process attaches to each. Ports:
 
 Usage:  python demo/testnet.py [n_nodes] [--signal] [--accelerator]
                                [--async] [--gateway]
-With --accelerator every node runs device consensus sweeps and the whole
-testnet shares one admission-control slot domain (co-located processes
-must not convoy their sweeps on the single device). With --async every
+With --accelerator every node process runs device consensus sweeps on a
+chip of its OWN (a chip belongs to one process at a time): node i is pinned
+to chip i, and more node processes than chips is refused — many validators
+on one chip is the in-process path (bench.bench_16node_threads,
+chip_smoke.py). With --async every
 node runs the event-driven gossip engine + binary codec (docs/gossip.md)
 instead of the threaded JSON transport — mixed testnets work too. With
 --gateway a sharded light-client gateway (babble_tpu.client.gateway)
@@ -82,9 +84,20 @@ def _record_pid(pid: int) -> None:
                 pass
 
 
-def _spawn(cmd: list) -> subprocess.Popen:
+def _local_chips() -> int:
+    """TPU chips on this host, counted from their device nodes — WITHOUT
+    touching jax: a driver that initialized the backend would hold the
+    chips its children need."""
+    import glob
+
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*")
+    )
+
+
+def _spawn(cmd: list, env: dict | None = None) -> subprocess.Popen:
     # own process group: one killpg reaps a node AND anything it forked
-    p = subprocess.Popen(cmd, start_new_session=True)
+    p = subprocess.Popen(cmd, start_new_session=True, env=env)
     _procs.append(p)
     _record_pid(p.pid)
     return p
@@ -150,6 +163,22 @@ def main() -> int:
     accelerator = "--accelerator" in sys.argv
     use_async = "--async" in sys.argv
     use_gateway = "--gateway" in sys.argv
+    # an explicit cpu pin (JAX_PLATFORMS=cpu) runs the device kernels on
+    # host XLA on purpose: no chip is needed or pinned then
+    on_chips = accelerator and (
+        os.environ.get("JAX_PLATFORMS", "").split(",")[0] != "cpu"
+    )
+    if on_chips and n > _local_chips():
+        print(
+            f"--accelerator: {n} node processes but {_local_chips()} TPU "
+            "chip(s) on this host. A chip belongs to one process at a "
+            "time, so each node process needs its own. To run many "
+            "validators on one chip use the in-process cluster: "
+            "python chip_smoke.py, or bench.bench_16node_threads("
+            "accelerator=True).",
+            file=sys.stderr,
+        )
+        return 2
     base = tempfile.mkdtemp(prefix="babble_tpu_testnet_")
     print(f"testnet dir: {base}")
     _pid_files.extend([os.path.join(base, "pids"), PIDS_WELL_KNOWN])
@@ -200,12 +229,18 @@ def main() -> int:
                 cmd += ["--signal", "--signal-addr", "127.0.0.1:2443"]
             if use_async and not use_signal:
                 cmd += ["--transport", "async"]
+            env = None
             if accelerator:
                 cmd.append("--accelerator")
-                os.environ.setdefault(
-                    "BABBLE_ACCEL_SLOT_DIR", os.path.join(base, "slots")
-                )
-            _spawn(cmd)
+            if on_chips:
+                # one chip per node process: node i sees only chip i
+                env = {
+                    **os.environ,
+                    "TPU_VISIBLE_CHIPS": str(i),
+                    "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                    "TPU_PROCESS_BOUNDS": "1,1,1",
+                }
+            _spawn(cmd, env)
             # dummy chat-app client on the other side of the socket pair
             _spawn(
                 [sys.executable, "-m", "babble_tpu.cli", "dummy",

@@ -9,10 +9,12 @@ silently misreport every round's numbers, so the subtle parts are pinned:
 
 from __future__ import annotations
 
+import os
 import sys
 import time
 
-sys.path.insert(0, "/root/repo")  # bench.py lives at the repo root
+# bench.py lives at the repo root
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import bench
 
@@ -99,3 +101,25 @@ def test_model_flops_monotone():
     small = bench._dag_model_flops(128, 16, 8)
     big = bench._dag_model_flops(512, 16, 8)
     assert big > small > 0
+
+
+def test_accelerated_capture_without_a_tpu_fails():
+    """An accelerated capture never publishes host-XLA numbers: with no
+    TPU it fails at device resolution; host-only captures just stamp."""
+    import pytest
+
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        bench._resolve_bench_device()
+    info = bench._resolve_bench_device(accelerated=False)
+    assert info["capture_class"] == "cpu-xla" and info["platform"] == "cpu"
+    assert set(info) >= {"platform", "device_kind", "count", "device"}
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        bench.bench_pallas_probe()  # never the interpreter, unasked
+
+
+def test_peak_flops_table_is_keyed_by_device_kind():
+    import pytest
+
+    assert bench._peak_flops("TPU v5 lite") == 197e12
+    with pytest.raises(KeyError, match="no published peak"):
+        bench._peak_flops("TPU v99")
