@@ -37,6 +37,7 @@ from typing import Optional
 
 from babble_tpu.common.breaker import CircuitBreaker
 from babble_tpu.common.errors import StoreError
+from babble_tpu.obs.trace import NULL_STAGE, Tracer, annotation
 
 logger = logging.getLogger("babble_tpu.hashgraph.accel")
 
@@ -65,7 +66,7 @@ class _Inflight:
     back while gossip continues."""
 
     __slots__ = ("win", "result", "error", "done", "generation", "t_launch",
-                 "t_done", "topo", "snap", "readback_s", "_slots",
+                 "t_done", "topo", "snap", "readback_s", "wake_s", "_slots",
                  "_slot_lock", "_slot_held")
 
     def __init__(self, win, generation: int, topo: int, slots=None,
@@ -83,6 +84,8 @@ class _Inflight:
         # generation gates apply — see TensorConsensus._apply.
         self.snap = snap
         self.readback_s = 0.0  # device→host wait measured by the reader
+        # batcher path: ticket done → the reader thread runs again
+        self.wake_s: Optional[float] = None
         # Admission-control slot ownership: released exactly once, by the
         # reader when the readback lands OR by the abandonment path when a
         # wedged readback times out — whichever gets there first.
@@ -158,6 +161,10 @@ class _FlockSlots:
             _, fd = self._held.pop()
         fcntl.flock(fd, fcntl.LOCK_UN)
         os.close(fd)
+
+
+def _parts_recorded(stage: str, seconds: float) -> None:
+    """Sink of a span whose parts are recorded one by one."""
 
 
 def _is_stale_window(err: BaseException) -> bool:
@@ -289,16 +296,28 @@ class TensorConsensus:
         self.last_window_events = 0
         # Per-stage rolling sums (seconds) for /debug and bench breakdowns.
         # snapshot cost = build (full rebuilds) + delta_scan + pack
-        # (incremental); dispatch/readback split the old "kernel" stage so
-        # a transfer regression is distinguishable from a compute one.
+        # (incremental); dispatch and readback are the device leg as this
+        # validator's threads see it. Two waits BETWEEN threads are
+        # recorded apart: wake (the batcher has the result → this
+        # engine's reader thread runs again; part of readback) and
+        # result_idle (the reader is done → the next flush applies it).
         self.stage_s = {
             "build": 0.0, "delta_scan": 0.0, "pack": 0.0,
-            "dispatch": 0.0, "readback": 0.0, "kernel": 0.0, "apply": 0.0,
+            "dispatch": 0.0, "readback": 0.0, "wake": 0.0,
+            "result_idle": 0.0, "apply": 0.0,
         }
         # Optional per-sample stage observer (obs.telemetry wires the
         # accel_stage_seconds{stage=...} histogram here); stage_s keeps
         # the legacy rolling totals either way.
         self.stage_observer = None
+        # The span tree the stages that run on the flushing thread hang
+        # in. obs.telemetry swaps in the node's tracer, which makes them
+        # children of its `flush` span and adds their CPU seconds and
+        # profiler annotations; this bare one only times them.
+        self.spans = Tracer()
+        # launches of this engine's own programs by shape bucket
+        # (voting.bucket_label); the batcher counts the ones it makes
+        self.bucket_launches: dict = {}
         self._inflight: Optional[_Inflight] = None
         self._compiling = set()
         self._lock = threading.Lock()
@@ -309,6 +328,30 @@ class TensorConsensus:
         obs = self.stage_observer
         if obs is not None:
             obs(stage, seconds)
+
+    def _span(self, stage: str):
+        """A stage that runs on the flushing thread, as a span that
+        records into ``_stage`` when it closes."""
+        return self.spans.span(stage, sink=self._stage)
+
+    def _thread_span(self, stage: str):
+        """The profiler annotation of a stage on one of this engine's
+        helper threads (their time is recorded by ``_apply``, on the
+        flushing thread); nothing unless the node's tracer is wired."""
+        owner = self.spans.owner
+        if owner is None:
+            return NULL_STAGE
+        return annotation(stage, owner) or NULL_STAGE
+
+    def _thread_name(self, role: str) -> str:
+        return f"{self._copro_owner()}:{role}"
+
+    def _count_launch(self, win) -> None:
+        """One program of this engine's own was launched at ``win``'s
+        shape bucket (single, resident or mesh: one window each)."""
+        from babble_tpu.ops import voting
+
+        voting.count_launch(self.bucket_launches, voting.bucket_key(win))
 
     # -- gates --------------------------------------------------------------
 
@@ -435,7 +478,7 @@ class TensorConsensus:
         if kick:
             threading.Thread(
                 target=self._compile_bucket, args=(key, use_mesh),
-                daemon=True,
+                daemon=True, name=self._thread_name("bucket-compile"),
             ).start()
         self.compile_waits += 1
         return False
@@ -615,15 +658,17 @@ class TensorConsensus:
         from babble_tpu.ops import voting
 
         if not self.resident:
-            t0 = time.perf_counter()
-            win = voting.build_voting_window(hg)
-            self._stage("build", time.perf_counter() - t0)
+            with self._span("build"):
+                win = voting.build_voting_window(hg)
             return win, None
         timers: dict = {}
         try:
-            snap = self.window_state.snapshot(
-                hg, timers, copy_rows=for_batcher
-            )
+            # WindowState times its own parts (build | delta_scan + pack);
+            # the span around them only places them in the tree
+            with self.spans.span("snapshot", sink=_parts_recorded):
+                snap = self.window_state.snapshot(
+                    hg, timers, copy_rows=for_batcher
+                )
         finally:
             for k, v in timers.items():
                 self._stage(k, v)
@@ -697,7 +742,7 @@ class TensorConsensus:
                     self._compiling.discard(gate)
 
         threading.Thread(target=work, daemon=True,
-                         name="resident-compile").start()
+                         name=self._thread_name("resident-compile")).start()
 
     def _launch(self, hg) -> bool:
         from babble_tpu.ops import voting
@@ -745,10 +790,14 @@ class TensorConsensus:
             def batch_reader() -> None:
                 try:
                     t_r = time.perf_counter()
-                    ticket.done.wait()
+                    with self._thread_span("readback"):
+                        ticket.done.wait()
                     # coalesce wait + dispatch + readback, from this
-                    # node's perspective
-                    inf.readback_s = time.perf_counter() - t_r
+                    # node's perspective; its last part is this thread
+                    # getting to run again once the batcher was done
+                    t_w = time.perf_counter()
+                    inf.readback_s = t_w - t_r
+                    inf.wake_s = t_w - ticket.t_read
                     if ticket.error is not None:
                         inf.error = ticket.error
                     else:
@@ -757,7 +806,10 @@ class TensorConsensus:
                     inf.t_done = time.perf_counter()
                     inf.done.set()
 
-            threading.Thread(target=batch_reader, daemon=True).start()
+            threading.Thread(
+                target=batch_reader, daemon=True,
+                name=self._thread_name("sweep-reader"),
+            ).start()
             self._inflight = inf
             self._last_snapshot_topo = hg.topological_index
             return True
@@ -786,14 +838,15 @@ class TensorConsensus:
         inf = _Inflight(win, self.generation, hg.topological_index, slots,
                         snap)
         try:
-            t_d = time.perf_counter()
-            out = self._dispatch_snap(win, snap)
-            self._stage("dispatch", time.perf_counter() - t_d)
+            with self._span("dispatch"):
+                out = self._dispatch_snap(win, snap)
+            self._count_launch(win)
 
             def reader() -> None:
                 try:
                     t_r = time.perf_counter()
-                    inf.result = voting.read_sweep(out, inf.win)
+                    with self._thread_span("readback"):
+                        inf.result = voting.read_sweep(out, inf.win)
                     inf.readback_s = time.perf_counter() - t_r
                 except BaseException as e:  # device failure
                     inf.error = e
@@ -802,7 +855,10 @@ class TensorConsensus:
                     inf.t_done = time.perf_counter()
                     inf.done.set()
 
-            threading.Thread(target=reader, daemon=True).start()
+            threading.Thread(
+                target=reader, daemon=True,
+                name=self._thread_name("sweep-reader"),
+            ).start()
         except BaseException as err:
             inf.release_slot()
             if not isinstance(err, Exception):
@@ -838,20 +894,23 @@ class TensorConsensus:
             self.stale_drops += 1
             self.breaker.cancel()  # not the device's fault: no verdict
             return False
-        try:
-            fame, rr = inf.result
-            _decided, fame_applied = voting.apply_fame(hg, inf.win, fame)
-            received = voting.apply_round_received(hg, inf.win, rr)
-        except Exception as err:
-            self._note_fallback(err)
-            return False
-        if inf.snap is not None and state is not None:
-            state.note_applied(fame_applied, received)
-        t_apply = time.perf_counter() - t0
+        with self._span("apply") as applying:
+            try:
+                fame, rr = inf.result
+                _decided, fame_applied = voting.apply_fame(hg, inf.win, fame)
+                received = voting.apply_round_received(hg, inf.win, rr)
+            except Exception as err:
+                self._note_fallback(err)
+                return False
+            if inf.snap is not None and state is not None:
+                state.note_applied(fame_applied, received)
+        t_apply = applying.seconds
         kernel_s = inf.t_done - inf.t_launch  # dispatch+kernel+readback
-        self._stage("apply", t_apply)
-        self._stage("kernel", kernel_s)
         self._stage("readback", inf.readback_s)
+        if inf.wake_s is not None:
+            self._stage("wake", inf.wake_s)
+        # the result lay ready until this flush came
+        self._stage("result_idle", max(0.0, t0 - inf.t_done))
         self.breaker.record_success()
         self.sweeps += 1
         self.last_window_events = len(inf.win.hashes)
@@ -895,28 +954,27 @@ class TensorConsensus:
                     self.breaker.cancel()
                     return False
                 self._stage("dispatch", time.perf_counter() - t1)
-                t_r = time.perf_counter()
-                if not ticket.done.wait(self.readback_timeout_s):
-                    raise TimeoutError(
-                        f"batched sweep exceeded {self.readback_timeout_s:.0f}s"
-                    )
+                with self._span("readback"):
+                    if not ticket.done.wait(self.readback_timeout_s):
+                        raise TimeoutError(
+                            "batched sweep exceeded "
+                            f"{self.readback_timeout_s:.0f}s"
+                        )
+                    self._stage("wake", time.perf_counter() - ticket.t_read)
                 if ticket.error is not None:
                     raise ticket.error
                 fame, rr = ticket.result
-                self._stage("readback", time.perf_counter() - t_r)
             else:
-                out = self._dispatch_snap(win, snap)
-                t_r = time.perf_counter()
-                self._stage("dispatch", t_r - t1)
-                fame, rr = voting.read_sweep(out, win)
-                self._stage("readback", time.perf_counter() - t_r)
-            t2 = time.perf_counter()
-            self._stage("kernel", t2 - t1)
-            _decided, fame_applied = voting.apply_fame(hg, win, fame)
-            received = voting.apply_round_received(hg, win, rr)
-            if snap is not None and self.window_state is not None:
-                self.window_state.note_applied(fame_applied, received)
-            self._stage("apply", time.perf_counter() - t2)
+                with self._span("dispatch"):
+                    out = self._dispatch_snap(win, snap)
+                self._count_launch(win)
+                with self._span("readback"):
+                    fame, rr = voting.read_sweep(out, win)
+            with self._span("apply"):
+                _decided, fame_applied = voting.apply_fame(hg, win, fame)
+                received = voting.apply_round_received(hg, win, rr)
+                if snap is not None and self.window_state is not None:
+                    self.window_state.note_applied(fame_applied, received)
         except Exception as err:
             if _is_stale_window(err):
                 self.stale_drops += 1
@@ -984,11 +1042,12 @@ class TensorConsensus:
             "accel_last_window_events": self.last_window_events,
             # Per-stage breakdown (ms totals): snapshot cost is build (full
             # rebuilds) + delta_scan + pack (incremental); dispatch and
-            # readback split the device leg; kernel is the legacy combined
-            # dispatch→readback wall time.
+            # readback split the device leg; wake (inside readback) and
+            # result_idle are the waits between this engine's threads.
             "accel_stage_ms": {
                 k: round(1000.0 * v, 1) for k, v in self.stage_s.items()
             },
+            "accel_bucket_launches": dict(self.bucket_launches),
             # Resident-window counters: delta rows uploaded vs rows served
             # from the device-resident buffers, and how often the
             # incremental state fell back to a from-scratch rebuild.
@@ -1009,7 +1068,11 @@ class TensorConsensus:
         # circuit-breaker surface: accel_breaker_state/open/probes/skips/
         # failures (open = count of closed→open transitions)
         out.update(self.breaker.stats(prefix="accel_breaker_"))
-        if self.batcher:
+        if self.batcher or (self.batcher is None and batcher_default_on()):
+            # also before the first flush has resolved ``batcher``: the
+            # tallies are process-wide, and a reader that differences two
+            # snapshots of a fresh engine would otherwise take the whole
+            # process's history for this engine's window
             from babble_tpu.hashgraph.sweep_batcher import SweepBatcher
 
             out.update(SweepBatcher.instance().stats())

@@ -160,10 +160,10 @@ class Hashgraph:
         # insert; inserts between sweeps are counted in _accel_pending.
         self.accel = None
         self._accel_pending = 0
-        # Pipeline-stage observer (obs.telemetry): fn(stage, seconds)
-        # feeding the sync_stage_seconds histogram + the active sync
-        # trace. None (bare hashgraphs, BABBLE_OBS=0) keeps the staged
-        # methods clockless — the decorator checks this attribute.
+        # Pipeline-stage observer: the node's span tracer (obs/trace.py),
+        # feeding the sync_stage_* histograms + the active sync trace.
+        # None (bare hashgraphs, BABBLE_OBS=0) keeps the staged methods
+        # clockless — the decorator checks this attribute.
         self.stage_observer = None
         # Delta channels for the accelerator's incremental WindowState
         # (ops/window_state.py): the insert path records the two mutations
@@ -626,6 +626,7 @@ class Hashgraph:
         fd, self._accel_fd_dirty = self._accel_fd_dirty, set()
         return nw, fd
 
+    @staged("flush")
     def run_consensus_sweep(self) -> None:
         """One batched voting sweep: device kernels when the undecided
         window is big enough to beat the dispatch+readback cost, oracle

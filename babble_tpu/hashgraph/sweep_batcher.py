@@ -35,6 +35,8 @@ import threading
 import time
 
 from ..common.timed_lock import named_lock
+from ..obs.metrics import enabled as obs_enabled
+from ..obs.trace import NULL_STAGE, annotation
 from typing import Dict, List, Optional
 
 logger = logging.getLogger("babble_tpu.hashgraph.sweep_batcher")
@@ -42,12 +44,23 @@ logger = logging.getLogger("babble_tpu.hashgraph.sweep_batcher")
 
 class Ticket:
     """One node's submitted window; the batcher delivers (fame, rr) or an
-    error. ``done`` is set exactly once."""
+    error. ``done`` is set exactly once.
+
+    A window's life is stamped where it happens (perf_counter seconds):
+    ``t_submit`` by the owner's thread; ``t_taken`` (``_loop`` took the
+    wave), ``t_launched`` (the last program of its group was launched)
+    and ``t_read`` (its result, or its error, is on the host — ``done``
+    is set right after) by the batcher thread, each beside the batcher
+    thread's own CPU clock (``c_*``, 0 under BABBLE_OBS=0)."""
 
     __slots__ = ("win", "result", "error", "done", "batch_size", "mesh",
-                 "owner")
+                 "owner", "t_submit", "t_taken", "t_launched", "t_read",
+                 "c_taken", "c_launched", "c_read")
 
     def __init__(self, win, mesh=None, owner: Optional[str] = None):
+        self.t_submit = time.perf_counter()
+        self.t_taken = self.t_launched = self.t_read = 0.0
+        self.c_taken = self.c_launched = self.c_read = 0.0
         self.win = win
         self.result = None  # (fame, rr) numpy arrays
         self.error: Optional[BaseException] = None
@@ -112,6 +125,17 @@ class SweepBatcher:
         self.copro_waves = 0  # mesh waves dispatched
         self.copro_windows = 0  # windows served through a mesh wave
         self._owners: set = set()  # validators seen on any mesh lane
+        # A served window's life, summed (seconds): queue = submit →
+        # taken, launch = taken → launched, read = launched → on the
+        # host; stage_cpu_s is the batcher thread's own CPU time inside
+        # launch and read (wall minus CPU: it waited, for the GIL or the
+        # device). Written by the batcher thread only.
+        self.stage_s = {"queue": 0.0, "launch": 0.0, "read": 0.0}
+        self.stage_cpu_s = {"launch": 0.0, "read": 0.0}
+        self.stage_windows = 0  # served windows the sums above cover
+        # launches by the shape bucket that RAN (voting.bucket_label)
+        self.bucket_launches: Dict[str, int] = {}
+        self._obs = obs_enabled()
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="sweep-batcher"
         )
@@ -153,6 +177,14 @@ class SweepBatcher:
             "batch_compile_kicks": self.compile_kicks,
             "batch_refused": self.refused,
             "batch_target_decays": self.target_decays,
+            "batch_stage_windows": self.stage_windows,
+            "batch_stage_ms": {
+                k: round(1000.0 * v, 3) for k, v in self.stage_s.items()
+            },
+            "batch_stage_cpu_ms": {
+                k: round(1000.0 * v, 3) for k, v in self.stage_cpu_s.items()
+            },
+            "batch_bucket_launches": dict(self.bucket_launches),
             # coprocessor lane: mesh waves, windows multiplexed through
             # them, and distinct validators sharing the mesh(es)
             "copro_waves": self.copro_waves,
@@ -172,16 +204,58 @@ class SweepBatcher:
             with self._lock:
                 batch, self._pending = self._pending, []
                 self._work.clear()
+            now, cpu = self._now()
+            for t in batch:
+                t.t_taken, t.c_taken = now, cpu
             if batch:
                 try:
                     self._dispatch(batch)
                 except BaseException as err:  # never kill the daemon
                     for t in batch:
                         if not t.done.is_set():
-                            t.error = err
-                            t.done.set()
+                            self._finish(t, error=err)
                     logger.warning("sweep batch dispatch failed",
                                    exc_info=True)
+
+    # -- a window's life -----------------------------------------------------
+
+    def _now(self) -> tuple:
+        """(wall, this thread's CPU) — the CPU clock only with telemetry
+        on, like every other clock read the kill switch removes."""
+        return (time.perf_counter(),
+                time.thread_time() if self._obs else 0.0)
+
+    def _wave_span(self, stage: str, group: List[Ticket]):
+        """The profiler annotation of one wave stage on the batcher
+        thread, owned by the validators whose windows ride the wave."""
+        if not self._obs:
+            return NULL_STAGE
+        owners = ",".join(sorted({t.owner for t in group if t.owner}))
+        return annotation(stage, owners) or NULL_STAGE
+
+    def _launched(self, group: List[Ticket]) -> None:
+        """The last program of ``group`` has been launched."""
+        now, cpu = self._now()
+        for t in group:
+            t.t_launched, t.c_launched = now, cpu
+
+    def _finish(self, t: Ticket, result=None, error=None,
+                batch_size: int = 0) -> None:
+        """Hand a ticket back to its owner. A served window's stamps are
+        folded into the stage sums; a failed one only gets its stamp."""
+        t.t_read, t.c_read = self._now()
+        if error is not None:
+            t.error = error
+        else:
+            t.result, t.batch_size = result, batch_size
+            self.stage_windows += 1
+            s, c = self.stage_s, self.stage_cpu_s
+            s["queue"] += t.t_taken - t.t_submit
+            s["launch"] += t.t_launched - t.t_taken
+            s["read"] += t.t_read - t.t_launched
+            c["launch"] += t.c_launched - t.c_taken
+            c["read"] += t.c_read - t.c_launched
+        t.done.set()
 
     def _dispatch(self, tickets: List[Ticket]) -> None:
         # Partition the wave into lanes: one per configured mesh (the
@@ -229,11 +303,10 @@ class SweepBatcher:
             if state is not None and state.generation != t.win.generation:
                 from babble_tpu.ops.window_state import StaleWindowError
 
-                t.error = StaleWindowError(
+                self._finish(t, error=StaleWindowError(
                     f"window generation {t.win.generation} != state "
                     f"generation {state.generation}"
-                )
-                t.done.set()
+                ))
                 continue
             fresh.append(t)
         return fresh
@@ -274,36 +347,38 @@ class SweepBatcher:
             wave = tuple(max(a, b) for a, b in zip(wave, prev))
         self._mesh_targets[mk] = wave
         launched = []
-        for t in group:
-            try:
-                padded = voting.repad_window(t.win, wave)
-                launched.append((
-                    t, padded,
-                    voting_shard._jitted(mesh)(
-                        *voting_shard.place_window(mesh, padded)
-                    ),
-                ))
-            except BaseException as err:
-                t.error = err
-                t.done.set()
+        with self._wave_span("launch", group):
+            for t in group:
+                try:
+                    padded = voting.repad_window(t.win, wave)
+                    launched.append((
+                        t, padded,
+                        voting_shard._jitted(mesh)(
+                            *voting_shard.place_window(mesh, padded)
+                        ),
+                    ))
+                    voting.count_launch(self.bucket_launches, wave)
+                except BaseException as err:
+                    self._finish(t, error=err)
+            self._launched([t for t, _p, _o in launched])
         import numpy as np
 
         served = 0
-        for t, padded, out in launched:
-            try:
-                host = np.asarray(out)
+        with self._wave_span("read", group):
+            for t, padded, out in launched:
+                try:
+                    host = np.asarray(out)
+                except BaseException as err:
+                    self._finish(t, error=err)
+                    continue
                 # real rows keep their indexes under repad: slice back to
                 # the ORIGINAL window's row spaces
-                t.result = (
+                self._finish(t, result=(
                     host[: t.win.n_witnesses],
                     host[padded.n_witnesses:
                          padded.n_witnesses + t.win.n_events],
-                )
-                t.batch_size = len(launched)
+                ), batch_size=len(launched))
                 served += 1
-            except BaseException as err:
-                t.error = err
-            t.done.set()
         if served:
             self.copro_waves += 1
             self.copro_windows += served
@@ -329,14 +404,18 @@ class SweepBatcher:
         target = self._update_target(wave)
         B = self.MAX_BATCH
         if len(group) > 1 and voting.batched_ready(target, B):
-            padded = [voting.repad_window(t.win, target) for t in group]
             try:
-                out = voting.launch_batched(padded, B)
-                results = voting.read_batched(out, padded)
+                with self._wave_span("launch", group):
+                    padded = [voting.repad_window(t.win, target)
+                              for t in group]
+                    out = voting.launch_batched(padded, B)
+                    voting.count_launch(self.bucket_launches, target, B)
+                    self._launched(group)
+                with self._wave_span("read", group):
+                    results = voting.read_batched(out, padded)
             except BaseException as err:
                 for t in group:
-                    t.error = err
-                    t.done.set()
+                    self._finish(t, error=err)
                 return
             self.batches += 1
             self.windows += len(group)
@@ -344,12 +423,11 @@ class SweepBatcher:
             for t, (fame, rr) in zip(group, results):
                 # slice the padded vectors back to the ORIGINAL window's
                 # row spaces (real rows keep their indexes under repad)
-                t.batch_size = len(group)
-                t.result = (
-                    fame[: t.win.n_witnesses],
-                    rr[: t.win.n_events],
+                self._finish(
+                    t,
+                    result=(fame[: t.win.n_witnesses], rr[: t.win.n_events]),
+                    batch_size=len(group),
                 )
-                t.done.set()
             return
         if len(group) > 1:
             self._kick_compile(target, B)
@@ -359,23 +437,27 @@ class SweepBatcher:
         # device buffers, so the device overlaps the windows' work and the
         # wave pays ~one readback latency instead of a serial convoy.
         launched = []
-        for t in group:
-            try:
-                launched.append((t, voting.launch_sweep(t.win)))
-            except BaseException as err:
-                t.error = err
+        with self._wave_span("launch", group):
+            for t in group:
+                try:
+                    launched.append((t, voting.launch_sweep(t.win)))
+                    voting.count_launch(self.bucket_launches,
+                                        voting.bucket_key(t.win))
+                except BaseException as err:
+                    self.singles += 1
+                    self.windows += 1
+                    self._finish(t, error=err)
+            self._launched([t for t, _o in launched])
+        with self._wave_span("read", group):
+            for t, out in launched:
                 self.singles += 1
                 self.windows += 1
-                t.done.set()
-        for t, out in launched:
-            try:
-                t.result = voting.read_sweep(out, t.win)
-                t.batch_size = 1
-            except BaseException as err:
-                t.error = err
-            self.singles += 1
-            self.windows += 1
-            t.done.set()
+                try:
+                    result = voting.read_sweep(out, t.win)
+                except BaseException as err:
+                    self._finish(t, error=err)
+                    continue
+                self._finish(t, result=result, batch_size=1)
 
     def _update_target(self, wave: tuple) -> tuple:
         """Monotone-with-decay shape bucket. The target grows to cover

@@ -24,6 +24,7 @@ from ..hashgraph.internal_transaction import (
 )
 from ..hashgraph.store import Store
 from ..mempool import Mempool
+from ..obs.trace import NULL_STAGE, staged
 from ..peers.peer_set import PeerSet
 from .peer_selector import RandomPeerSelector
 from .promise import JoinPromise
@@ -190,16 +191,22 @@ class Core:
         # Telemetry (docs/observability.md): the per-node registry wiring
         # every subsystem's counters into instruments, created at the
         # core so standalone cores (benches, tests) measure identically
-        # to full nodes. _stage_obs is None under BABBLE_OBS=0 — the
-        # timing sites below null-check it and skip even the clock reads.
+        # to full nodes.
         from ..obs.telemetry import NodeTelemetry
 
         self.obs = NodeTelemetry(self)
-        self._stage_obs = self.obs.stage_observer
-        self.hg.stage_observer = self._stage_obs
-        # The @staged decorator times hashgraph stages against this
-        # clock, so simulated runs record virtual durations.
-        self.hg.stage_clock = self.clock.perf_counter
+        # The span tracer (obs/trace.py) behind @staged and _span, here
+        # and in the hashgraph; it times against this node's clock, so
+        # simulated runs record virtual durations. None under
+        # BABBLE_OBS=0: no span opens and no clock is read.
+        self.stage_observer = self.obs.stage_observer
+        self.hg.stage_observer = self.stage_observer
+
+    def _span(self, stage: str):
+        """A stage that is part of a method, as a span to enter with
+        ``with`` (whole methods use @staged)."""
+        obs = self.stage_observer
+        return NULL_STAGE if obs is None else obs.span(stage)
 
     # -- head/seq -----------------------------------------------------------
 
@@ -250,6 +257,7 @@ class Core:
 
     # -- sync ---------------------------------------------------------------
 
+    @staged("prepare_sync")
     def prepare_sync(self, unknown_events: List[WireEvent]) -> PreparedSync:
         """Lock-free ingest stage: decode + hash the longest possible
         prefix of an incoming sync and verify all its signatures in ONE
@@ -277,6 +285,7 @@ class Core:
         prepared.decoded = decoded
         return prepared
 
+    @staged("decode")
     def _decode_chunk(
         self, unknown_events: List[WireEvent], start: int
     ) -> tuple[List[Event], int]:
@@ -285,8 +294,6 @@ class Core:
         decoded so far. Returns (decoded, next_pos); a decode stall cuts
         the run at next_pos. Shared by the lock-free prepare stage and
         sync's under-lock tail so their semantics can never diverge."""
-        obs = self._stage_obs
-        t0 = self.clock.perf_counter() if obs is not None else 0.0
         overlay: Dict[tuple, str] = {}
         decoded: List[Event] = []
         j = start
@@ -304,10 +311,9 @@ class Core:
             overlay.setdefault((ev.creator(), ev.index()), ev.hex())
             decoded.append(ev)
             j += 1
-        if obs is not None:
-            obs("decode", self.clock.perf_counter() - t0)
         return decoded, j
 
+    @staged("batch_verify")
     def _batch_prevalidate(self, decoded: List[Event]) -> None:
         """Verify a decoded chunk's signatures in one batch call, then
         pinpoint offenders: events the batch flagged are re-checked
@@ -315,8 +321,6 @@ class Core:
         can never reject a valid event and a genuinely bad event is
         identified exactly (its verdict stays cached for insert to
         reject)."""
-        obs = self._stage_obs
-        t_verify = self.clock.perf_counter() if obs is not None else 0.0
         use_device_verify = self.accelerated_verify
         if use_device_verify:
             # The device ladder kernel is dispatch/loop-bound (no
@@ -343,8 +347,6 @@ class Core:
 
             if not prevalidate_events_host(decoded):
                 # Native library unavailable: scalar verify at insert.
-                if obs is not None:
-                    obs("batch_verify", self.clock.perf_counter() - t_verify)
                 return
         self.ingest_batch_verifies += 1
         if len(decoded) > self.ingest_batch_size_max:
@@ -354,9 +356,8 @@ class Core:
                 ev.clear_prevalidation()
                 ev.prevalidate(ev.verify())
                 self.ingest_fallback_singles += 1
-        if obs is not None:
-            obs("batch_verify", self.clock.perf_counter() - t_verify)
 
+    @staged("sync")
     def sync(
         self,
         from_id: int,
@@ -497,6 +498,7 @@ class Core:
             del self.heads[we.body.creator_id]
         return other_head
 
+    @staged("record_heads")
     def record_heads(self) -> None:
         """reference: core.go:274-289."""
         for fid in list(self.heads.keys()):
@@ -541,9 +543,12 @@ class Core:
                 self.accepted_round,
             )
             return
+        self._mint_self_event(other_head)
 
-        obs = self._stage_obs
-        t_event = self.clock.perf_counter() if obs is not None else 0.0
+    @staged("self_event")
+    def _mint_self_event(self, other_head: str) -> None:
+        """The whole self-event packaging; its own insert, DivideRounds
+        and any mid-batch flush are child spans."""
         sigs = list(self.self_block_signatures.values())
         n_itxs = len(self.internal_transaction_pool)
 
@@ -551,9 +556,8 @@ class Core:
         # most event_max_txs / event_max_bytes of client transactions, so
         # gossip payloads stay bounded under sustained overload; leftovers
         # keep busy() true and ride the next event (FIFO fairness).
-        txs = self.mempool.drain()
-        if obs is not None:
-            obs("mempool_drain", self.clock.perf_counter() - t_event)
+        with self._span("mempool_drain"):
+            txs = self.mempool.drain()
 
         new_head = Event.new(
             txs,
@@ -578,10 +582,6 @@ class Core:
         self.internal_transaction_pool = self.internal_transaction_pool[n_itxs:]
         for s in sigs:
             self.self_block_signatures.pop(s.key(), None)
-        if obs is not None:
-            # whole self-event packaging incl. its insert+DivideRounds
-            # (the nested insert/divide_rounds stages record too)
-            obs("self_event", self.clock.perf_counter() - t_event)
 
     def sign_and_insert_self_event(self, event: Event) -> None:
         """reference: core.go:337-343."""
@@ -681,15 +681,8 @@ class Core:
     def commit(self, block: Block) -> None:
         """The hashgraph's commit callback: push the block to the app, sign
         it, and process membership receipts (reference: core.go:485-536)."""
-        obs = self._stage_obs
-        if obs is None:
+        with self._span("proxy_deliver"):
             commit_response = self.proxy_commit_callback(block)
-        else:
-            t0 = self.clock.perf_counter()
-            try:
-                commit_response = self.proxy_commit_callback(block)
-            finally:
-                obs("proxy_deliver", self.clock.perf_counter() - t0)
 
         # Feed the committed-hash LRU atomically with the commit (under
         # the mempool's own lock): from here on a client retry of any of
@@ -802,6 +795,7 @@ class Core:
 
     # -- pools --------------------------------------------------------------
 
+    @staged("process_sig_pool")
     def process_sig_pool(self) -> None:
         self.hg.process_sig_pool()
 

@@ -96,6 +96,9 @@ class Node(StateManager):
         # Equivocation proofs persist through the store's evidence table
         # (and load back on restart) when the store supports it.
         self.core.sentry.attach_store(store)
+        # this validator's name on its threads: gossip and RPC routines
+        # here, the background and run loops below
+        self.routine_name = self._thread_name("routine")
         # Telemetry: the core created its registry (docs/observability.md);
         # bind the node-level instruments (RPC counters, queue depth) and
         # take the sync-stage observer for the gossip legs below.
@@ -394,7 +397,10 @@ class Node(StateManager):
 
             obs_profile.ensure_started(self.conf.profile_hz)
         self.control_timer.run(self.conf.heartbeat_timeout)
-        bg = threading.Thread(target=self._do_background_work, daemon=True)
+        bg = threading.Thread(
+            target=self._do_background_work, daemon=True,
+            name=self._thread_name("background"),
+        )
         bg.start()
         self._threads.append(bg)
 
@@ -413,8 +419,15 @@ class Node(StateManager):
             else:
                 self.clock.sleep(0.05)
 
+    def _thread_name(self, role: str) -> str:
+        v = self.core.validator
+        return f"{v.moniker or v.public_key_hex()[:16]}:{role}"
+
     def run_async(self, gossip: bool = True) -> None:
-        t = threading.Thread(target=self.run, args=(gossip,), daemon=True)
+        t = threading.Thread(
+            target=self.run, args=(gossip,), daemon=True,
+            name=self._thread_name("run"),
+        )
         t.start()
         self._threads.append(t)
 
@@ -1147,9 +1160,9 @@ class Node(StateManager):
             # behind the re-raise.
             t0 = self.clock.monotonic()
             self.core.process_sig_pool()
-            dt = self.clock.monotonic() - t0
-            self.timers.record("process_sig_pool", dt)
-            self.telemetry.observe_stage("process_sig_pool", dt)
+            self.timers.record(
+                "process_sig_pool", self.clock.monotonic() - t0
+            )
 
     # -- catching up --------------------------------------------------------
 

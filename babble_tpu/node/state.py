@@ -48,6 +48,8 @@ class StateManager:
         self._routines_lock = threading.Lock()
         self._routines: List[threading.Thread] = []
         self._live = 0
+        # thread name of the routines; a Node puts its moniker in front
+        self.routine_name = "routine"
 
     def get_state(self) -> State:
         with self._state_lock:
@@ -76,7 +78,9 @@ class StateManager:
             self._live += 1
             if len(self._routines) >= WGLIMIT:
                 self._routines = [t for t in self._routines if t.is_alive()]
-            t = threading.Thread(target=wrapped, daemon=True)
+            t = threading.Thread(
+                target=wrapped, daemon=True, name=self.routine_name
+            )
             try:
                 t.start()
             except Exception:

@@ -43,7 +43,25 @@ CATALOG: Tuple[Instrument, ...] = (
         "Per-stage wall time of the gossip/consensus pipeline: "
         "request_sync, decode, batch_verify, insert, divide_rounds, "
         "decide_fame, round_received, commit, proxy_deliver, "
-        "process_sig_pool, diff, eager_sync, mempool_drain, self_event.",
+        "process_sig_pool, diff, eager_sync, mempool_drain, self_event, "
+        "sync, prepare_sync, flush, record_heads. Inclusive: a span's "
+        "whole duration, its children's included.",
+    ),
+    Instrument(
+        "sync_stage_self_seconds", _H, ("stage",), "node",
+        "Self time of the same spans: a span's duration minus what the "
+        "spans opened inside it on the same thread covered. The self "
+        "time of the roots sync and prepare_sync is ingest time under no "
+        "finer span.",
+    ),
+    Instrument(
+        "sync_stage_cpu_seconds", _H, ("stage",), "node",
+        "Thread CPU time (time.thread_time) inside the COARSE spans "
+        "only: sync, prepare_sync, decode, batch_verify, flush, commit, "
+        "self_event and the accel spans build, snapshot (delta_scan + "
+        "pack), dispatch, readback, apply. Wall minus CPU is time the "
+        "thread did not run: GIL, sleep, device wait. Empty on a "
+        "simulated clock.",
     ),
     Instrument(
         "core_lock_wait_seconds", _H, (), "node",
@@ -423,7 +441,7 @@ CATALOG: Tuple[Instrument, ...] = (
     Instrument(
         "accel_stage_seconds", _H, ("stage",), "accel",
         "Per-stage device-sweep time: build, delta_scan, pack, dispatch, "
-        "kernel, readback, apply.",
+        "readback, wake, result_idle, apply.",
     ),
     Instrument(
         "accel_sweeps_total", _C, (), "accel",
@@ -564,13 +582,28 @@ SYNC_STAGES = (
     "request_sync", "decode", "batch_verify", "insert", "divide_rounds",
     "decide_fame", "round_received", "commit", "proxy_deliver",
     "process_sig_pool", "diff", "eager_sync", "mempool_drain",
+    "self_event", "sync", "prepare_sync", "flush", "record_heads",
+)
+# COARSE spans open at most a few times per sync: obs/trace.py also
+# reads the thread CPU clock and writes a profiler annotation for them.
+# Sync stages first, then the accel stages that run as spans on the
+# flushing thread: `snapshot` is the span around WindowState's
+# delta_scan + pack (or its rebuild), which time themselves; wake and
+# result_idle are waits between threads, recorded after the fact.
+COARSE_STAGES = (
+    "sync", "prepare_sync", "decode", "batch_verify", "flush", "commit",
     "self_event",
+    "build", "snapshot", "dispatch", "readback", "apply",
 )
 TX_STAGES = ("mempool_wait", "consensus")
 ACCEL_STAGES = (
-    "build", "delta_scan", "pack", "dispatch", "kernel", "readback",
-    "apply",
+    "build", "delta_scan", "pack", "dispatch", "readback", "wake",
+    "result_idle", "apply",
 )
+# SweepBatcher.stats() batch_stage_ms / batch_stage_cpu_ms keys: one
+# window's life inside the batcher thread (docs/observability.md
+# §Reading a sweep)
+BATCH_STAGES = ("queue", "launch", "read")
 # Profiler stage buckets (obs/profile.py): the union of the two stage
 # families above plus the sampler-only buckets.
 PROFILE_STAGES = SYNC_STAGES + ACCEL_STAGES + ("lock_wait", "idle", "other")

@@ -63,7 +63,7 @@ _FRAME_TABLE: Dict[str, Tuple[str, str]] = {
     "process_decided_rounds": ("hashgraph/hashgraph.py", "commit"),
     "commit": ("node/core.py", "proxy_deliver"),
     "add_self_event": ("node/core.py", "self_event"),
-    "process_sig_pool": ("node/node.py", "process_sig_pool"),
+    "process_sig_pool": ("node/core.py", "process_sig_pool"),
     "_pull": ("node/node.py", "request_sync"),
     "_push": ("node/node.py", "eager_sync"),
     # accel stages (hashgraph/accel.py + ops/voting.py)
@@ -72,7 +72,7 @@ _FRAME_TABLE: Dict[str, Tuple[str, str]] = {
     "_dispatch": ("hashgraph/accel.py", "dispatch"),
     "_dispatch_snap": ("hashgraph/accel.py", "dispatch"),
     "_compile_bucket": ("hashgraph/accel.py", "dispatch"),
-    "_flush": ("hashgraph/accel.py", "kernel"),
+    "_flush": ("hashgraph/accel.py", "dispatch"),
     "apply_sweep_result": ("", "apply"),
     # lock wait: the instrumented core lock only — a thread inside
     # TimedLock.acquire is by definition waiting on the core lock

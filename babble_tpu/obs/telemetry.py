@@ -72,12 +72,21 @@ class NodeTelemetry:
         self.lock_wait = self._histogram(
             "core_lock_wait_seconds", STAGE_BUCKETS
         )
-        # Pre-resolved per-stage children so the hot path pays one dict
-        # get, not a labels() call.
-        self._stage_children: Dict[str, object] = {}
+        # The span tree's sinks: inclusive, self and (coarse spans only)
+        # thread-CPU seconds per stage. On a simulated clock the tracer
+        # gets no CPU sink and no owner, so it reads no real clock and
+        # writes no profiler annotation (same-seed digests stay equal).
+        wall = self._wall = self.clock is WALL
+        v = core.validator
+        cpu_hist = self._histogram("sync_stage_cpu_seconds", STAGE_BUCKETS)
         self.tracer = Tracer(
-            stage_sink=self._observe_stage_hist,
+            stage_sink=self._stage_sink(self._sync_stage),
             clock=self.clock.perf_counter,
+            self_sink=self._stage_sink(
+                self._histogram("sync_stage_self_seconds", STAGE_BUCKETS)
+            ),
+            cpu_sink=self._stage_sink(cpu_hist) if wall else None,
+            owner=(v.moniker or v.public_key_hex()[:16]) if wall else None,
         )
         # Per-transaction commit provenance (docs/observability.md
         # §"Causal tracing"): admit/drain/first-seen/commit stamps keyed
@@ -90,7 +99,7 @@ class NodeTelemetry:
 
         # The observer the pipeline code null-checks: None when disabled
         # so instrumented code skips even its perf_counter reads.
-        self.stage_observer = self.tracer.observe if self.enabled else None
+        self.stage_observer = self.tracer if self.enabled else None
         self.lock_wait_observer = (
             self.lock_wait.observe if self.enabled else None
         )
@@ -117,17 +126,25 @@ class NodeTelemetry:
 
     # -- stage observation --------------------------------------------------
 
-    def _observe_stage_hist(self, stage: str, seconds: float) -> None:
-        child = self._stage_children.get(stage)
-        if child is None:
-            child = self._sync_stage.labels(stage=stage)
-            self._stage_children[stage] = child
-        child.observe(seconds)
+    @staticmethod
+    def _stage_sink(hist):
+        """``fn(stage, seconds)`` observing into ``hist{stage}``, with the
+        per-stage children pre-resolved so the hot path pays one dict
+        get, not a labels() call."""
+        children: Dict[str, object] = {}
+
+        def observe(stage: str, seconds: float) -> None:
+            child = children.get(stage)
+            if child is None:
+                child = children[stage] = hist.labels(stage=stage)
+            child.observe(seconds)
+
+        return observe
 
     def observe_stage(self, stage: str, seconds: float) -> None:
         """Histogram + active-trace stage record (no-op when disabled)."""
         if self.stage_observer is not None:
-            self.stage_observer(stage, seconds)
+            self.stage_observer.observe(stage, seconds)
 
     def start_sync_trace(self, peer_id: int, kind: str = "sync"):
         if not self.enabled:
@@ -290,17 +307,14 @@ class NodeTelemetry:
 
     def _wire_accel(self, accel) -> None:
         hist = self._histogram("accel_stage_seconds", STAGE_BUCKETS)
-        children: Dict[str, object] = {}
-
-        def observe(stage: str, seconds: float) -> None:
-            child = children.get(stage)
-            if child is None:
-                child = hist.labels(stage=stage)
-                children[stage] = child
-            child.observe(seconds)
-
         if self.enabled:
-            accel.stage_observer = observe
+            accel.stage_observer = self._stage_sink(hist)
+            if self._wall:
+                # the accel stages become coarse spans of THIS tree (CPU
+                # seconds, profiler annotations, children of `flush`);
+                # accel times itself on the wall clock, so a simulated
+                # node keeps the engine's own bare tracer
+                accel.spans = self.tracer
         self._func("accel_sweeps_total", lambda: accel.sweeps)
         self._func("accel_fallbacks_total", lambda: accel.fallbacks)
         self._func(

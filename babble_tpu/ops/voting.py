@@ -162,20 +162,11 @@ def pallas_mode() -> Optional[str]:
     return "tpu" if pallas_requested() else None
 
 
-def _fame_core(creator, index, la_w, fd_w, rounds_w, valid_w, fame0_w, mid_w,
-               wit_idx, member, sm_s, psi, sm_r):
-    """Virtual voting on the witness axis (oracle: hashgraph.go:875-998)
-    with per-round peer-sets. Returns (see_we, fame_w); ``see_we`` ([W, E],
-    witness w sees event x) stays on device for the round-received kernel."""
-    R = psi.shape[0]
-
-    # SEE[w, x] = w sees x via lastAncestors (oracle: hashgraph.go:96-128).
-    see_we = (la_w[:, creator] >= index[None, :]) & valid_w[:, None]
-    see_ww = see_we[:, wit_idx]  # witness-to-witness visibility
-
-    # SS[s, w, w'] per peer-set slot (oracle: hashgraph.go:172-206 with the
-    # per-round peer-set argument). [W, W, P] compare stays small because W
-    # is the witness count, not the event count.
+def _strongly_see_counts(la_w, fd_w, member):
+    """counts[s, w, w'] = peers of slot s through which w strongly sees w'
+    (oracle: hashgraph.go:172-206 with the per-round peer-set argument).
+    The [W, W, P] compare stays small because W is the witness count, not
+    the event count."""
     mode = pallas_mode()
     if mode is not None:
         # Pallas tiled kernel: streams the peer axis through VMEM, no
@@ -198,6 +189,22 @@ def _fame_core(creator, index, la_w, fd_w, rounds_w, valid_w, fame0_w, mid_w,
             member.astype(jnp.int8),
             preferred_element_type=jnp.int32,
         )
+    return counts
+
+
+def _fame_core(creator, index, la_w, fd_w, rounds_w, valid_w, fame0_w, mid_w,
+               wit_idx, member, sm_s, psi, sm_r):
+    """Virtual voting on the witness axis (oracle: hashgraph.go:875-998)
+    with per-round peer-sets. Returns (see_we, fame_w); ``see_we`` ([W, E],
+    witness w sees event x) stays on device for the round-received kernel."""
+    R = psi.shape[0]
+
+    # SEE[w, x] = w sees x via lastAncestors (oracle: hashgraph.go:96-128).
+    see_we = (la_w[:, creator] >= index[None, :]) & valid_w[:, None]
+    see_ww = see_we[:, wit_idx]  # witness-to-witness visibility
+
+    with jax.named_scope("strongly_see_counts"):
+        counts = _strongly_see_counts(la_w, fd_w, member)
     ss_all = counts >= sm_s[:, None, None]  # [S, W, W]
 
     def per_round(j, state):
@@ -283,10 +290,11 @@ def _sweep_core(creator, index, la_w, fd_w, rounds_w, valid_w, fame0_w, mid_w,
     an unreadable round blocks unconditionally; an undecided round blocks
     only above the fast-sync lower bound.
     """
-    see_we, fame = _fame_core(
-        creator, index, la_w, fd_w, rounds_w, valid_w, fame0_w, mid_w,
-        wit_idx, member, sm_s, psi, sm_r,
-    )
+    with jax.named_scope("fame"):
+        see_we, fame = _fame_core(
+            creator, index, la_w, fd_w, rounds_w, valid_w, fame0_w, mid_w,
+            wit_idx, member, sm_s, psi, sm_r,
+        )
     R = psi.shape[0]
     r_ax = jnp.arange(R)
     m_rw = valid_w[None, :] & (rounds_w[None, :] == r_ax[:, None])  # [R, W]
@@ -295,28 +303,32 @@ def _sweep_core(creator, index, la_w, fd_w, rounds_w, valid_w, fame0_w, mid_w,
     cnt = jnp.sum(m_rw & (~undecided_w)[None, :], axis=1, dtype=jnp.int32)
     decided_r = prior_dec_r | (exists_r & ~has_undec & (cnt >= sm_r))
     hard_block_r = (~exists_r) | ((~decided_r) & lb_gate_r)
-    rr = _rr_core(see_we, rounds_w, valid_w, fame, rounds_e, undet_e,
-                  decided_r, hard_block_r, sm_r)
+    with jax.named_scope("round_received"):
+        rr = _rr_core(see_we, rounds_w, valid_w, fame, rounds_e, undet_e,
+                      decided_r, hard_block_r, sm_r)
     return jnp.concatenate([fame, rr])
 
 
-# Counts traces so tests can pin the compile-cache property.
-_trace_count = 0
+# The two compiled programs. A jitted function's __name__ is the program's
+# name in a profiler trace (``jit_<name>``), and the benchmark finds the
+# sweep's device time by it: both contain ``counting_sweep``, and the two
+# are told apart by what follows.
 
 
-def _counting_sweep(*args):
-    global _trace_count
-    _trace_count += 1
+def counting_sweep_single(*args):
     return _sweep_core(*args)
 
 
-_sweep_jit = jax.jit(_counting_sweep)
+def counting_sweep_batched(*args):
+    """The SAME fused program vmapped over a leading batch axis, so
+    co-located nodes' windows ride ONE device dispatch and ONE readback
+    (hashgraph/sweep_batcher.py). Exact per-window semantics: vmap adds a
+    batch dimension, it never mixes rows."""
+    return jax.vmap(_sweep_core)(*args)
 
-# Batched sweep: the SAME fused program vmapped over a leading batch axis,
-# so co-located nodes' windows ride ONE device dispatch and ONE readback
-# (hashgraph/sweep_batcher.py). Exact per-window semantics: vmap adds a
-# batch dimension, it never mixes rows.
-_batched_sweep_jit = jax.jit(jax.vmap(_counting_sweep))
+
+_sweep_jit = jax.jit(counting_sweep_single)
+_batched_sweep_jit = jax.jit(counting_sweep_batched)
 
 
 # =============================================================================
@@ -524,6 +536,20 @@ def bucket_key(win: VotingWindow) -> tuple:
         win.member.shape[0],
         win.psi.shape[0],
     )
+
+
+def bucket_label(key: tuple, batch: int = 1) -> str:
+    """``BxWxExPxSxR``: the name a launch of the program at bucket ``key``
+    (``batch`` windows to a vmapped execution) is counted under in the
+    ``*_bucket_launches`` stats. It holds no ``.``."""
+    return "x".join(str(int(d)) for d in (batch,) + tuple(key))
+
+
+def count_launch(launches: Dict[str, int], key: tuple, batch: int = 1) -> None:
+    """Count one launch of the program at bucket ``key`` in ``launches``,
+    the ``bucket_launches`` tally of whoever launched it."""
+    label = bucket_label(key, batch)
+    launches[label] = launches.get(label, 0) + 1
 
 
 def repad_window(win: VotingWindow, key: tuple) -> VotingWindow:

@@ -190,10 +190,219 @@ def test_staged_decorator_null_observer_is_clockless():
 
     o = Obj()
     assert o.work(3) == 6
-    o.stage_observer = lambda s, d: calls.append((s, d))
+    o.stage_observer = Tracer(stage_sink=lambda s, d: calls.append((s, d)))
     assert o.work(4) == 8
     assert len(calls) == 1 and calls[0][0] == "insert"
     assert calls[0][1] >= 0.0
+
+
+# -- the span tree -----------------------------------------------------------
+
+
+class _FakeClock:
+    """A clock the test advances by hand."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self):
+        return self.now
+
+
+def _tree_tracer():
+    clock = _FakeClock()
+    sinks = {"incl": [], "self": [], "cpu": []}
+    tracer = Tracer(
+        stage_sink=lambda s, d: sinks["incl"].append((s, d)),
+        self_sink=lambda s, d: sinks["self"].append((s, d)),
+        clock=clock,
+    )
+    return tracer, clock, sinks
+
+
+def test_span_tree_nesting_and_self_time():
+    """A span's self time is its duration minus what its children
+    covered; the children's own children count for the children only."""
+    tracer, clock, sinks = _tree_tracer()
+    tr = tracer.start("sync", 3)
+    with tracer.span("sync") as root:
+        clock.now += 1.0
+        with tracer.span("flush") as flush:
+            assert flush.parent is root
+            clock.now += 2.0
+            with tracer.span("commit") as commit:
+                assert commit.parent is flush
+                clock.now += 4.0
+            tracer.observe("request_sync", 0.5)  # a leaf measured elsewhere
+            clock.now += 0.5
+        clock.now += 8.0
+    assert root.parent is None
+    assert (root.t0, root.t1) == (0.0, 15.5)
+    assert dict(sinks["incl"]) == {
+        "commit": 4.0, "request_sync": 0.5, "flush": 6.5, "sync": 15.5}
+    assert dict(sinks["self"]) == {
+        "commit": 4.0, "request_sync": 0.5, "flush": 2.0, "sync": 9.0}
+    # self times sum to the root's duration: every second counted once
+    assert sum(d for _s, d in sinks["self"]) == root.seconds
+    tr.finish()
+    rec = tracer.recent()[-1]
+    assert [s for s, _ in rec["stages"]] == [
+        "commit", "request_sync", "flush", "sync"]
+    assert dict(map(tuple, rec["self_ms"]))["sync"] == 9000.0
+    assert dict(map(tuple, rec["stages"]))["sync"] == 15500.0
+
+
+def test_span_stacks_are_per_thread():
+    import threading
+
+    tracer, clock, _sinks = _tree_tracer()
+    seen = {}
+
+    def other():
+        with tracer.span("insert") as sp:
+            seen["parent"] = sp.parent
+
+    with tracer.span("sync"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(10)
+    assert seen == {"parent": None}
+
+
+def test_custom_sink_span_is_a_child_but_not_a_sync_stage():
+    """The accel stages feed their own histogram, and still count as
+    children of the span around them."""
+    tracer, clock, sinks = _tree_tracer()
+    accel = []
+    with tracer.span("flush"):
+        with tracer.span("apply", sink=lambda s, d: accel.append((s, d))):
+            clock.now += 3.0
+        clock.now += 1.0
+    assert accel == [("apply", 3.0)]
+    assert sinks["incl"] == [("flush", 4.0)]
+    assert sinks["self"] == [("flush", 1.0)]
+
+
+def test_coarse_spans_read_cpu_and_annotate_only_when_wired(monkeypatch):
+    """CPU reads and profiler annotations: coarse spans only, and only
+    on a tracer that was given a CPU sink / an owner (a simulated clock
+    gets neither)."""
+    import babble_tpu.obs.trace as trace_mod
+
+    entered = []
+
+    class _Ann:
+        def __init__(self, stage, owner):
+            self.what = (stage, owner)
+
+        def __enter__(self):
+            entered.append(self.what)
+
+        def __exit__(self, *exc):
+            entered.append("exit")
+
+    monkeypatch.setattr(trace_mod, "annotation", _Ann)
+    cpu = []
+    wired = Tracer(cpu_sink=lambda s, d: cpu.append(s), owner="v7")
+    with wired.span("sync"):
+        with wired.span("insert"):  # per-event: neither
+            pass
+    assert cpu == ["sync"]
+    assert entered == [("sync", "v7"), "exit"]
+
+    def boom():
+        raise AssertionError("CPU clock read on an unwired tracer")
+
+    monkeypatch.setattr(trace_mod.time, "thread_time", boom)
+    del entered[:]
+    with Tracer().span("sync"):
+        pass
+    assert entered == []
+
+
+def _stage_sum(node, name, stage):
+    return node.telemetry.registry.histogram_summary(name, stage=stage)["sum"]
+
+
+def test_self_event_no_longer_double_counts_its_insert():
+    """``self_event`` contains its own insert + divide_rounds (+ flush):
+    inclusive it still does, self it is what none of them covers, and
+    ``record_heads`` around it has only its loop left."""
+    node = _tiny_node()
+    try:
+        core = node.core
+        core.add_self_event("")
+        core.heads[core.validator.id()] = None
+        core.record_heads()
+        incl = {s: _stage_sum(node, "sync_stage_seconds", s)
+                for s in ("self_event", "insert", "divide_rounds",
+                          "mempool_drain", "flush", "record_heads")}
+        self_s = {s: _stage_sum(node, "sync_stage_self_seconds", s)
+                  for s in ("self_event", "record_heads")}
+        children = (incl["insert"] + incl["divide_rounds"]
+                    + incl["mempool_drain"] + incl["flush"])
+        assert incl["self_event"] >= children > 0
+        assert self_s["self_event"] == pytest.approx(
+            incl["self_event"] - children, abs=2e-5)  # sums round to 1 us
+        assert self_s["record_heads"] < incl["record_heads"]
+        # the CPU clock: coarse spans only
+        reg = node.telemetry.registry
+        assert reg.get("sync_stage_cpu_seconds", stage="self_event") == 2
+        assert reg.get("sync_stage_cpu_seconds", stage="insert") == 0
+    finally:
+        node.shutdown()
+
+
+def test_kill_switch_skips_every_clock_read(monkeypatch):
+    """BABBLE_OBS=0: no span opens, so the ingest path reads neither the
+    stage clock nor the CPU clock."""
+    import babble_tpu.obs.metrics as metrics_mod
+    import babble_tpu.obs.trace as trace_mod
+    from babble_tpu.common.clock import WallClock
+
+    monkeypatch.setattr(metrics_mod, "_ENABLED", False)
+    node = _tiny_node()
+    try:
+        core = node.core
+        assert core.stage_observer is None
+        assert core._span("self_event") is trace_mod.NULL_STAGE
+
+        def boom(*_a):
+            raise AssertionError("clock read under BABBLE_OBS=0")
+
+        monkeypatch.setattr(WallClock, "perf_counter", boom)
+        monkeypatch.setattr(trace_mod.time, "thread_time", boom)
+        before = core.seq
+        core.add_self_event("")
+        core.process_sig_pool()
+        core.prepare_sync([])
+        assert core.seq == before + 1
+    finally:
+        monkeypatch.undo()
+        node.shutdown()
+
+
+def test_sim_clock_tracer_reads_no_real_clock_and_digests_repeat():
+    """On a simulated clock the tracer gets no CPU sink and no owner, so
+    same-seed runs keep byte-identical telemetry (self times included)."""
+    from babble_tpu.sim.scenario import ScenarioSpec, run_scenario
+
+    spec = ScenarioSpec(seed=77, nodes=3, duration_s=0.6, heartbeat_s=0.08,
+                        tx_rate=5, settle_s=0.6)
+    r1, r2 = run_scenario(spec), run_scenario(spec)
+    assert r1.telemetry_digest == r2.telemetry_digest
+    assert r1.event_log_digest == r2.event_log_digest
+
+    from babble_tpu.sim.harness import SimCluster
+    from babble_tpu.sim.scheduler import SimScheduler
+
+    cluster = SimCluster(SimScheduler(5), 2, heartbeat_s=0.05)
+    try:
+        tracer = cluster.nodes[0].telemetry.tracer
+        assert tracer.cpu_sink is None and tracer.owner is None
+        assert tracer.self_sink is not None
+    finally:
+        cluster.shutdown()
 
 
 # -- mempool latency feed ----------------------------------------------------

@@ -285,3 +285,150 @@ def test_target_bucket_decay_resets_on_regrowth():
     b._update_target(mid)
     assert b._target == mid
     assert b.target_decays == 1
+
+
+# -- a window's life: stamps, stage sums, the bucket that ran ----------------
+
+
+def test_ticket_stamps_are_monotone_and_sum_inside_the_owners_wait():
+    """submit <= taken <= launched <= read, taken a coalesce interval after
+    submit, and queue + launch + read (what SweepBatcher.stats() sums) is
+    no more than the owner waited for its ticket."""
+    import time
+
+    from babble_tpu.hashgraph.sweep_batcher import SweepBatcher
+
+    win = _two_windows()[0]
+    voting.precompile(*voting.bucket_key(win))
+    svc = SweepBatcher()
+    t0 = time.perf_counter()
+    t = svc.submit(win)
+    assert t.done.wait(60) and t.error is None, t.error
+    waited = time.perf_counter() - t0
+    assert t0 <= t.t_submit <= t.t_taken <= t.t_launched <= t.t_read
+    assert t.t_taken - t.t_submit >= SweepBatcher.COALESCE_S
+    assert t.c_taken <= t.c_launched <= t.c_read  # the batcher's CPU clock
+    stats = svc.stats()
+    life = sum(stats["batch_stage_ms"].values())
+    assert set(stats["batch_stage_ms"]) == {"queue", "launch", "read"}
+    assert life == pytest.approx(1e3 * (t.t_read - t.t_submit), abs=0.01)
+    assert 0 < life <= 1e3 * waited
+    cpu = stats["batch_stage_cpu_ms"]
+    assert set(cpu) == {"launch", "read"}
+    assert 0 <= cpu["launch"] <= stats["batch_stage_ms"]["launch"] + 1.0
+    # one window served: the sums are per window, like batch_windows
+    assert stats["batch_windows"] == 1
+
+
+def test_failed_ticket_is_stamped_but_not_summed(monkeypatch):
+    from babble_tpu.hashgraph.sweep_batcher import SweepBatcher
+
+    win = _two_windows()[0]
+    svc = SweepBatcher()
+
+    def boom(*a, **k):
+        raise RuntimeError("device fell off the bus")
+
+    monkeypatch.setattr(voting, "launch_sweep", boom)
+    t = svc.submit(win)
+    assert t.done.wait(30) and isinstance(t.error, RuntimeError)
+    assert t.t_read >= t.t_taken >= t.t_submit
+    stats = svc.stats()
+    assert stats["batch_stage_ms"] == {"queue": 0.0, "launch": 0.0,
+                                       "read": 0.0}
+    assert stats["batch_bucket_launches"] == {}
+
+
+def test_bucket_launches_count_the_bucket_that_ran():
+    """A lone window counts 1x<its own bucket>; a batched wave counts ONE
+    launch of 16x<the target bucket> — not the windows' own buckets."""
+    from babble_tpu.hashgraph.sweep_batcher import SweepBatcher
+
+    wins = _two_windows()
+    key = voting.bucket_key(wins[0])
+    B = SweepBatcher.MAX_BATCH
+    big = voting.repad_window(wins[1], (key[0] * 2,) + key[1:])
+    target = (key[0] * 2,) + key[1:]
+    voting.precompile_batched(B, *target)
+
+    svc = SweepBatcher()
+    t0 = svc.submit(wins[0])
+    assert t0.done.wait(60) and t0.error is None, t0.error
+    single = voting.bucket_label(key)
+    assert single == "1x" + "x".join(str(d) for d in key)
+    assert "." not in single  # the harness flattens dotted names
+    assert svc.stats()["batch_bucket_launches"] == {single: 1}
+
+    t1, t2 = svc.submit(wins[0]), svc.submit(big)
+    assert t1.done.wait(60) and t2.done.wait(60)
+    assert t1.batch_size == 2 and t2.batch_size == 2
+    assert svc.stats()["batch_bucket_launches"] == {
+        single: 1, voting.bucket_label(target, B): 1}
+    assert voting.bucket_label(target, B).startswith(f"{B}x{key[0] * 2}x")
+    # three windows served, two launches
+    assert svc.stats()["batch_windows"] == 3
+    # both windows of the wave were launched by the one program
+    assert t1.t_launched == t2.t_launched
+
+
+def test_engine_counts_its_own_launches_and_names_its_threads():
+    """Without the batcher a TensorConsensus launches its own programs and
+    counts them under accel_bucket_launches; with it, the reader thread
+    carries the owner's name and the owner records wake + result_idle."""
+    from tests.test_accel import BUILDERS, _ordered_events
+    from babble_tpu.hashgraph import Event, Hashgraph, InmemStore
+    from babble_tpu.hashgraph.accel import TensorConsensus
+
+    h0, index, nodes, peer_set = BUILDERS["consensus"]()
+    ordered = _ordered_events(h0)
+
+    def replay(**kw):
+        h = Hashgraph(InmemStore(1000))
+        h.init(peer_set)
+        h.accel = TensorConsensus(sweep_events=8, async_compile=False,
+                                  min_window=0, owner="v3", **kw)
+        names = set()
+        for ev in ordered:
+            h.insert_event_and_run_consensus(
+                Event(ev.body, ev.signature), set_wire_info=True)
+            names |= {t.name for t in threading.enumerate()}
+        for _ in range(200):
+            h.flush_consensus()
+            if not h.accel.busy():
+                break
+            h.accel._inflight.done.wait(10)
+        return h.accel, names
+
+    own, _ = replay(batcher=False, pipeline=False)
+    s = own.stats()
+    assert s["accel_sweeps"] > 0
+    assert sum(s["accel_bucket_launches"].values()) == s["accel_sweeps"]
+    assert all(k.startswith("1x") for k in s["accel_bucket_launches"])
+    assert "kernel" not in s["accel_stage_ms"]
+    assert s["accel_stage_ms"]["wake"] == 0  # no batcher: nobody to wake
+
+    piped, names = replay(batcher=True, pipeline=True)
+    s = piped.stats()
+    assert s["accel_sweeps"] > 0 and s["accel_fallbacks"] == 0
+    assert s["accel_bucket_launches"] == {}  # the batcher launched them
+    assert "v3:sweep-reader" in names
+    st = piped.stage_s
+    assert st["wake"] > 0 and st["result_idle"] > 0
+    assert st["wake"] <= st["readback"]
+
+
+def test_the_two_sweep_programs_have_stable_distinct_names():
+    """A profiler trace finds a program by its jit name: both contain
+    ``counting_sweep`` (the benchmark's ``sweep_device_us`` matches it) and
+    they differ, so single and vmapped executions can be told apart."""
+    win = _two_windows()[0]
+    args = [np.asarray(getattr(win, f)) for f in voting._WIN_FIELDS]
+    single = voting._sweep_jit.lower(*args).as_text()
+    batched = voting._batched_sweep_jit.lower(
+        *(np.stack([a, a]) for a in args)).as_text()
+    assert "module @jit_counting_sweep_single" in single
+    assert "module @jit_counting_sweep_batched" in batched
+    # the fused program's stages are named scopes inside it
+    debug = voting._sweep_jit.lower(*args).as_text(debug_info=True)
+    for scope in ("fame", "strongly_see_counts", "round_received"):
+        assert scope in debug, scope
