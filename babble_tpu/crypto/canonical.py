@@ -101,6 +101,18 @@ def canonical_dumps(obj: Any) -> bytes:
     ).encode("utf-8")
 
 
+def canonical_scalar(v: Any) -> bytes:
+    """canonical_dumps(v) for a value that is as a rule a plain int or
+    bool (a round, a timestamp, a flag spliced into a larger encoding);
+    whatever else it turns out to be goes the plain way."""
+    t = type(v)
+    if t is int:
+        return b"%d" % v
+    if t is bool:
+        return b"true" if v else b"false"
+    return canonical_dumps(v)
+
+
 def canonical_loads(data: bytes) -> Any:
     return json.loads(data.decode("utf-8"))
 

@@ -320,6 +320,9 @@ class TensorConsensus:
         self.bucket_launches: dict = {}
         self._inflight: Optional[_Inflight] = None
         self._compiling = set()
+        # bucket compiles running because a flush of this engine met a
+        # compile wait (the (key, use_mesh) gates of _compiling)
+        self._awaited_compiles = 0
         self._lock = threading.Lock()
 
     def _stage(self, stage: str, seconds: float) -> None:
@@ -377,8 +380,14 @@ class TensorConsensus:
 
     def busy(self) -> bool:
         """True while decisions are pending on an in-flight sweep — keeps
-        the node's fast heartbeat ticking so the next flush applies them."""
-        return self._inflight is not None
+        the node's fast heartbeat ticking so the next flush applies them —
+        and while a bucket compile that a compile wait of this engine
+        kicked is still running: a flush that applied a result and whose
+        relaunch then met that wait has reported "handled" with the
+        newest events still undecided, and nothing else tells a drain (or
+        a quiet node) to flush again. It is False again once every such
+        compile is over, or has failed."""
+        return self._inflight is not None or self._awaited_compiles > 0
 
     def invalidate(self) -> None:
         """Drop any in-flight sweep (hashgraph reset / fast-sync landing):
@@ -475,6 +484,7 @@ class TensorConsensus:
             kick = gate not in self._compiling
             if kick:
                 self._compiling.add(gate)
+                self._awaited_compiles += 1
         if kick:
             threading.Thread(
                 target=self._compile_bucket, args=(key, use_mesh),
@@ -507,6 +517,7 @@ class TensorConsensus:
         finally:
             with self._lock:
                 self._compiling.discard((key, use_mesh))
+                self._awaited_compiles -= 1
 
     # -- flush entry point ---------------------------------------------------
 

@@ -19,7 +19,12 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-from babble_tpu.crypto.canonical import CacheStats, canonical_dumps
+from babble_tpu.crypto.canonical import (
+    CacheStats,
+    PreNormalized,
+    canonical_dumps,
+    canonical_scalar,
+)
 from babble_tpu.crypto.hashing import sha256
 from babble_tpu.crypto.keys import PrivateKey, PublicKey, decode_signature
 from babble_tpu.hashgraph.internal_transaction import InternalTransaction
@@ -72,6 +77,10 @@ class EventBody:
     self_parent_index: int = -1
     other_parent_index: int = -1
 
+    # canonical_json() memo; no annotation, so a plain class attribute and
+    # not a dataclass field
+    _json = None
+
     def normalized(self) -> dict:
         """Canonically normalized to_dict (bytes already base64), memoized.
         Frames re-encode every contained event body per decided round
@@ -84,6 +93,18 @@ class EventBody:
 
     def invalidate_normalized(self) -> None:
         self._norm = None
+        self._json = None
+
+    def canonical_json(self) -> bytes:
+        """The body's canonical encoding, memoized beside ``_norm``: what
+        hash() digests at insert is what every Frame that carries the
+        event splices into its own encoding (FrameEvent.canonical_text).
+        It depends on the body alone, so it may sit on a body that
+        several Events share."""
+        j = self._json
+        if j is None:
+            j = self._json = canonical_dumps(PreNormalized(self.normalized()))
+        return j
 
     def to_dict(self) -> dict:
         return {
@@ -100,9 +121,7 @@ class EventBody:
         """SHA256 of the canonical encoding (reference: event.go:57-64).
         Shares the normalized memo with the frame/wire encoders, so the
         b64 walk happens once per body however it is consumed."""
-        from babble_tpu.crypto.canonical import PreNormalized
-
-        return sha256(canonical_dumps(PreNormalized(self.normalized())))
+        return sha256(self.canonical_json())
 
     @staticmethod
     def from_dict(d: dict) -> "EventBody":
@@ -179,6 +198,102 @@ class WireBlockSignature:
         return WireBlockSignature(index=d["Index"], signature=d["Signature"])
 
 
+class FrameForm:
+    """What Frames need of one event, made once and kept on the Event
+    (``Event._frame``, beside ``_hash`` and ``_wire``; its lifetime is the
+    store's own event cache): the canonical text of the event's FrameEvent
+    and the R of its signature, the frame sort's tie-break.
+
+    An event enters one Frame as an event and the Roots of the Frames
+    after it ROOT_DEPTH + 1 times over; every time it is the same text.
+    The form is stamped with all the text was made from — round, Lamport
+    timestamp, witness flag, signature, the body's encoding — and answers
+    only for exactly those (``fits``), so one that no longer matches its
+    event can only miss. It carries a round and a Lamport timestamp, which
+    belong to one Hashgraph's view of the event: it sits on the Event,
+    never on an EventBody that several Events may share."""
+
+    __slots__ = ("round", "lamport_timestamp", "witness", "signature",
+                 "body_json", "_text", "_r")
+
+    def __init__(self, core: "Event", round: int, lamport_timestamp: int,
+                 witness: bool):
+        self.round = round
+        self.lamport_timestamp = lamport_timestamp
+        self.witness = witness
+        self.signature = core.signature
+        self.body_json = core.body.canonical_json()
+        self._text: Optional[bytes] = None
+        self._r: Optional[int] = None
+
+    @staticmethod
+    def of(core: "Event", round: int, lamport_timestamp: int,
+           witness: bool) -> "FrameForm":
+        """The core's form if it fits these annotations, else a new one,
+        kept on the core when the annotations are a plain int, int and
+        bool (1 == True, but their texts differ: a form that could answer
+        for the other is not kept)."""
+        form = core._frame
+        if form is not None and form.fits(core, round, lamport_timestamp,
+                                          witness):
+            return form
+        form = FrameForm(core, round, lamport_timestamp, witness)
+        if (type(round) is int and type(lamport_timestamp) is int
+                and type(witness) is bool):
+            core._frame = form
+        return form
+
+    def fits(self, core: "Event", round: int, lamport_timestamp: int,
+             witness: bool) -> bool:
+        return (
+            self.witness is witness
+            and type(round) is int
+            and type(lamport_timestamp) is int
+            and self.round == round
+            and self.lamport_timestamp == lamport_timestamp
+            and self.signature == core.signature
+            and self.body_json is core.body._json
+        )
+
+    def text(self) -> bytes:
+        """canonical_dumps(FrameEvent.to_dict()), spliced around the
+        body's own encoding instead of encoding the body again."""
+        t = self._text
+        if t is None:
+            t = self._text = (
+                b'{"Core":{"Body":%b,"Signature":%b},"LamportTimestamp":%b,'
+                b'"Round":%b,"Witness":%b}'
+            ) % (
+                self.body_json,
+                canonical_dumps(self.signature),
+                canonical_scalar(self.lamport_timestamp),
+                canonical_scalar(self.round),
+                canonical_scalar(self.witness),
+            )
+        return t
+
+    def r(self) -> int:
+        r = self._r
+        if r is None:
+            r = self._r = _parse_signature_r(self.signature)
+        return r
+
+
+def _parse_signature_r(sig: str) -> int:
+    """``decode_signature(sig)[0]``, or 0 for a signature it rejects. Two
+    plain base-36 numbers (what ``encode_signature`` writes) are checked
+    and R alone is parsed, by the built-in; anything else — a sign, blanks,
+    a number over int()'s digit limit — goes through decode_signature."""
+    r, _, s = sig.partition("|")
+    if (r.isascii() and r.isalnum() and s.isascii() and s.isalnum()
+            and len(r) <= 128):
+        return int(r, 36)
+    try:
+        return decode_signature(sig)[0]
+    except ValueError:
+        return 0
+
+
 class Event:
     """EventBody + creator signature + local-only consensus annotations
     (reference: event.go:102-142)."""
@@ -197,6 +312,7 @@ class Event:
         "_hex",
         "_sig_ok",
         "_wire",
+        "_frame",
     )
 
     def __init__(self, body: EventBody, signature: str = ""):
@@ -213,6 +329,7 @@ class Event:
         self._hex: str = ""
         self._sig_ok: Optional[bool] = None
         self._wire: Optional["WireEvent"] = None
+        self._frame: Optional[FrameForm] = None
 
     @staticmethod
     def new(
@@ -290,6 +407,7 @@ class Event:
         self._creator = ""
         self._sig_ok = None
         self._wire = None
+        self._frame = None
         self.body.invalidate_normalized()
 
     # -- signatures --------------------------------------------------------
@@ -298,6 +416,7 @@ class Event:
         """reference: event.go:201-215."""
         self.signature = key.sign(self.hash())
         self._wire = None  # wire form carries the signature
+        self._frame = None  # and so does the frame form
 
     def verify(self) -> bool:
         """Verify the creator's signature AND every internal transaction's
@@ -489,8 +608,6 @@ class FrameEvent:
     witness: bool = False
 
     def to_dict(self) -> dict:
-        from babble_tpu.crypto.canonical import PreNormalized
-
         return {
             # memoized normalized body: frames re-encode the same immutable
             # event bodies per decided round (see EventBody.normalized)
@@ -503,10 +620,16 @@ class FrameEvent:
             "Witness": self.witness,
         }
 
+    def canonical_text(self) -> bytes:
+        """canonical_dumps(self.to_dict()), from the core's frame form:
+        made on the spot, the same way, where the core carries none that
+        fits (a FrameEvent out of from_dict, a mutated one)."""
+        return FrameForm.of(
+            self.core, self.round, self.lamport_timestamp, self.witness
+        ).text()
+
     @staticmethod
     def from_dict(d: dict) -> "FrameEvent":
-        from babble_tpu.crypto.canonical import PreNormalized
-
         body = d["Core"]["Body"]
         if isinstance(body, PreNormalized):
             # in-process round trip of a to_dict (no codec in between)
@@ -529,11 +652,12 @@ def sort_topological(events: List[Event]) -> List[Event]:
 
 
 def _signature_r(e: Event) -> int:
-    try:
-        r, _ = decode_signature(e.signature)
-        return r
-    except ValueError:
-        return 0
+    """R of the event's signature, from its frame form where it has one
+    (once per event, then), else parsed here."""
+    form = e._frame
+    if form is not None and form.signature == e.signature:
+        return form.r()
+    return _parse_signature_r(e.signature)
 
 
 def sort_frame_events(events: List[FrameEvent]) -> List[FrameEvent]:

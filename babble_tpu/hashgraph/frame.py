@@ -8,7 +8,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List
 
-from babble_tpu.crypto.canonical import canonical_dumps
+from babble_tpu.crypto.canonical import canonical_dumps, canonical_scalar
 from babble_tpu.crypto.hashing import sha256
 from babble_tpu.hashgraph.event import FrameEvent, sort_frame_events
 from babble_tpu.peers.peer import Peer
@@ -27,6 +27,12 @@ class Root:
 
     def to_dict(self) -> dict:
         return {"Events": [fe.to_dict() for fe in self.events]}
+
+    def canonical_bytes(self) -> bytes:
+        """canonical_dumps(self.to_dict()), joined from the events' texts."""
+        return b'{"Events":[%b]}' % b",".join(
+            [fe.canonical_text() for fe in self.events]
+        )
 
     @staticmethod
     def from_dict(d: dict) -> "Root":
@@ -65,9 +71,35 @@ class Frame:
             "Timestamp": self.timestamp,
         }
 
+    def canonical_bytes(self) -> bytes:
+        """The canonical encoding, byte for byte canonical_dumps(
+        self.to_dict()) — sorted keys at every level, compact separators —
+        but assembled: every event of the Frame and of its Roots brings
+        the text its frame form holds (FrameEvent.canonical_text), and
+        only the peers and the scalars are encoded here. to_dict() stays
+        the wire and persistence form, and what this is tested against."""
+        return (
+            b'{"Events":[%b],"PeerSets":%b,"Peers":%b,"Roots":{%b},'
+            b'"Round":%b,"Timestamp":%b}'
+        ) % (
+            b",".join([fe.canonical_text() for fe in self.events]),
+            canonical_dumps({
+                str(rnd): [p.to_dict() for p in ps]
+                for rnd, ps in self.peer_sets.items()
+            }),
+            canonical_dumps([p.to_dict() for p in self.peers.peers]),
+            b",".join([
+                b"%b:%b" % (canonical_dumps(k), root.canonical_bytes())
+                for k, root in sorted(self.roots.items())
+            ]),
+            canonical_scalar(self.round),
+            canonical_scalar(self.timestamp),
+        )
+
     def hash(self) -> bytes:
-        """SHA256 of the canonical encoding (reference: frame.go:63-69)."""
-        return sha256(canonical_dumps(self.to_dict()))
+        """SHA256 of the canonical encoding (reference: frame.go:63-69):
+        the one way a Frame is hashed, whoever built it."""
+        return sha256(self.canonical_bytes())
 
     @staticmethod
     def from_dict(d: dict) -> "Frame":

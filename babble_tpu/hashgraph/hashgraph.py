@@ -43,6 +43,7 @@ from babble_tpu.hashgraph.event import (
     EventBody,
     EventCoordinates,
     FrameEvent,
+    FrameForm,
     WireEvent,
     decode_hash,
     sort_frame_events,
@@ -152,6 +153,11 @@ class Hashgraph:
         self.last_committed_round_events = 0
         self.consensus_transactions = 0
         self.pending_loaded_events = 0
+        # _create_frame_event calls answered by the frame form the Event
+        # already carried (its earlier Frame made it), and those that had
+        # to make one. Per hashgraph: co-located validators do not sum.
+        self.frame_event_hits = 0
+        self.frame_event_misses = 0
         self.commit_callback = commit_callback
         self.topological_index = 0
         # Device consensus offload (TensorConsensus), attached by the node's
@@ -1059,17 +1065,26 @@ class Hashgraph:
     # =========================================================================
 
     def _create_frame_event(self, x: str) -> FrameEvent:
-        """reference: hashgraph.go:521-557."""
+        """reference: hashgraph.go:521-557. Also sees to the event's frame
+        form (FrameForm): an event that was in an earlier Frame — every
+        Root event was — still carries the one made then, and its text
+        and sort key are not made again."""
         ev = self.store.get_event(x)
         round_ = self.round(x)
         round_info = self.store.get_round(round_)
         te = round_info.created_events.get(x)
         if te is None:
             raise ValueError(f"round {round_} created_events[{x}] not found")
+        lamport_timestamp = self.lamport_timestamp(x)
+        carried = ev._frame
+        if FrameForm.of(ev, round_, lamport_timestamp, te.witness) is carried:
+            self.frame_event_hits += 1
+        else:
+            self.frame_event_misses += 1
         return FrameEvent(
             core=ev,
             round=round_,
-            lamport_timestamp=self.lamport_timestamp(x),
+            lamport_timestamp=lamport_timestamp,
             witness=te.witness,
         )
 

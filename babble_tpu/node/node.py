@@ -662,6 +662,11 @@ class Node(StateManager):
                 "norm_cache_misses": NORM_CACHE.misses,
                 "verify_cache_hits": VERIFY_CACHE.hits,
                 "verify_cache_misses": VERIFY_CACHE.misses,
+                # frame forms (hashgraph/event.py FrameForm) this
+                # validator's hashgraph reused / made: its own tally,
+                # unlike the three process-wide pairs above
+                "frame_event_hits": self.core.hg.frame_event_hits,
+                "frame_event_misses": self.core.hg.frame_event_misses,
             }
         )
         # Mempool surface (docs/mempool.md): admission verdict counters,

@@ -221,6 +221,17 @@ CATALOG: Tuple[Instrument, ...] = (
         "Transactions carried by consensus events so far.",
     ),
     Instrument(
+        "frame_event_hits_total", _C, (), "node",
+        "Frame events whose frame form (canonical text, sort key) the "
+        "Event still carried from an earlier Frame; every Root event of "
+        "a Frame is one.",
+    ),
+    Instrument(
+        "frame_event_misses_total", _C, (), "node",
+        "Frame events whose frame form had to be made: an event's first "
+        "Frame, or a form that no longer fitted its annotations.",
+    ),
+    Instrument(
         "node_peers", _G, (), "node",
         "Current peer-set size as seen by the selector.",
     ),

@@ -210,6 +210,12 @@ class NodeTelemetry:
             lambda: core.get_consensus_transactions_count(),
         )
         self._func(
+            "frame_event_hits_total", lambda: core.hg.frame_event_hits
+        )
+        self._func(
+            "frame_event_misses_total", lambda: core.hg.frame_event_misses
+        )
+        self._func(
             "node_peers", lambda: len(core.peer_selector.get_peers())
         )
 

@@ -268,3 +268,97 @@ def test_accel_matches_oracle_random_streams(seed, n_peers):
     assert accel.accel.sweeps > 0
     assert accel.accel.fallbacks == 0
     assert _consensus_state(accel) == _consensus_state(oracle)
+
+
+def _drain_as_the_benchmark_does(hg, seconds: float = 30.0) -> None:
+    """benchmark/harness/ingest.py ``_Pass._drain``, and in effect a quiet
+    node's heartbeat: flush while the engine says it is busy, stop when it
+    is not and the consensus count stands still. Unlike drain_pipelined it
+    never forces a flush the engine did not ask for."""
+    import time
+
+    prev = -1
+    deadline = time.monotonic() + seconds
+    while time.monotonic() < deadline:
+        hg.flush_consensus()
+        if hg.accel.busy():
+            time.sleep(0.0005)
+            continue
+        cur = hg.store.consensus_events_count()
+        if cur == prev:
+            return
+        prev = cur
+    raise AssertionError("the drain never quiesced")
+
+
+@pytest.mark.parametrize("outcome", ["compiled", "failed"])
+def test_drain_that_meets_a_compile_wait_ends_decided(monkeypatch, outcome):
+    """async_compile on, one bucket left uncompiled: a flush applies a
+    sweep's result and its relaunch finds the window's bucket not ready. It
+    has reported "handled", nothing is in flight and nothing is pending —
+    only busy() can tell a drain to flush again, and it does while the
+    bucket compiles. The drain ends with the tail decided as the oracle
+    decides it, and busy() is False once the compile is over or has
+    failed."""
+    import threading
+
+    from babble_tpu.ops import voting
+    from babble_tpu.parallel.voting_shard import synthetic_voting_window
+
+    src, _ = synthetic_voting_window(n_peers=4, n_events=200, seed=7,
+                                     peer_change=False)
+    ordered = _ordered_events(src)
+    peer_set = src.store.get_peer_set(0)
+    oracle = _replay(ordered, peer_set)
+
+    withheld = threading.Event()  # from now on the next bucket is not ready
+    release = threading.Event()  # lets the background compile end
+    compiled = threading.Event()
+
+    def bucket_ready(key):
+        return compiled.is_set() or not withheld.is_set()
+
+    def precompile(*key):
+        assert release.wait(30.0)
+        if outcome == "failed":
+            raise RuntimeError("compile failed (injected)")
+        compiled.set()
+
+    monkeypatch.setattr(voting, "bucket_ready", bucket_ready)
+    monkeypatch.setattr(voting, "precompile", precompile)
+
+    hp = Hashgraph(InmemStore(1000))
+    hp.init(peer_set)
+    accel = hp.accel = TensorConsensus(
+        sweep_events=10_000, async_compile=True, min_window=0, pipeline=True,
+        batcher=False)
+    half = len(ordered) // 2
+    for ev in ordered[:half]:
+        hp.insert_event_and_run_consensus(Event(ev.body, ev.signature),
+                                          set_wire_info=True)
+    hp.flush_consensus()  # launches the first sweep
+    assert accel._inflight is not None and accel.compile_waits == 0
+    assert accel._inflight.done.wait(30.0)
+    for ev in ordered[half:]:
+        hp.insert_event_and_run_consensus(Event(ev.body, ev.signature),
+                                          set_wire_info=True)
+
+    withheld.set()
+    hp.flush_consensus()  # applies the first sweep; the relaunch must wait
+    assert accel.sweeps == 1 and accel.compile_waits == 1
+    assert accel._inflight is None and hp._accel_pending == 0
+    assert _consensus_state(hp) != _consensus_state(oracle)  # tail undecided
+    assert accel.busy(), "nothing would flush the undecided tail"
+
+    threading.Timer(0.2, release.set).start()
+    _drain_as_the_benchmark_does(hp)
+    assert release.is_set(), "the drain ended while the bucket compiled"
+    assert not accel.busy()
+    assert not accel._compiling
+    assert accel.fallbacks == 0
+    assert _consensus_state(hp) == _consensus_state(oracle)
+    if outcome == "compiled":
+        # the bucket is there now: the next growth of the DAG sweeps again
+        assert voting.bucket_ready(None)
+    else:
+        assert not voting.bucket_ready(None)

@@ -963,7 +963,9 @@ def _get_diff(h: Hashgraph, known: Dict[int, int], peer_set: PeerSet) -> List[Ev
 
 def _reset_and_continue(h: Hashgraph, index, peer_set, max_round: int):
     """Shared body of the funky/sparse reset tests
-    (reference: hashgraph_test.go:2252-2325, 2430-2510)."""
+    (reference: hashgraph_test.go:2252-2325, 2430-2510). Returns the
+    hashgraphs that landed, each with the index of the block it landed on."""
+    landed = []
     for bi in range(3):
         block = h.store.get_block(bi)
         frame = _round_trip_frame(h.get_frame(block.round_received()))
@@ -992,6 +994,39 @@ def _reset_and_continue(h: Hashgraph, index, peer_set, max_round: int):
                 name_of(index, w) for w in h2.store.get_round(r).witnesses()
             )
             assert hw == h2w, f"block {bi}, round {r} witnesses"
+        landed.append((bi, h2))
+    return landed
+
+
+@pytest.mark.parametrize("graph", ["funky", "sparse"])
+def test_reset_then_commit_gives_the_replayed_frame_hashes(graph):
+    """A hashgraph that lands on a Frame a peer built (through from_dict:
+    its events carry no frame form) and then commits further blocks gives
+    them the FrameHashes of the hashgraph that replayed from the start —
+    and each is the sha256 of the plain canonical encoding."""
+    from babble_tpu.crypto.canonical import canonical_dumps
+    from babble_tpu.crypto.hashing import sha256
+
+    h, index, _, peer_set = (
+        init_funky(True) if graph == "funky" else init_sparse()
+    )
+    h.divide_rounds()
+    h.decide_fame()
+    h.decide_round_received()
+    h.process_decided_rounds()
+    compared = 0
+    for bi, h2 in _reset_and_continue(h, index, peer_set, 5):
+        for k in range(bi + 1, h2.store.last_block_index() + 1):
+            ours, theirs = h2.store.get_block(k), h.store.get_block(k)
+            assert ours.round_received() == theirs.round_received()
+            assert ours.body.frame_hash == theirs.body.frame_hash, (bi, k)
+            frame = h2.store.get_frame(ours.round_received())
+            assert frame.hash() == sha256(canonical_dumps(frame.to_dict()))
+            assert frame.hash() == ours.body.frame_hash
+            compared += 1
+        if h2.store.last_block_index() > bi:
+            assert h2.frame_event_misses > 0
+    assert compared >= 3
 
 
 def test_funky_hashgraph_reset():
