@@ -30,7 +30,9 @@ running; the ``fallbacks`` counter surfaces it in /stats.
 
 from __future__ import annotations
 
+import atexit
 import logging
+import queue
 import threading
 import time
 from typing import Optional
@@ -178,6 +180,97 @@ def _is_stale_window(err: BaseException) -> bool:
     return isinstance(err, StaleWindowError)
 
 
+# Compilation follows the validator set. A window's bucket is (W, E, P, S,
+# R): P the repertoire padded to a multiple of 8, S the peer-set slots of
+# the rounds it spans. A membership change moves P or S under every shape
+# (W, E, R) in use at once, and each moved bucket would meet a compile
+# wait of its own, one flush at a time, with the oracle carrying each.
+# So the (P, S) pairs this process has seen and the shapes it has compiled
+# are kept as a cross product: when a window shows a new pair, every
+# compiled shape is compiled at it; when a shape is first compiled, it is
+# compiled at every other pair seen. One worker thread, one program at a
+# time: the compile a flush is waiting for has a thread of its own
+# (_compile_bucket) and is never queued behind these. With one pair — a
+# validator set that never changes — nothing is queued and no thread
+# starts. Single-device programs only: a mesh compiles on demand.
+_peer_axes_seen: set = set()
+_variants_queued: set = set()
+_variant_queue: "queue.SimpleQueue[tuple]" = queue.SimpleQueue()
+_variant_lock = threading.Lock()
+_variant_worker: Optional[threading.Thread] = None
+_variants_stopping = threading.Event()
+variant_compiles = 0  # programs the worker compiled, process-wide
+_variants_done = 0  # ... and those it is through with, compiled or failed
+
+
+def variant_backlog() -> int:
+    """Programs queued by the policy that the worker is not through with:
+    while it is over 0 a compile is running, or about to."""
+    return len(_variants_queued) - _variants_done
+
+
+def _follow_peer_axes(key: tuple, compiled: bool = False) -> None:
+    """``key`` is the bucket of a window at the flush gate, or
+    (``compiled``) one whose on-demand compile just finished. Queues the
+    variants that keep shapes x pairs whole."""
+    global _variant_worker
+    from babble_tpu.ops import voting
+
+    pair = key[2:4]
+    with _variant_lock:
+        new_pair = pair not in _peer_axes_seen
+        _peer_axes_seen.add(pair)
+        if len(_peer_axes_seen) == 1 or not (new_pair or compiled):
+            return
+        wanted = []
+        if new_pair:
+            wanted += [(W, E) + pair + (R,)
+                       for (W, E, _P, _S, R) in voting.ready_buckets()]
+        if compiled:
+            wanted += [key[:2] + q + key[4:]
+                       for q in _peer_axes_seen if q != pair]
+        for k in wanted:
+            if k not in _variants_queued and not voting.bucket_ready(k):
+                _variants_queued.add(k)
+                _variant_queue.put(k)
+        if _variant_worker is None and not _variant_queue.empty():
+            _variant_worker = threading.Thread(
+                target=_compile_variants, daemon=True,
+                name="voting-variant-compile")
+            _variant_worker.start()
+            atexit.register(_stop_variants)
+
+
+def _stop_variants() -> None:
+    """At interpreter exit: let the worker finish the program it is
+    compiling and go. A daemon thread killed inside XLA's compiler takes
+    the process down with it (SIGABRT, "exception not rethrown")."""
+    _variants_stopping.set()
+    _variant_queue.put(None)  # wakes a worker that waits for work
+    _variant_worker.join(timeout=120.0)
+
+
+def _compile_variants() -> None:
+    global variant_compiles, _variants_done
+    from babble_tpu.ops import voting
+
+    while True:
+        key = _variant_queue.get()
+        if _variants_stopping.is_set():
+            return
+        try:
+            if not voting.bucket_ready(key):
+                t0 = time.perf_counter()
+                voting.precompile(*key)
+                variant_compiles += 1
+                logger.info("voting kernels ready for variant %s in %.1fs",
+                            key, time.perf_counter() - t0)
+        except Exception:
+            logger.warning("variant %s precompile failed", key,
+                           exc_info=True)
+        _variants_done += 1
+
+
 _INFLIGHT_SLOTS = None
 _slots_lock = threading.Lock()
 
@@ -291,6 +384,9 @@ class TensorConsensus:
         # worth of decisions, never the node.
         self.readback_timeout_s = 30.0
         self._last_snapshot_topo = -1
+        # topological index of the hashgraph at the snapshot of the sweep
+        # applied last: what Hashgraph.voting_deferred compares
+        self.applied_topo = -1
         self.last_sweep_s = 0.0
         self.total_sweep_s = 0.0
         self.last_window_events = 0
@@ -389,6 +485,28 @@ class TensorConsensus:
         compile is over, or has failed."""
         return self._inflight is not None or self._awaited_compiles > 0
 
+    def wait_inflight(self) -> bool:
+        """Block until the sweep in flight has been read back (or has
+        outlived ``readback_timeout_s``, which the next flush then
+        handles). False when none was in flight."""
+        inf = self._inflight
+        if inf is None:
+            return False
+        inf.done.wait(self.readback_timeout_s)
+        return True
+
+    def handed_to_oracle(self, hg) -> None:
+        """The oracle stages are about to run on ``hg``: they mutate fame
+        and round-received state the resident mirrors can't track in
+        O(ΔE), so the next engaged snapshot rebuilds from scratch. The
+        hashgraph's delta channels go too: that rebuild reads the store
+        directly, and on a node whose windows never clear the min_window
+        gate NO snapshot ever drains them — without this they'd grow one
+        entry per witness/fd-update forever."""
+        if self.window_state is not None:
+            self.window_state.mark_dirty("oracle-pass")
+            hg.drain_accel_delta()
+
     def invalidate(self) -> None:
         """Drop any in-flight sweep (hashgraph reset / fast-sync landing):
         its snapshot no longer describes this store. Reclaim its admission
@@ -406,6 +524,7 @@ class TensorConsensus:
             self.breaker.cancel()
         self._inflight = None
         self._last_snapshot_topo = -1
+        self.applied_topo = -1
         if self.window_state is not None:
             # drop residency + force a rebuild: the mirrors describe a
             # store that no longer exists
@@ -476,6 +595,7 @@ class TensorConsensus:
 
             ready = voting_shard.bucket_ready(self.mesh, key)
         else:
+            _follow_peer_axes(key)
             ready = voting.bucket_ready(key)
         if ready:
             return True
@@ -504,6 +624,7 @@ class TensorConsensus:
                 voting_shard.precompile(self.mesh, *key)
             else:
                 voting.precompile(*key)
+                _follow_peer_axes(key, compiled=True)
             logger.info(
                 "voting kernels ready for bucket %s (mesh=%s) in %.1fs",
                 key,
@@ -528,14 +649,8 @@ class TensorConsensus:
         follows mutates fame/round-received state the mirrors can't track
         in O(ΔE); the next engaged snapshot rebuilds from scratch."""
         handled = self._flush(hg)
-        if not handled and self.window_state is not None:
-            self.window_state.mark_dirty("oracle-pass")
-            # Discard the hashgraph's delta channels too: the rebuild that
-            # follows reads the store directly, and on a node whose
-            # windows never clear the min_window gate NO snapshot ever
-            # drains them — without this they'd grow one entry per
-            # witness/fd-update forever.
-            hg.drain_accel_delta()
+        if not handled:
+            self.handed_to_oracle(hg)
         return handled
 
     def _flush(self, hg) -> bool:
@@ -762,6 +877,7 @@ class TensorConsensus:
             win, snap = self._snapshot(hg, for_batcher=bool(self.batcher))
             if win is None:
                 self.breaker.cancel()  # no device attempt to judge
+                self.applied_topo = hg.topological_index
                 return True  # nothing undecided
             if self.mesh is not None:
                 # resident snapshots are already mesh-aligned (WindowState
@@ -924,6 +1040,7 @@ class TensorConsensus:
         self._stage("result_idle", max(0.0, t0 - inf.t_done))
         self.breaker.record_success()
         self.sweeps += 1
+        self.applied_topo = inf.topo
         self.last_window_events = len(inf.win.hashes)
         # Sweep cost, not launch-to-apply wall time (the latter includes
         # the idle wait for this flush and would read as the flush
@@ -944,6 +1061,7 @@ class TensorConsensus:
             win, snap = self._snapshot(hg, for_batcher=bool(self.batcher))
             if win is None:
                 self.breaker.cancel()  # no device attempt to judge
+                self.applied_topo = hg.topological_index
                 return True  # nothing undecided
             if self.mesh is not None:
                 win = self._mesh_align(win)
@@ -995,6 +1113,7 @@ class TensorConsensus:
             return False
         self.breaker.record_success()
         self.sweeps += 1
+        self.applied_topo = hg.topological_index
         self.last_window_events = len(win.hashes)
         self.last_sweep_s = time.perf_counter() - t0
         self.total_sweep_s += self.last_sweep_s
@@ -1070,6 +1189,17 @@ class TensorConsensus:
                 if self.window_state is not None
                 else 0
             ),
+            # ... and what forced them (window_state.py: "oracle-pass",
+            # "repertoire-change", "peer-set-slot-overflow", ...)
+            "accel_rebuilds_by_reason": (
+                dict(self.window_state.rebuilds_by_reason)
+                if self.window_state is not None
+                else {}
+            ),
+            # programs compiled ahead of need because the validator set
+            # moved P or S (process-wide, see _follow_peer_axes)
+            "accel_variant_compiles": variant_compiles,
+            "accel_variant_backlog": variant_backlog(),
             "accel_stale_drops": self.stale_drops,
             # Mesh padding visibility: witness rows added to align W to
             # the mesh, and windows that dropped to single-device anyway

@@ -51,7 +51,7 @@ from babble_tpu.hashgraph.event import (
 from babble_tpu.hashgraph.frame import Frame, Root
 from babble_tpu.hashgraph.round_info import RoundInfo
 from babble_tpu.hashgraph.store import Store
-from babble_tpu.obs.trace import staged
+from babble_tpu.obs.trace import NULL_STAGE, staged
 from babble_tpu.peers.peer_set import PeerSet
 
 logger = logging.getLogger("babble_tpu.hashgraph")
@@ -62,6 +62,14 @@ ROOT_DEPTH = 10
 
 # Frequency of coin rounds in the fame decision (reference: hashgraph.go:24-25).
 COIN_ROUND_FREQ = 4
+
+# All consistent hashgraphs will have decided the fame of round r witnesses
+# by round r+5, so an accepted membership request takes effect 6 rounds
+# after the round its block was received (whitepaper lemmas 5.15 and 5.17;
+# reference: node/core.go:566-569). The core applies it; the hashgraph needs
+# the number to know which rounds' peer-sets a request that is still
+# undecided can change (_settle_membership).
+PEER_SET_EFFECTIVE_DELAY = 6
 
 # Verbose per-event rejection logging, resolved once at import: the old
 # per-reject `import os` + env read sat inside the hot insert path.
@@ -166,6 +174,17 @@ class Hashgraph:
         # insert; inserts between sweeps are counted in _accel_pending.
         self.accel = None
         self._accel_pending = 0
+        # Undetermined events that carry internal transactions (membership
+        # requests), in insert order: while one is undecided, the
+        # peer-sets from its round + 1 + PEER_SET_EFFECTIVE_DELAY on are
+        # not final, and deferred voting must not divide an event into
+        # those rounds (_settle_membership). Emptied as blocks commit.
+        self._membership_pending: List[str] = []
+        # drains that _settle_membership forced
+        self.peer_set_waits = 0
+        # the topological index the voting stages are level with: every
+        # decision the events below it allow has been made and committed
+        self._voted_topo = 0
         # Pipeline-stage observer: the node's span tracer (obs/trace.py),
         # feeding the sync_stage_* histograms + the active sync trace.
         # None (bare hashgraphs, BABBLE_OBS=0) keeps the staged methods
@@ -606,6 +625,8 @@ class Hashgraph:
         reference), but the voting stages are deferred to a batched device
         sweep — normally once per sync via flush_consensus, or mid-batch
         when enough inserts accumulate."""
+        if self.accel is not None and self._membership_pending:
+            self._settle_membership(event)
         self.insert_event(event, set_wire_info)
         self.divide_rounds()
         if self.accel is not None:
@@ -640,10 +661,75 @@ class Hashgraph:
         self._accel_pending = 0
         if self.accel is not None and self.accel.flush(self):
             self.process_decided_rounds()
+            # a pipelined flush applies the sweep launched a flush ago
+            self._voted_topo = max(self._voted_topo, self.accel.applied_topo)
             return
+        self._oracle_sweep()
+
+    def _oracle_sweep(self) -> None:
         self.decide_fame()
         self.decide_round_received()
         self.process_decided_rounds()
+        self._voted_topo = self.topological_index
+
+    def voting_deferred(self) -> bool:
+        """True while the voting stages trail the DAG: inserts no sweep
+        has covered, or a sweep whose result has not been applied."""
+        return (self.accel is not None
+                and self._voted_topo != self.topological_index)
+
+    def drain_consensus(self) -> None:
+        """Bring deferred voting level with the DAG, as a sequential
+        validator is after every insert: flush, wait for the sweep in
+        flight, apply and commit, until the sweep applied last covered
+        every event. No-op without an accelerator."""
+        accel = self.accel
+        while self.voting_deferred():
+            self.run_consensus_sweep()
+            if not self.voting_deferred() or accel.wait_inflight():
+                continue
+            # the flush applied a sweep and could not launch the next (a
+            # bucket still compiling, a full device): the oracle stages
+            # decide what the newest events add
+            accel.handed_to_oracle(self)
+            self._oracle_sweep()
+
+    def _settle_membership(self, event: Event) -> None:
+        """Called before ``event`` is inserted (a sweep cannot snapshot an
+        event that has no round yet), while a membership request is
+        undecided. The reference runs consensus after every
+        insert, so the block that carries a request has been committed,
+        and its peer-set stored for round received + 6, long before an
+        event of that round arrives. Deferred voting lags by sweeps: the
+        peer-set could land after events were divided against the old
+        one. DivideRounds reads the peer-sets of the event's parent round
+        and of the round after it; a request in an event of round r is
+        received in round r + 1 at the earliest. So when the parent round
+        comes within a round of r + 1 + PEER_SET_EFFECTIVE_DELAY, voting
+        is drained first — after which this hashgraph is where the
+        sequential one would be, request decided or not."""
+        if not self.voting_deferred():
+            return
+        rounds = []
+        for h in self._membership_pending:
+            try:
+                r = self.store.get_event(h).round
+            except StoreError:
+                continue
+            if r is not None:
+                rounds.append(r)
+        if not rounds:
+            return
+        parent_round = -1
+        for parent in (event.self_parent(), event.other_parent()):
+            if parent != "":
+                parent_round = max(parent_round, self.round(parent))
+        if parent_round + 1 < min(rounds) + 1 + PEER_SET_EFFECTIVE_DELAY:
+            return
+        self.peer_set_waits += 1
+        obs = self.stage_observer
+        with NULL_STAGE if obs is None else obs.span("peer_set_wait"):
+            self.drain_consensus()
 
     @staged("insert")
     def insert_event(self, event: Event, set_wire_info: bool = False) -> None:
@@ -683,6 +769,8 @@ class Hashgraph:
 
         if event.is_loaded():
             self.pending_loaded_events += 1
+        if event.body.internal_transactions:
+            self._membership_pending.append(event.hex())
 
         for bs in event.block_signatures():
             self.pending_signatures.add(bs)
@@ -1049,6 +1137,12 @@ class Hashgraph:
                             )
                         self.store.set_block(block)
                     self.last_committed_round_events = len(frame.events)
+                    if self._membership_pending:
+                        committed = {fe.core.hex() for fe in frame.events}
+                        self._membership_pending = [
+                            h for h in self._membership_pending
+                            if h not in committed
+                        ]
 
                 processed_rounds.append(pr.index)
 
@@ -1335,6 +1429,8 @@ class Hashgraph:
         self._accel_pending = 0
         self._accel_new_witnesses = []
         self._accel_fd_dirty = set()
+        self._membership_pending = []
+        self._voted_topo = 0
         self._round_ctx = {}
         if self.accel is not None:
             # An in-flight sweep's snapshot no longer describes this store.

@@ -16,7 +16,7 @@ from ..hashgraph.block import Block
 from ..hashgraph.errors import ForkError, is_normal_self_parent_error
 from ..hashgraph.event import Event, WireEvent, sort_topological
 from ..hashgraph.frame import Frame
-from ..hashgraph.hashgraph import Hashgraph
+from ..hashgraph.hashgraph import PEER_SET_EFFECTIVE_DELAY, Hashgraph
 from ..hashgraph.internal_transaction import (
     InternalTransaction,
     InternalTransactionReceipt,
@@ -32,11 +32,6 @@ from .sentry import Sentry
 from .validator import Validator
 
 logger = logging.getLogger(__name__)
-
-# All consistent hashgraphs will have decided the fame of round r witnesses
-# by round r+5, so a new peer-set becomes effective at round r+6 (whitepaper
-# lemmas 5.15 and 5.17; reference: core.go:566-569).
-PEER_SET_EFFECTIVE_DELAY = 6
 
 
 class PreparedSync:
@@ -138,6 +133,11 @@ class Core:
         self.ingest_batch_verifies = 0
         self.ingest_batch_size_max = 0
         self.ingest_fallback_singles = 0
+        # Membership: accepted PEER_ADD / PEER_REMOVE requests applied, and
+        # syncs that stalled on an event whose creator the repertoire did
+        # not hold yet (resolved by draining voting, see sync).
+        self.membership_changes_applied = 0
+        self.sync_creator_stalls = 0
 
         # Coalesced self-event minting (docs/gossip.md §Adaptive
         # scheduling): when the mempool still holds a full event's worth
@@ -421,6 +421,8 @@ class Core:
                 decoded, j = self._decode_chunk(unknown_events, pos)
                 if decoded:
                     self._batch_prevalidate(decoded)
+            if j == pos and self._creator_unknown(unknown_events[pos]):
+                decoded, j = self._resolve_creator_stall(unknown_events, pos)
             if j == pos:
                 # Sequential path (accelerator off, or chunk stalled at the
                 # first event — let read_wire_info raise its real error).
@@ -455,6 +457,34 @@ class Core:
 
         if fork_errs:
             raise fork_errs[0]
+
+    def _creator_unknown(self, we: WireEvent) -> bool:
+        """True when deferred voting may be what keeps ``we`` from
+        decoding: its creator is not in the repertoire, and voting trails
+        the DAG. (The reference runs consensus after every insert, so
+        there a joiner's first event finds the block that admitted it
+        committed.)"""
+        hg = self.hg
+        return (
+            hg.voting_deferred()
+            and we.body.creator_id not in hg.store.repertoire_by_id()
+        )
+
+    def _resolve_creator_stall(
+        self, unknown_events: List[WireEvent], pos: int
+    ) -> tuple[List[Event], int]:
+        """Drain voting — flush, wait for the sweep in flight, apply,
+        commit — so that every block the events inserted so far decide is
+        committed and its peer-set stored, then decode again from ``pos``.
+        A stall that survives a drained pipeline is the sender's, and the
+        caller lets read_wire_info raise it."""
+        self.sync_creator_stalls += 1
+        with self._span("creator_stall"):
+            self.hg.drain_consensus()
+            decoded, j = self._decode_chunk(unknown_events, pos)
+            if decoded:
+                self._batch_prevalidate(decoded)
+        return decoded, j
 
     def _ingest_one(
         self,
@@ -734,6 +764,14 @@ class Core:
     ) -> None:
         """Apply accepted PEER_ADD/PEER_REMOVE at round_received + 6
         (reference: core.go:562-650)."""
+        if not receipts:
+            return
+        with self._span("membership"):
+            self._apply_receipts(round_received, receipts)
+
+    def _apply_receipts(
+        self, round_received: int, receipts: List[InternalTransactionReceipt]
+    ) -> None:
         current_peers = self.peers
         validators = self.validators
         effective_round = round_received + PEER_SET_EFFECTIVE_DELAY
@@ -754,6 +792,7 @@ class Core:
             else:
                 continue
             changed = True
+            self.membership_changes_applied += 1
 
         if changed:
             self.last_peer_change_round = effective_round

@@ -667,6 +667,14 @@ class Node(StateManager):
                 # unlike the three process-wide pairs above
                 "frame_event_hits": self.core.hg.frame_event_hits,
                 "frame_event_misses": self.core.hg.frame_event_misses,
+                # membership: requests applied (node/core.py), syncs that
+                # stalled on a creator the repertoire lacked, and inserts
+                # that waited for a peer-set (hashgraph.py), the last two
+                # only where voting is deferred
+                "membership_changes_applied":
+                    self.core.membership_changes_applied,
+                "sync_creator_stalls": self.core.sync_creator_stalls,
+                "peer_set_waits": self.core.hg.peer_set_waits,
             }
         )
         # Mempool surface (docs/mempool.md): admission verdict counters,

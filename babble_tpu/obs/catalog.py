@@ -44,8 +44,9 @@ CATALOG: Tuple[Instrument, ...] = (
         "request_sync, decode, batch_verify, insert, divide_rounds, "
         "decide_fame, round_received, commit, proxy_deliver, "
         "process_sig_pool, diff, eager_sync, mempool_drain, self_event, "
-        "sync, prepare_sync, flush, record_heads. Inclusive: a span's "
-        "whole duration, its children's included.",
+        "sync, prepare_sync, flush, record_heads, membership, "
+        "creator_stall, peer_set_wait. Inclusive: a span's whole "
+        "duration, its children's included.",
     ),
     Instrument(
         "sync_stage_self_seconds", _H, ("stage",), "node",
@@ -58,7 +59,8 @@ CATALOG: Tuple[Instrument, ...] = (
         "sync_stage_cpu_seconds", _H, ("stage",), "node",
         "Thread CPU time (time.thread_time) inside the COARSE spans "
         "only: sync, prepare_sync, decode, batch_verify, flush, commit, "
-        "self_event and the accel spans build, snapshot (delta_scan + "
+        "self_event, creator_stall, peer_set_wait and the accel spans "
+        "build, snapshot (delta_scan + "
         "pack), dispatch, readback, apply. Wall minus CPU is time the "
         "thread did not run: GIL, sleep, device wait. Empty on a "
         "simulated clock.",
@@ -594,6 +596,7 @@ SYNC_STAGES = (
     "decide_fame", "round_received", "commit", "proxy_deliver",
     "process_sig_pool", "diff", "eager_sync", "mempool_drain",
     "self_event", "sync", "prepare_sync", "flush", "record_heads",
+    "membership", "creator_stall", "peer_set_wait",
 )
 # COARSE spans open at most a few times per sync: obs/trace.py also
 # reads the thread CPU clock and writes a profiler annotation for them.
@@ -603,7 +606,7 @@ SYNC_STAGES = (
 # result_idle are waits between threads, recorded after the fact.
 COARSE_STAGES = (
     "sync", "prepare_sync", "decode", "batch_verify", "flush", "commit",
-    "self_event",
+    "self_event", "creator_stall", "peer_set_wait",
     "build", "snapshot", "dispatch", "readback", "apply",
 )
 TX_STAGES = ("mempool_wait", "consensus")

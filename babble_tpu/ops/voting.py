@@ -642,6 +642,13 @@ def mark_bucket_ready(key: tuple) -> None:
         _ready_buckets.add(key)
 
 
+def ready_buckets() -> set:
+    """The shape buckets whose single-window program is compiled: the
+    shapes this process has had in use."""
+    with _bucket_lock():
+        return set(_ready_buckets)
+
+
 # The vmapped program is a different executable per (batch, bucket); its
 # readiness is tracked separately so the batcher can route unwarmed batch
 # shapes through warm single-window dispatches meanwhile.

@@ -234,6 +234,10 @@ class WindowState:
         self.dirty = True  # force a rebuild on the next snapshot
         self.dirty_reason = "initial"
         self.rebuilds = 0
+        # the same count by what forced each rebuild ("initial",
+        # "oracle-pass", "repertoire-change", "peer-set-slot-overflow",
+        # "round-bucket-overflow", ...)
+        self.rebuilds_by_reason: Dict[str, int] = {}
         self.mirror: Optional[Dict[str, np.ndarray]] = None
         self.row: Dict[str, int] = {}
         self.wit_row: Dict[str, int] = {}
@@ -389,6 +393,8 @@ class WindowState:
         self._mask_cache.clear()
         self.generation += 1
         self.rebuilds += 1
+        self.rebuilds_by_reason[reason] = (
+            self.rebuilds_by_reason.get(reason, 0) + 1)
         self.dirty = False
         timers["build"] = timers.get("build", 0.0) + (time.perf_counter() - t0)
         rows = len(self.row) + len(self.wit_row)
