@@ -99,12 +99,18 @@ class _RoundCtx:
     per-voter loop in DecideFame's oracle) into a single vectorized
     compare — the dict-walk version is the profiled host-tail hotspot.
 
-    Invalidation: a witness added to the round (divide_rounds /
-    insert_frame_event) or a cached witness's first_descendants mutating
-    (the insert-time walk) drops the entry; a peer-set object swap or a
-    created-event count change is caught at lookup time."""
+    An entry of ``Hashgraph._round_ctx`` is kept equal to what
+    ``_build_round_ctx`` would build by the two places that change its
+    inputs: the insert-time walk writes the one entry a cached witness's
+    new first descendant changes (``set_first_descendant``), and
+    ``Hashgraph._round_ctx_created`` appends the row of a witness added
+    to the round (``add_witness``). What still drops an entry, to be
+    rebuilt at the next lookup: a peer-set object swap or a witness list
+    that is not the cached one (``_round_ctx_for``), a created event the
+    ctx was not told of, a round with more witnesses than peers,
+    ``prune_below``, ``reset`` and the 128-entry trim."""
 
-    __slots__ = ("peer_set", "sm", "col", "wits", "wit_set", "fd",
+    __slots__ = ("peer_set", "sm", "col", "wits", "row", "fd", "_rows",
                  "n_created")
 
     def __init__(self, peer_set, wits, fd, n_created):
@@ -112,9 +118,45 @@ class _RoundCtx:
         self.sm = peer_set.super_majority()
         self.col = {pk: i for i, pk in enumerate(peer_set.pub_keys())}
         self.wits = wits
-        self.wit_set = frozenset(wits)
-        self.fd = fd  # int64 [n_wit, n_peers], missing = _FD_MISSING
+        self.row = {w: i for i, w in enumerate(wits)}
+        # int64 [n_wit, n_peers], missing = _FD_MISSING: the filled rows
+        # of _rows, which add_witness gives room for the round's most
+        self.fd = fd
+        self._rows = fd
         self.n_created = n_created
+
+    def set_first_descendant(self, w: str, creator: str, index: int) -> bool:
+        """Witness ``w`` gained ``creator``'s first descendant. False when
+        the matrix has no such entry: ``w`` is not a witness of this round,
+        or the creator has no column in its peer-set."""
+        i = self.row.get(w)
+        j = self.col.get(creator)
+        if i is None or j is None:
+            return False
+        self.fd[i, j] = index
+        return True
+
+    def add_witness(self, w: str, first_descendants) -> bool:
+        """Append the row of a witness added to the round. The matrix is
+        allocated once for as many witnesses as the peer-set has peers (a
+        round holds one per peer: forks are refused at insert); False when
+        even that is full."""
+        n, n_peers = len(self.wits), len(self.col)
+        if n == len(self._rows):
+            if n >= n_peers:
+                return False
+            rows = np.full((n_peers, n_peers), _FD_MISSING, dtype=np.int64)
+            rows[:n] = self.fd
+            self._rows = rows
+        row = self._rows[n]
+        for p, e in first_descendants.items():
+            j = self.col.get(p)
+            if j is not None:
+                row[j] = e.index
+        self.fd = self._rows[: n + 1]
+        self.row[w] = n
+        self.wits.append(w)
+        return True
 
 
 def middle_bit(ehex: str) -> bool:
@@ -210,12 +252,18 @@ class Hashgraph:
         self._round_cache = LRU(cs)
         self._timestamp_cache = LRU(cs)
         self._witness_cache = LRU(cs)
-        # round -> _RoundCtx, consulted by _round/_witness on every insert.
-        # Entries self-validate against the round's created-event count and
-        # peer-set identity; the only mutation that check cannot catch — a
-        # cached witness gaining a first-descendant entry — is invalidated
-        # explicitly in _update_ancestor_first_descendant.
+        # round -> _RoundCtx, consulted by _round/_witness on every insert
+        # and kept current in place: _update_ancestor_first_descendant
+        # writes the entry a cached witness's new first descendant changes,
+        # _round_ctx_created appends a new witness's row. Entries still
+        # self-validate at lookup against the round's created-event count,
+        # witness list and peer-set identity (_round_ctx_for), which drops
+        # and rebuilds whatever those two did not account for.
         self._round_ctx: Dict[int, _RoundCtx] = {}
+        # matrix entries written + witness rows appended in place, and
+        # matrices built at lookup (first use of a round, or a dropped entry)
+        self.round_ctx_patches = 0
+        self.round_ctx_rebuilds = 0
 
     def init(self, peer_set: PeerSet) -> None:
         """Set the genesis peer-set at round 0 (reference: hashgraph.go:84-89).
@@ -315,8 +363,10 @@ class Hashgraph:
         """Cached per-round ctx, revalidated cheaply on every lookup: a
         created-event count change forces a witness-list recompute, and a
         changed witness list (or peer-set swap) forces a matrix rebuild.
-        When only non-witness events were added, the ctx survives with its
-        count refreshed — the common case on the hot insert path."""
+        ``_round_ctx_created`` keeps count and rows level as events are
+        divided into the round, so on the hot insert path the count agrees
+        and the build is left to a round's first use and to whatever that
+        did not see: the safety net, not the routine."""
         ctx = self._round_ctx.get(r)
         n_created = len(round_info.created_events)
         if ctx is not None and ctx.peer_set is peer_set:
@@ -329,6 +379,7 @@ class Hashgraph:
         else:
             wits = round_info.witnesses()
         ctx = self._build_round_ctx(peer_set, wits, n_created)
+        self.round_ctx_rebuilds += 1
         if len(self._round_ctx) >= 128:
             # Consensus advances monotonically; old rounds stop being
             # parent rounds, so prune from the bottom.
@@ -336,6 +387,26 @@ class Hashgraph:
                 del self._round_ctx[k]
         self._round_ctx[r] = ctx
         return ctx
+
+    def _round_ctx_created(self, r: int, round_info, ev: Event,
+                           witness: bool) -> None:
+        """``ev`` was just added to round ``r``'s created events: keep the
+        cached ctx level with them. A witness gets its row, filled from its
+        first descendants as they stand. A ctx that was not level before
+        (someone else added to the round) or has no room is dropped, and
+        ``_round_ctx_for`` rebuilds it."""
+        ctx = self._round_ctx.get(r)
+        if ctx is None:
+            return
+        n_created = len(round_info.created_events)
+        if ctx.n_created + 1 != n_created or (
+            witness and not ctx.add_witness(ev.hex(), ev.first_descendants)
+        ):
+            del self._round_ctx[r]
+            return
+        ctx.n_created = n_created
+        if witness:
+            self.round_ctx_patches += 1
 
     def _strongly_seen_mask(self, x: str, ctx: _RoundCtx):
         """Boolean mask over ctx.wits: which witnesses x strongly sees.
@@ -564,12 +635,14 @@ class Hashgraph:
                     self.store.set_event(a)
                     if self._accel_track_delta:
                         self._accel_fd_dirty.add(ah)
-                    # A cached round-ctx matrix snapshots witness fds; this
-                    # is the one mutation its count check cannot see.
+                    # A cached round-ctx matrix holds witness fds; this is
+                    # the one mutation its lookup-time checks cannot see.
                     if a.round is not None:
                         ctx = self._round_ctx.get(a.round)
-                        if ctx is not None and ah in ctx.wit_set:
-                            del self._round_ctx[a.round]
+                        if ctx is not None and ctx.set_first_descendant(
+                            ah, creator, coords.index
+                        ):
+                            self.round_ctx_patches += 1
                     # Stop at witnesses so the walk doesn't descend to the
                     # bottom of the graph (reference: hashgraph.go:503-512).
                     try:
@@ -799,6 +872,10 @@ class Hashgraph:
 
         self._init_event_coordinates(event)
         self.store.set_event(event)
+        # after the coordinates: a witness's row starts from them
+        self._round_ctx_created(
+            frame_event.round, round_info, event, frame_event.witness
+        )
         self._update_ancestor_first_descendant(event)
         self.store.add_consensus_event(event)
 
@@ -881,6 +958,7 @@ class Hashgraph:
                 self.pending_rounds.set(PendingRound(round_number, False))
 
             round_info.add_created_event(hash_, is_witness)
+            self._round_ctx_created(round_number, round_info, ev, is_witness)
             if round_infos is None or fresh_round:
                 # A fresh round registers immediately — the very next event
                 # in the batch may read it via get_round / last_round.

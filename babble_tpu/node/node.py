@@ -675,6 +675,10 @@ class Node(StateManager):
                     self.core.membership_changes_applied,
                 "sync_creator_stalls": self.core.sync_creator_stalls,
                 "peer_set_waits": self.core.hg.peer_set_waits,
+                # DivideRounds' per-round witness matrices: entries and
+                # rows written in place, and matrices built at lookup
+                "round_ctx_patches": self.core.hg.round_ctx_patches,
+                "round_ctx_rebuilds": self.core.hg.round_ctx_rebuilds,
             }
         )
         # Mempool surface (docs/mempool.md): admission verdict counters,
