@@ -9,9 +9,8 @@ Usage::
 
 Exit codes: 0 clean, 1 violations, 2 usage error. ``--self-proof``
 injects one violation per pass (plus a stale allow) into synthetic
-sources and exits nonzero unless EVERY pass catches its injection — the
-perfgate ``--inject-regression`` pattern: a toothless linter fails the
-build, not the code it was supposed to guard.
+sources and exits nonzero unless EVERY pass catches its injection: a
+toothless linter fails the build, not the code it was supposed to guard.
 """
 
 from __future__ import annotations

@@ -20,6 +20,5 @@ Everything between validators and untrusted readers:
                    stream to its own subscribers;
 - ``swarm``      — a selector-based many-subscriber load client (one
                    thread, thousands of sockets) used by
-                   demo/bombard.py, bench.py --clients and the
-                   clientsmoke suite.
+                   demo/bombard.py and the clientsmoke suite.
 """

@@ -1268,7 +1268,7 @@ def prewarm_buckets(n_peers: int, background: bool = True, mesh=None):
     if n_peers >= 12:
         # sustained backlogs at 16+ validators accumulate rounds past the
         # R=16 bucket before decisions drain; compiling R=32 up front keeps
-        # mid-run compiles (and their single-core steal) off the bench
+        # mid-run compiles (and their single-core steal) off the hot
         # path. Small clusters never hit these shapes — skipping them
         # keeps their prewarm cheap.
         buckets += [

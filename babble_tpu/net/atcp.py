@@ -4,7 +4,7 @@ binary connections, per-peer outbound write queues.
 The seed transport (net/tcp.py) is thread-per-connection with one
 blocking request/response in flight per socket — at 16 nodes that is
 hundreds of parked threads convoying on the GIL, and the JSON codec on
-top of it is the measured wall (BENCH_r05, ROADMAP item 1). This
+top of it was the wall the threaded rings hit (round 5). This
 transport replaces the hot path:
 
 - **One loop thread** (``selectors``-based) owns every socket:

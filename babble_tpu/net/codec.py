@@ -3,7 +3,7 @@
 The seed wire format (net/tcp.py) is canonical JSON with every bytes
 field base64-encoded — so each event pushed to a peer pays a dict
 build, a b64 walk, and a JSON parse on the far side, per peer. At 16
-nodes that codec IS the wall (BENCH_r05). This module replaces it on
+nodes that codec was the wall (round 5). This module replaces it on
 the Sync/EagerSync hot path with a length-prefixed binary encoding:
 
 - Each :class:`~babble_tpu.hashgraph.event.WireEvent` is encoded ONCE

@@ -124,8 +124,8 @@ class Core:
         self.self_block_signatures = {}  # key -> BlockSignature
         self.promises: Dict[str, JoinPromise] = {}
 
-        # Batched-ingest fast-path counters (surfaced via Node.get_stats
-        # and bench.py): on the happy path every incoming sync costs
+        # Batched-ingest fast-path counters (surfaced via
+        # Node.get_stats): on the happy path every incoming sync costs
         # exactly ONE native batch-verify call, and fallback_singles
         # counts the per-event scalar re-checks that pinpoint offenders
         # after a batch reported failures.

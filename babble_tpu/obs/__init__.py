@@ -23,12 +23,6 @@ The package is organized as:
   node-id correlation) replacing per-module ad-hoc setup.
 - ``obs.lint``     — ``python -m babble_tpu.obs.lint``: fails when a
   cataloged instrument is missing from the docs table or vice versa.
-- ``obs.ledger``   — the bench-history ledger (BENCH_HISTORY.jsonl):
-  schema-versioned perf records appended by every bench run, plus the
-  backfill of the pre-ledger BENCH_r* artifacts.
-- ``obs.perfgate`` — ``python -m babble_tpu.obs.perfgate``: regression
-  gate over the ledger (rolling same-host baseline, noise-aware bands,
-  ``--inject-regression`` self-proof).
 - ``obs.profile``  — always-on ~50 Hz thread-stack sampler: stage-
   attributed collapsed stacks at ``GET /profile`` and the
   ``profile_stage_samples{stage}`` instrument.

@@ -20,8 +20,7 @@ A single process-wide daemon thread wakes ~``hz`` times a second
 
 Sampling is wait-free for the profiled threads — no locks are taken,
 no code is instrumented; the only cost is the sampler thread's own
-slice (measured alongside the obs kill switch in ``bench.py --obs``,
-acceptance bound <2%). ``BABBLE_OBS=0`` or ``profile_hz=0`` keeps the
+slice. ``BABBLE_OBS=0`` or ``profile_hz=0`` keeps the
 sampler off entirely.
 
 On-demand windows (``GET /profile?seconds=N`` on the service) diff two

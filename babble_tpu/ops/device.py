@@ -109,7 +109,7 @@ def on_accelerator() -> bool:
 
 def _is_tpu_device(dev) -> bool:
     """Shared TPU classifier for on_tpu() and describe() — one predicate so
-    the bench's capture label and the TPU-layout code paths can't drift."""
+    describe()'s capture label and the TPU-layout code paths can't drift."""
     return dev.platform == "tpu"
 
 

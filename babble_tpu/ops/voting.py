@@ -21,8 +21,9 @@ milliseconds even when a large undecided window (E in the hundreds) has
 accumulated. (A dense [E, E] formulation measurably death-spirals: slow
 sweeps grow the window, which slows sweeps further.)
 
-Unlike :mod:`babble_tpu.ops.dag` (the all-at-once pipeline used by the bench
-and the multi-chip dryrun), these kernels support **dynamic membership**:
+Unlike :mod:`babble_tpu.ops.dag` (the all-at-once pipeline used by
+``__graft_entry__`` and the multi-chip dryrun), these kernels support
+**dynamic membership**:
 peer-sets vary per round, so the peer axis is padded to the full repertoire
 and each round carries a peer-set slot (``psi``) selecting a membership mask
 and super-majority threshold (reference: per-round peer-sets in DecideFame,

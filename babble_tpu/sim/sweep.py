@@ -6,8 +6,8 @@ crash churn x mempool flood, each dimension drawn from a seeded stream
 the failing spec to a minimal reproducer written as a replayable JSON
 artifact (babble_tpu.sim.shrink).
 
-The last stdout line is a compact JSON summary (same tail-capture
-contract as bench.py); everything else goes to stderr. Determinism
+The last stdout line is a compact JSON summary; everything else goes
+to stderr. Determinism
 contract: the same ``--seed``/``--seeds`` invocation produces
 byte-identical commit sequences and event logs — verify with
 ``--dump FILE`` twice and compare the files.

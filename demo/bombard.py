@@ -69,7 +69,11 @@ def scrape_commit_latency(endpoints: str, settle_s: float = 15.0) -> None:
     ``settle_s`` before being reported as empty."""
     import urllib.request
 
-    from bench import _parse_prom_histogram, _prom_hist_quantile
+    from babble_tpu.obs.healthview import (
+        hist_quantile,
+        parse_prom,
+        prom_histogram,
+    )
 
     for ep in endpoints.split(","):
         ep = ep.strip()
@@ -87,7 +91,7 @@ def scrape_commit_latency(endpoints: str, settle_s: float = 15.0) -> None:
                 print(f"{ep}: scrape failed ({err})", file=sys.stderr)
                 hist = ()  # sentinel: failed scrape, not an empty histogram
                 break
-            hist = _parse_prom_histogram(text, "commit_latency_seconds")
+            hist = prom_histogram(parse_prom(text), "commit_latency_seconds")
             if (hist is not None and hist["count"] > 0) or (
                 time.monotonic() >= deadline
             ):
@@ -99,10 +103,10 @@ def scrape_commit_latency(endpoints: str, settle_s: float = 15.0) -> None:
             print(f"{ep}: commit_latency_seconds empty (no local commits)")
             continue
         p50, p90, p99 = (
-            _prom_hist_quantile(hist, q) for q in (0.50, 0.90, 0.99)
+            hist_quantile(hist, q) for q in (0.50, 0.90, 0.99)
         )
         print(
-            f"{ep}: commit latency n={hist['count']} "
+            f"{ep}: commit latency n={hist['count']:.0f} "
             f"p50={1e3 * p50:.0f}ms p90={1e3 * p90:.0f}ms "
             f"p99={1e3 * p99:.0f}ms"
         )

@@ -14,8 +14,7 @@ Usage:  python demo/testnet.py [n_nodes] [--signal] [--accelerator]
 With --accelerator every node process runs device consensus sweeps on a
 chip of its OWN (a chip belongs to one process at a time): node i is pinned
 to chip i, and more node processes than chips is refused — many validators
-on one chip is the in-process path (bench.bench_16node_threads,
-chip_smoke.py). With --async every
+on one chip is the in-process path (chip_smoke.py). With --async every
 node runs the event-driven gossip engine + binary codec (docs/gossip.md)
 instead of the threaded JSON transport — mixed testnets work too. With
 --gateway a sharded light-client gateway (babble_tpu.client.gateway)
@@ -23,9 +22,9 @@ rides on top: submit at 127.0.0.1:16000, subscribe at 127.0.0.1:16001,
 proofs at http://127.0.0.1:16002. Stop with Ctrl-C (nodes leave politely
 on SIGTERM).
 
-Cleanup is hardened (a perfgate lesson — stray nodes from an aborted
-run poison later benches): children run in their own process group, a
-SIGTERM/SIGHUP handler and an atexit hook both tear the group down, and
+Cleanup is hardened (stray nodes from an aborted run squat the demo
+ports and starve whatever runs next): children run in their own process
+group, a SIGTERM/SIGHUP handler and an atexit hook both tear the group down, and
 every child PID is recorded in <testnet dir>/pids plus the well-known
 /tmp/babble_tpu_testnet.pids so `make killtestnet` can reap survivors
 of even a SIGKILLed driver.
@@ -174,8 +173,7 @@ def main() -> int:
             "chip(s) on this host. A chip belongs to one process at a "
             "time, so each node process needs its own. To run many "
             "validators on one chip use the in-process cluster: "
-            "python chip_smoke.py, or bench.bench_16node_threads("
-            "accelerator=True).",
+            "python chip_smoke.py.",
             file=sys.stderr,
         )
         return 2

@@ -25,39 +25,6 @@ alltests: test
 dryrun:
 	JAX_PLATFORMS=cpu python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
-bench:
-	python bench.py
-
-# benchsmoke: short 4-node in-process bench; asserts the compact summary
-# line (the driver's tail-capture contract) parses as JSON and carries
-# the headline metric
-benchsmoke:
-	JAX_PLATFORMS=cpu python bench.py --smoke | tail -n 1 | python -c "import json,sys; line=sys.stdin.read().strip(); d=json.loads(line); assert 'committed_txs_per_s_4node' in d, 'summary missing headline metric'; assert len(line) < 2000, 'summary too long'; print('benchsmoke ok:', d['committed_txs_per_s_4node'], 'tx/s')"
-
-# benchdag: dag_pipeline microbench, full-rebuild vs incremental
-# (device-resident) voting windows, with the per-stage sweep breakdown
-benchdag:
-	JAX_PLATFORMS=cpu python bench.py --dag
-
-# benchdagsmoke: small CI variant; asserts the JSON digest parses, both
-# arms reached identical consensus, and the stage breakdown is present
-benchdagsmoke:
-	JAX_PLATFORMS=cpu python bench.py --dag --smoke | tail -n 1 | python -c "import json,sys; d=json.loads(sys.stdin.read().strip()); assert d.get('consensus_match') is True, d; assert d['incremental']['stage_ms_per_sweep'], d; print('benchdagsmoke ok: snapshot', str(d['speedup_snapshot']) + 'x,', 'rebuilds', d['incremental']['rebuilds'])"
-
-# coprosmoke: multi-validator consensus coprocessor smoke — two
-# in-process validators share one 8-device virtual CPU mesh through the
-# sweep batcher's mesh lane; asserts per-validator consensus parity,
-# owner accounting, and the wedged-dispatch breaker trip (ISSUE 17)
-coprosmoke:
-	JAX_PLATFORMS=cpu python bench.py --copro --smoke | tail -n 1 | python -c "import json,sys; d=json.loads(sys.stdin.read().strip()); assert d.get('parity') is True, d; assert d.get('breaker_tripped') is True, d; assert d.get('copro_validators', 0) >= 2, d; print('coprosmoke ok:', d['copro_windows'], 'windows /', d['copro_waves'], 'waves from', d['copro_validators'], 'validators')"
-
-# mempoolsmoke: seeded overload smoke — submit ≥10x the commit rate
-# against a small admission cap; asserts bounded pending, a nonzero shed
-# rate, no lost/duplicated accepted txs, and committed throughput held
-# near the non-overloaded baseline (docs/mempool.md)
-mempoolsmoke:
-	JAX_PLATFORMS=cpu python bench.py --mempool --smoke | tail -n 1 | python -c "import json,sys; d=json.loads(sys.stdin.read().strip()); assert d['shed_rate'] and d['shed_rate'] > 0, d; assert not d['cap_exceeded'], d; assert d['accepted_lost'] == 0, d; assert d['accepted_dup_commits'] == 0, d; assert d['overload_ratio'] and d['overload_ratio'] > 0.5, d; print('mempoolsmoke ok: shed_rate', d['shed_rate'], 'ratio', d['overload_ratio'])"
-
 # chaossmoke: short-budget nemesis soak — 10% drop + duplication +
 # partition/heal on a 5-node in-mem cluster, plus the bounded
 # shutdown/leave-under-partition checks; deterministic under
@@ -86,14 +53,6 @@ byzsmoke:
 byzstorm:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_byzantine.py -q -m "byz"
 
-# obssmoke: observability smoke — boot 3 nodes, commit txs, scrape every
-# node's /metrics over HTTP; asserts valid Prometheus text, a populated
-# commit_latency_seconds histogram, every cataloged instrument present,
-# and the BABBLE_OBS=0 kill-switch overhead ratio ≥ 0.97
-# (docs/observability.md)
-obssmoke:
-	JAX_PLATFORMS=cpu python bench.py --obs --smoke | tail -n 1 | python -c "import json,sys; d=json.loads(sys.stdin.read().strip()); assert d['obs_ok'], d; assert d['commit_latency_samples'] > 0, d; assert not d['missing_metrics'], d; assert d['profile_stage_attributed'], d; oh=d.get('obs_overhead',{}); r=oh.get('ratio'); assert r is None or r >= 0.97, oh; po=d.get('profile_overhead',{}); cf=po.get('cpu_fraction'); assert cf is not None and cf < 0.02, po; assert po.get('samples_taken') is None or po['samples_taken'] > 0, po; print('obssmoke ok: clat p50', d['commit_latency_p50_ms'], 'ms, overhead ratio', r, 'profiler cpu_fraction', cf)"
-
 # metricslint: the instrument catalog and the docs table must match in
 # both directions (a new instrument cannot ship undocumented). Now a
 # thin shim over the babblelint metrics pass (docs/static_analysis.md).
@@ -103,24 +62,12 @@ metricslint:
 # staticcheck: babblelint, the project-wide static-analysis suite
 # (docs/static_analysis.md) — clock/RNG discipline, lock discipline,
 # knob drift, metrics drift, with self-linted inline allows. Then prove
-# its teeth the perfgate way: --self-proof injects one violation per
+# its teeth: --self-proof injects one violation per
 # pass (plus a stale allow) and exits nonzero unless EVERY pass fires,
 # so a toothless linter fails the build, not the code it guards.
 staticcheck:
 	python -m babble_tpu.analysis
 	python -m babble_tpu.analysis --self-proof
-
-# perfgate: the perf observatory's CI teeth (docs/observability.md
-# §Perf ledger & regression gate) — backfill the pre-ledger artifacts
-# (idempotent), run the smoke bench (appends its record to
-# BENCH_HISTORY.jsonl), gate it against the rolling same-host baseline,
-# then PROVE the gate fires: an injected 35% regression must exit
-# nonzero, else the build fails.
-perfgate:
-	python -m babble_tpu.obs.ledger --backfill
-	JAX_PLATFORMS=cpu python bench.py --smoke > /dev/null
-	python -m babble_tpu.obs.perfgate
-	@if python -m babble_tpu.obs.perfgate --inject-regression > /dev/null 2>&1; then echo "perfgate: inject-regression did NOT trip the gate"; exit 1; else echo "perfgate inject ok: gate fired on the injected regression"; fi
 
 # healthsmoke: cluster healthview end to end — a live 4-node cluster
 # with HTTP services merged over /metrics + /stats + /suspects; asserts
@@ -139,25 +86,6 @@ healthsmoke:
 tracesmoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_trace.py -q -m "not slow"
 
-# gossipsmoke: async gossip engine end to end — the adaptive-vs-fixed
-# A/B on an 8-node MULTI-PROCESS cluster (event-driven transport +
-# binary framed codec, docs/gossip.md); the arms differ only by
-# BABBLE_ADAPT. Asserts liveness (committed tx/s > 0), no-fork
-# (byte-identical block Body at a cluster-wide committed index, checked
-# over HTTP), a populated commit-latency histogram scraped from the
-# children's live /metrics, and the ISSUE-11 inequality: the adaptive
-# arm's committed tx/s >= the fixed arm's. The bench asserts internally
-# too; this re-checks the parseable summary line (driver tail contract).
-gossipsmoke:
-	JAX_PLATFORMS=cpu python bench.py --gossip --smoke | tail -n 1 | python -c "import json,sys; d=json.loads(sys.stdin.read().strip()); assert d['txs_per_s'] > 0, d; assert d['no_fork'] is True, d; assert d['clat_samples'] > 0, d; assert d['ab_ok'] is True, d; print('gossipsmoke ok:', d['txs_per_s'], 'tx/s adaptive vs', d.get('fixed_txs_per_s'), 'fixed (ratio', str(d.get('adaptive_vs_fixed_ratio')) + '), clat p50', d.get('clat_p50_ms'), 'ms')"
-
-# adaptsmoke: the adaptive-scheduler A/B by itself — 4-node in-process
-# cluster per arm under identical load, arms differing only by
-# BABBLE_ADAPT; ledger-recorded so perfgate bands the adaptive/fixed
-# throughput + p50 ratios (docs/gossip.md §Adaptive scheduling)
-adaptsmoke:
-	JAX_PLATFORMS=cpu python bench.py --adaptive --smoke | tail -n 1 | python -c "import json,sys; d=json.loads(sys.stdin.read().strip()); assert d['adaptive_txs_per_s'] > 0, d; assert d['fixed_txs_per_s'] > 0, d; print('adaptsmoke ok: adaptive', d['adaptive_txs_per_s'], 'vs fixed', d['fixed_txs_per_s'], 'tx/s (ratio', str(d.get('adaptive_vs_fixed_ratio')) + '), p50 improvement', d.get('p50_improvement_ratio'))"
-
 # clientsmoke: light-client gateway tier end to end (docs/clients.md) —
 # a live 4-validator TCP cluster with one sharded gateway and a
 # 100-subscriber swarm: every sampled accepted tx's GET /proof/<txid>
@@ -167,11 +95,6 @@ adaptsmoke:
 # latency; plus the adversarial proof/checkpoint unit coverage.
 clientsmoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_client.py -q -m "not slow"
-
-# clientbench: subscriber fan-out throughput + proof-serving latency,
-# ledger-recorded so perfgate bands regressions (bench.py --clients)
-clientbench:
-	JAX_PLATFORMS=cpu python bench.py --clients --smoke | tail -n 1 | python -c "import json,sys; d=json.loads(sys.stdin.read().strip()); assert d['sub_blocks_received'] > 0, d; assert d['sub_gaps'] == 0, d; assert d['proof_verify_ok'], d; print('clientbench ok:', d['fanout_blocks_per_s'], 'pushed blocks/s to', d['subscribers'], 'subs, proof p50', d['proof_latency_p50_ms'], 'ms')"
 
 # prunesmoke: lifecycle tier end to end (docs/lifecycle.md) — pruned-vs-
 # oracle digest equality in virtual time, the rotation/rejoin-from-
@@ -183,14 +106,8 @@ clientbench:
 prunesmoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_lifecycle.py -q -m "not slow"
 
-# prunebench: checkpoint-prune economics — retained-footprint ratio vs
-# an un-pruned same-seed control arm, with the digest-equality invariant
-# re-proven; ledger-recorded so perfgate bands regressions
-prunebench:
-	JAX_PLATFORMS=cpu python bench.py --prune --smoke | tail -n 1 | python -c "import json,sys; d=json.loads(sys.stdin.read().strip()); assert d['digest_match'], d; assert d['pruned']['prunes'] > 0, d; print('prunebench ok:', d['pruned']['rounds'], 'rounds,', d['pruned']['events_retained'], 'vs', d['control']['events_retained'], 'events retained (ratio', str(d['retained_ratio']) + '),', d['pruned']['prunes'], 'prunes')"
-
 # killtestnet: reap stray demo/testnet.py processes from an aborted run
-# — they squat the demo ports and poison later perfgate baselines. The
+# — they squat the demo ports and starve whatever runs next. The
 # well-known pidfile covers even a SIGKILLed driver; each recorded PID
 # is verified against /proc/<pid>/cmdline before any signal, so a PID
 # the OS recycled to an unrelated process is never touched. The pattern
@@ -225,4 +142,4 @@ simsweep:
 wheel:
 	python -m pip wheel . --no-deps -w dist
 
-.PHONY: native tests test flagtest extratests alltests dryrun bench benchsmoke benchdag benchdagsmoke coprosmoke mempoolsmoke chaossmoke chaossoak byzsmoke byzstorm obssmoke metricslint staticcheck perfgate healthsmoke tracesmoke gossipsmoke adaptsmoke clientsmoke clientbench prunesmoke prunebench killtestnet simsmoke simsweep wheel
+.PHONY: native tests test flagtest extratests alltests dryrun chaossmoke chaossoak byzsmoke byzstorm metricslint staticcheck healthsmoke tracesmoke clientsmoke prunesmoke killtestnet simsmoke simsweep wheel

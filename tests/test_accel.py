@@ -42,12 +42,14 @@ BUILDERS = {
 }
 
 
-def _replay(ordered, peer_set, sweep_events=None):
+def _replay(ordered, peer_set, sweep_events=None, chip=False):
     """Re-insert fresh copies of the signed events through the live driver.
 
     sweep_events=None runs the oracle pipeline per insert; an int attaches
     TensorConsensus with that mid-batch sweep threshold (plus the final
-    flush, mirroring core.sync's cadence)."""
+    flush, mirroring core.sync's cadence). ``chip`` makes it the lane a
+    chip resolves — pipelined sweeps launched by the batcher's thread —
+    and ends with the drain that brings deferred voting level."""
     h = Hashgraph(InmemStore(1000))
     h.init(peer_set)
     if sweep_events is not None:
@@ -55,11 +57,14 @@ def _replay(ordered, peer_set, sweep_events=None):
         # oracle-carried ones while a background compile warms up.
         # min_window=0 forces the device path regardless of window size.
         h.accel = TensorConsensus(sweep_events=sweep_events,
-                                  async_compile=False, min_window=0)
+                                  async_compile=False, min_window=0,
+                                  pipeline=chip, batcher=chip)
     for ev in ordered:
         h.insert_event_and_run_consensus(Event(ev.body, ev.signature),
                                          set_wire_info=True)
     h.flush_consensus()
+    if chip:
+        h.drain_consensus()
     return h
 
 
@@ -137,12 +142,15 @@ def drain_pipelined(hg, max_iters: int = 200) -> None:
         prev = cur
 
 
+@pytest.mark.parametrize("batcher", [False, True])
 @pytest.mark.parametrize("graph", list(BUILDERS))
-def test_accel_pipelined_matches_oracle(graph):
+def test_accel_pipelined_matches_oracle(graph, batcher):
     """The non-blocking pipelined mode (the real-accelerator default, where
     flushes apply the PREVIOUS sweep's results while the next computes)
     must converge to the oracle's exact consensus state. Forced on the CPU
-    mesh here; each insert's flush may defer, so drain at the end."""
+    mesh here; each insert's flush may defer, so drain at the end. With
+    ``batcher`` on this is the lane a chip resolves and both benchmark
+    cells run: every launch goes through the sweep batcher's thread."""
     h, index, nodes, peer_set = BUILDERS[graph]()
     ordered = _ordered_events(h)
     oracle = _replay(ordered, peer_set)
@@ -150,13 +158,14 @@ def test_accel_pipelined_matches_oracle(graph):
     hp = Hashgraph(InmemStore(1000))
     hp.init(peer_set)
     hp.accel = TensorConsensus(sweep_events=3, async_compile=False,
-                               min_window=0, pipeline=True)
+                               min_window=0, pipeline=True, batcher=batcher)
     for ev in ordered:
         hp.insert_event_and_run_consensus(Event(ev.body, ev.signature),
                                           set_wire_info=True)
     drain_pipelined(hp)
     assert hp.accel.sweeps > 0
     assert hp.accel.fallbacks == 0
+    assert hp.accel.stats()["accel_batcher"] is batcher
     assert _consensus_state(hp) == _consensus_state(oracle)
 
 
@@ -249,13 +258,16 @@ def test_flock_slots_thread_exclusion(tmp_path):
     s.release()  # over-release is a no-op
 
 
+@pytest.mark.parametrize("lane", ["synchronous", "chip"])
 @pytest.mark.parametrize("seed,n_peers", [(11, 4), (12, 6), (13, 9)])
-def test_accel_matches_oracle_random_streams(seed, n_peers):
+def test_accel_matches_oracle_random_streams(seed, n_peers, lane):
     """Randomized differential: seeded random gossip streams (not just the
     hand-drawn golden DAGs) through the oracle and the device sweep must
     produce identical consensus state — fame, round-received, and block
     bodies. Catches shape/mask bugs the fixed fixtures can't reach
-    (padding buckets, larger peer counts, deeper round structure)."""
+    (padding buckets, larger peer counts, deeper round structure). The
+    ``chip`` lane is what a chip resolves (pipelined, through the batcher),
+    brought level with ``Hashgraph.drain_consensus()``."""
     from babble_tpu.parallel.voting_shard import synthetic_voting_window
 
     h, _ = synthetic_voting_window(
@@ -264,9 +276,10 @@ def test_accel_matches_oracle_random_streams(seed, n_peers):
     ordered = _ordered_events(h)
     peer_set = h.store.get_peer_set(0)
     oracle = _replay(ordered, peer_set)
-    accel = _replay(ordered, peer_set, sweep_events=13)
+    accel = _replay(ordered, peer_set, sweep_events=13, chip=lane == "chip")
     assert accel.accel.sweeps > 0
     assert accel.accel.fallbacks == 0
+    assert accel.accel.stats()["accel_batcher"] is (lane == "chip")
     assert _consensus_state(accel) == _consensus_state(oracle)
 
 
@@ -291,8 +304,10 @@ def _drain_as_the_benchmark_does(hg, seconds: float = 30.0) -> None:
     raise AssertionError("the drain never quiesced")
 
 
+@pytest.mark.parametrize("batcher", [False, True])
 @pytest.mark.parametrize("outcome", ["compiled", "failed"])
-def test_drain_that_meets_a_compile_wait_ends_decided(monkeypatch, outcome):
+def test_drain_that_meets_a_compile_wait_ends_decided(monkeypatch, outcome,
+                                                      batcher):
     """async_compile on, one bucket left uncompiled: a flush applies a
     sweep's result and its relaunch finds the window's bucket not ready. It
     has reported "handled", nothing is in flight and nothing is pending —
@@ -331,7 +346,7 @@ def test_drain_that_meets_a_compile_wait_ends_decided(monkeypatch, outcome):
     hp.init(peer_set)
     accel = hp.accel = TensorConsensus(
         sweep_events=10_000, async_compile=True, min_window=0, pipeline=True,
-        batcher=False)
+        batcher=batcher)
     half = len(ordered) // 2
     for ev in ordered[:half]:
         hp.insert_event_and_run_consensus(Event(ev.body, ev.signature),

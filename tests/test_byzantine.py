@@ -297,9 +297,9 @@ def test_equivocation_soak_quarantine_proofs_and_restart(tmp_path):
     """Acceptance (ISSUE-5) — with the ISSUE-15 retry-once corroboration:
     this soak is the known under-load tier-1 flake (it passes standalone;
     a loaded host can starve the 4-node cluster past the drive window).
-    Same pattern as gossipsmoke's A/B re-run: a first-attempt assertion
-    failure triggers ONE full fresh-cluster re-run, and only a failure of
-    BOTH runs fails the test — corroboration, not masking: a real
+    A first-attempt assertion failure triggers ONE full fresh-cluster
+    re-run, and only a failure of BOTH runs fails the test —
+    corroboration, not masking: a real
     regression fails twice, a host-load artifact doesn't repeat."""
     try:
         _equivocation_soak_attempt(tmp_path / "run1")

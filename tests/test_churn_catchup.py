@@ -49,15 +49,20 @@ def _backlog(seed=SEED, n_events=600, eager=False, tag=0):
     return keys, peers, genesis, requests, script, wires, final
 
 
-def _core(keys, genesis, pipeline):
+BATCHER = pytest.mark.parametrize("batcher", [False, True],
+                                  ids=["direct", "batcher"])
+
+
+def _core(keys, genesis, pipeline, batcher=False):
     """v0's core as ``Node`` builds it with ``--accelerator``, the flush
-    gate scaled to a 4-validator window and compiles inline."""
+    gate scaled to a 4-validator window and compiles inline. Pipelined
+    with ``batcher`` on is the lane a chip resolves and ``churn16`` runs."""
     core = Core(Validator(keys[ME], "v0"), genesis, genesis,
                 InmemStore(10000), dummy_commit_response,
                 accelerated_verify=True)
     tc = core.hg.accel
     tc.min_window, tc.async_compile = 16, False
-    tc.pipeline, tc.batcher = pipeline, False
+    tc.pipeline, tc.batcher = pipeline, batcher
     return core
 
 
@@ -136,7 +141,8 @@ def test_the_validator_equals_the_reference_across_the_changes(pipeline):
     assert "repertoire-change" in by_reason
 
 
-def test_a_joiners_first_event_in_the_sync_that_admits_it_is_stored():
+@BATCHER
+def test_a_joiners_first_event_in_the_sync_that_admits_it_is_stored(batcher):
     """The first sync of 300 holds the request for x0 (event 40), the block
     that admits it and x0's first event. Sequential decode + insert +
     consensus accepts it whole; with voting deferred the peer-set has to be
@@ -144,7 +150,7 @@ def test_a_joiners_first_event_in_the_sync_that_admits_it_is_stored():
     keys, peers, genesis, _requests, script, wires, _final = _backlog()
     first_x0 = next(i for i, s in enumerate(script) if s.creator == N_GENESIS)
     assert 40 < first_x0 < 300
-    core = _core(keys, genesis, pipeline=True)
+    core = _core(keys, genesis, pipeline=True, batcher=batcher)
     chunk = wires[:300]
     prepared = core.prepare_sync(chunk)
     assert len(prepared.decoded) == first_x0  # the decode stall
@@ -155,14 +161,15 @@ def test_a_joiners_first_event_in_the_sync_that_admits_it_is_stored():
     assert core.membership_changes_applied == 1
 
 
-def test_an_eager_joiner_stalls_the_sync_until_voting_is_drained():
+@BATCHER
+def test_an_eager_joiner_stalls_the_sync_until_voting_is_drained(batcher):
     """A joiner that starts as soon as a sequential validator has committed
     its admission, rounds before it is a member: no round's peer-set is in
     doubt yet, so nothing has been waited for, and its creator id is
     unknown where voting lags. ``Core.sync`` drains and decodes again."""
     keys, peers, genesis, requests, _script, wires, final = _backlog(
         eager=True)
-    core = _core(keys, genesis, pipeline=True)
+    core = _core(keys, genesis, pipeline=True, batcher=batcher)
     _ingest(core, wires, peers[1].id, 300)
     assert core.sync_creator_stalls >= 1
     snap = core.obs.registry.snapshot()
@@ -171,14 +178,16 @@ def test_an_eager_joiner_stalls_the_sync_until_voting_is_drained():
     assert got.blocks.ok and got.changes_not_applied == 0, got.note
     assert got.peer_sets_differing == 0
     assert core.hg.accel.fallbacks == 0
+    assert core.hg.accel.stats()["accel_batcher"] is batcher
 
 
-def test_a_stall_that_survives_a_drained_pipeline_is_raised():
+@BATCHER
+def test_a_stall_that_survives_a_drained_pipeline_is_raised(batcher):
     from babble_tpu.hashgraph.errors import UnknownParticipantError
 
     keys, peers, genesis, _requests, script, wires, _final = _backlog()
     first_x0 = next(i for i, s in enumerate(script) if s.creator == N_GENESIS)
-    core = _core(keys, genesis, pipeline=True)
+    core = _core(keys, genesis, pipeline=True, batcher=batcher)
     # x0's first event with its admission left out: nobody's to resolve
     chunk = wires[:40] + wires[first_x0:first_x0 + 1]
     with pytest.raises(UnknownParticipantError):
@@ -187,14 +196,16 @@ def test_a_stall_that_survives_a_drained_pipeline_is_raised():
         core.hg.store, keys[ME].public_key.hex()) == 40
 
 
-def test_a_backlog_with_no_request_never_waits():
+@BATCHER
+def test_a_backlog_with_no_request_never_waits(batcher):
     keys = data.seeded_keys(N_GENESIS, SEED)
     peers = data.peer_set(keys, [f"inmem://v{i}" for i in range(N_GENESIS)])
     wires = data.backlog_wire_events(keys, peers, [1, 2, 3], 600, DAG_SEED,
                                      100)
-    core = _core(keys, peers, pipeline=True)
+    core = _core(keys, peers, pipeline=True, batcher=batcher)
     _ingest(core, wires, peers.by_pub_key[keys[1].public_key.hex()].id, 300)
     assert core.hg.accel.sweeps > 0
+    assert core.hg.accel.stats()["accel_batcher"] is batcher
     assert core.sync_creator_stalls == 0 and core.hg.peer_set_waits == 0
     assert core.membership_changes_applied == 0
     stages = core.obs.registry.snapshot()["sync_stage_seconds"]

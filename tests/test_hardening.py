@@ -101,10 +101,13 @@ def test_direct_upgrade_over_tls_relay(tmp_path):
         srv.close()
 
 
-def test_reset_invalidates_inflight_sweep_without_corruption():
+@pytest.mark.parametrize("batcher", [False, True])
+def test_reset_invalidates_inflight_sweep_without_corruption(batcher):
     """A fast-sync style reset while a pipelined sweep is in flight: the
     stale sweep must be dropped (generation bump), its admission slot
-    reclaimed, and subsequent consensus must match the oracle exactly."""
+    reclaimed (direct launch) or its ticket's result left unapplied (the
+    batcher, the lane a chip resolves), and subsequent consensus must
+    match the oracle exactly."""
     from babble_tpu.hashgraph import Event, Hashgraph, InmemStore
     from babble_tpu.hashgraph.accel import TensorConsensus
     from test_accel import BUILDERS, _consensus_state, _ordered_events, \
@@ -117,7 +120,7 @@ def test_reset_invalidates_inflight_sweep_without_corruption():
     h = Hashgraph(InmemStore(1000))
     h.init(peer_set)
     acc = TensorConsensus(sweep_events=10**9, async_compile=False,
-                          min_window=0, pipeline=True)
+                          min_window=0, pipeline=True, batcher=batcher)
     h.accel = acc
     half = len(ordered) // 2
     for ev in ordered[:half]:

@@ -439,8 +439,11 @@ def _preregister(steps):
 
 
 @pytest.mark.parametrize("script", list(SCRIPTS))
-@pytest.mark.parametrize("mode", ["sync", "pipelined"])
+@pytest.mark.parametrize("mode", ["sync", "pipelined", "chip"])
 def test_dyn_accel_matches_oracle(script, mode):
+    """``chip`` is the lane a chip resolves: multi-slot windows through
+    the batcher's re-padding WHILE pipelined (the batched test below
+    launches them synchronously)."""
     steps, index = SCRIPTS[script]()
     steps = _preregister(steps)
     oracle = _build(steps, run_consensus=True)
@@ -448,10 +451,11 @@ def test_dyn_accel_matches_oracle(script, mode):
         sweep_events=3,
         async_compile=False,
         min_window=0,
-        pipeline=(mode == "pipelined"),
+        pipeline=(mode != "sync"),
+        batcher=(mode == "chip"),
     )
     dev = _build(steps, accel=accel, run_consensus=True)
-    if mode == "pipelined":
+    if mode != "sync":
         drain_pipelined(dev)
     assert accel.sweeps > 0
     assert accel.fallbacks == 0

@@ -304,12 +304,14 @@ def test_round_eviction_triggers_rebuild():
     assert snap is None or snap.rebuilt
 
 
-def test_stale_generation_readback_discarded():
+@pytest.mark.parametrize("batcher", [False, True])
+def test_stale_generation_readback_discarded(batcher):
     """Donation safety: a pipelined sweep launched from generation N whose
     readback lands after generation N+1 mutated the resident state is
     discarded by the generation check (accel_stale_drops), the oracle
     carries the flush, and consensus converges to the oracle's exact
-    state."""
+    state. With ``batcher`` on (the lane a chip resolves) the window went
+    out as ``snapshot(copy_rows=True)`` copies, which no other lane takes."""
     h0, index, nodes, peer_set = BUILDERS["consensus"]()
     ordered = _ordered_events(h0)
     oracle = _replay(ordered, peer_set)
@@ -317,7 +319,8 @@ def test_stale_generation_readback_discarded():
     h = Hashgraph(InmemStore(1000))
     h.init(peer_set)
     h.accel = TensorConsensus(sweep_events=3, async_compile=False,
-                              min_window=0, pipeline=True, resident=True)
+                              min_window=0, pipeline=True, resident=True,
+                              batcher=batcher)
     for ev in ordered:
         h.insert_event_and_run_consensus(Event(ev.body, ev.signature),
                                          set_wire_info=True)
@@ -426,10 +429,11 @@ def test_skipped_dispatch_reseeds_residency():
         )
 
 
-def test_resident_pipelined_matches_oracle():
+@pytest.mark.parametrize("batcher", [False, True])
+def test_resident_pipelined_matches_oracle(batcher):
     """The pipelined resident path (deltas + donated buffers + deferred
-    applies) converges to the oracle's exact consensus on the golden
-    DAGs."""
+    applies; through the batcher, host mirrors and copied rows) converges
+    to the oracle's exact consensus on the golden DAGs."""
     h0, index, nodes, peer_set = BUILDERS["funky_full"]()
     ordered = _ordered_events(h0)
     oracle = _replay(ordered, peer_set)
@@ -437,7 +441,8 @@ def test_resident_pipelined_matches_oracle():
     hp = Hashgraph(InmemStore(1000))
     hp.init(peer_set)
     hp.accel = TensorConsensus(sweep_events=3, async_compile=False,
-                               min_window=0, pipeline=True, resident=True)
+                               min_window=0, pipeline=True, resident=True,
+                               batcher=batcher)
     for ev in ordered:
         hp.insert_event_and_run_consensus(Event(ev.body, ev.signature),
                                           set_wire_info=True)
@@ -449,7 +454,7 @@ def test_resident_pipelined_matches_oracle():
 def test_resident_stats_surface():
     """The new counters ride TensorConsensus.stats() (and therefore node
     get_stats): rows_delta/rows_reused/rebuilds, the stale-drop counter,
-    and the per-stage breakdown keys the bench records."""
+    and the per-stage breakdown keys the benchmark reads."""
     events, peers, _keys = _stream(n_peers=6, n_events=120, seed=16)
     acc = TensorConsensus(sweep_events=8, async_compile=False,
                           min_window=0, pipeline=False, batcher=False,

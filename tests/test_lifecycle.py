@@ -322,8 +322,7 @@ def test_long_horizon_plateau_10k_rounds(tmp_path):
         # digest equality over the COMMON PREFIX: under a sustained tx
         # pump the nodes' committed tips legitimately lag each other by
         # a block or two at any instant — tip lag is pipelining, a fork
-        # is a body-hash mismatch at the same index (the prunebench
-        # contract, bench.py bench_prune)
+        # is a body-hash mismatch at the same index
         tip = min(n.get_last_block_index() for n in cl.nodes)
         assert tip > 1000, f"common tip only {tip} after 10k rounds"
         for bi in range(tip + 1):

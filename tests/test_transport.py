@@ -377,6 +377,9 @@ def test_tcp_stale_pooled_socket_retries_on_fresh_dial():
     """A pooled socket the peer has since closed must not fail the RPC:
     the pool is evicted and the RPC retried once on a fresh dial
     (ISSUE-3 satellite: TCP pool hardening)."""
+    import select
+    import socket
+
     srv, addr, stop, served = _one_shot_server(
         {"SyncRequest": SyncResponse(from_id=5, events=[], known={})}
     )
@@ -386,11 +389,20 @@ def test_tcp_stale_pooled_socket_retries_on_fresh_dial():
         assert cli.sync(addr, req).from_id == 5
         # the socket went back to the pool, but the server closed its end
         with cli._pool_lock:
-            assert sum(len(v) for v in cli._pool.values()) == 1
-        time.sleep(0.1)  # let the server-side FIN land
+            (pooled,) = [c for v in cli._pool.values() for c in v]
+        # wait until the server-side FIN has landed: readable, and empty
+        deadline = time.monotonic() + 5.0
+        while True:
+            readable, _, _ = select.select([pooled], [], [], 0.05)
+            if readable and pooled.recv(1, socket.MSG_PEEK) == b"":
+                break
+            assert time.monotonic() < deadline, "the server never hung up"
         assert cli.sync(addr, req).from_id == 5  # salvaged by the retry
         assert cli.retries == 1
         assert cli.pool_evictions >= 1
+        # the server thread counts an RPC after it has answered it
+        while len(served) < 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
         assert len(served) == 2
     finally:
         stop.set()
