@@ -2,22 +2,23 @@
 
 Semantics from the reference (cited for parity checks, not copied):
 - EventBody fields and hashing: /root/reference/src/hashgraph/event.go:21-64
-- coordinates maps (lastAncestors / firstDescendants): event.go:70-120
+- coordinates (lastAncestors / firstDescendants): event.go:70-120
 - sign/verify incl. internal-transaction signatures: event.go:201-247
 - wire format replacing parent hashes with (creatorID, index): event.go:411-449
 - FrameEvent wrapper and the two sort orders (topological vs
   Lamport+signature-R consensus order): event.go:457-511
 
-TPU-first notes: the string-keyed coordinate maps here are the *oracle*
-representation. The JAX kernels in ``babble_tpu.ops.dag`` consume dense
-``[n_events, n_peers] int32`` snapshots of the same data; ``peer_index`` in
-:class:`babble_tpu.peers.PeerSet` fixes the tensor coordinate of each peer.
+TPU-first notes: an event's coordinates are integer rows in the column
+space of the ``Hashgraph`` that inserted it (one column per participant, in
+order of first registration). The JAX kernels in ``babble_tpu.ops`` consume
+dense ``[n_events, n_peers] int32`` snapshots of the same rows, permuted to
+their own peer columns (``Hashgraph.coord_columns``).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import List, Optional
 
 from babble_tpu.crypto.canonical import (
     CacheStats,
@@ -47,8 +48,11 @@ def decode_hash(s: str) -> bytes:
 
 @dataclass
 class EventCoordinates:
-    """(hash, index) of an event, used by the stronglySee predicate
-    (reference: event.go:70-74)."""
+    """(hash, index) of an event (reference: event.go:70-74). A cold type:
+    the value of ``Hashgraph.last_ancestors`` / ``first_descendants``, the
+    dicts built on demand for tests and debugging. Nothing on the insert,
+    DivideRounds, snapshot or voting-window paths makes one: there an
+    event's coordinates are the integer rows of ``Event``."""
 
     hash: str
     index: int
@@ -322,8 +326,17 @@ class Event:
         self.round: Optional[int] = None
         self.lamport_timestamp: Optional[int] = None
         self.round_received: Optional[int] = None
-        self.last_ancestors: Dict[str, EventCoordinates] = {}
-        self.first_descendants: Dict[str, EventCoordinates] = {}
+        # Coordinates, in the column space of the Hashgraph that inserted
+        # the event; None until then, and on an event reloaded from a
+        # PersistentStore row (which never held them): all missing.
+        # last_ancestors: int64 row, per column the index of that
+        # creator's last event this one descends from. first_descendants:
+        # a list of plain ints, per column the index of that creator's
+        # first event that descends from this one; the insert-time walk
+        # of later events fills it, and extends it when the repertoire
+        # has outgrown it. Rows of different events may differ in width.
+        self.last_ancestors = None  # Optional[np.ndarray]
+        self.first_descendants: Optional[List[int]] = None
         self._creator: str = ""
         self._hash: bytes = b""
         self._hex: str = ""

@@ -29,7 +29,12 @@ from babble_tpu.common.errors import StoreError, StoreErrorKind, is_store_err
 from babble_tpu.common.lru import LRU
 from babble_tpu.common.utils import median_int
 from babble_tpu.hashgraph.block import Block
-from babble_tpu.hashgraph.caches import PendingRound, PendingRoundsCache, SigPool
+from babble_tpu.hashgraph.caches import (
+    INT32_MAX,
+    PendingRound,
+    PendingRoundsCache,
+    SigPool,
+)
 from babble_tpu.hashgraph.errors import (
     ForkError,
     InvalidSignatureError,
@@ -90,14 +95,34 @@ def dummy_commit_callback(block: Block) -> None:
 _LA_MISSING = -(2**62)
 _FD_MISSING = 2**62
 
+# Coordinate rows are allocated to the next multiple of this many columns,
+# as the device's P bucket is (16 -> 24): a joiner finds room in the rows
+# that are there, and only the ninth of a run of joiners widens them.
+_COORD_ROW_STEP = 8
+
+
+def _widened(row: np.ndarray, width: int) -> np.ndarray:
+    """A last-ancestor row made before the repertoire outgrew it, at the
+    width of today's rows: the columns it never had are missing."""
+    wide = np.full(width, _LA_MISSING, dtype=np.int64)
+    wide[: len(row)] = row
+    return wide
+
 
 class _RoundCtx:
     """Per-round data resolved ONCE and reused across the whole ingest
-    batch: the round's peer-set columns, super-majority, witness list, and
-    the witnesses' first-descendant coordinates as one dense matrix. This
+    batch: the round's peer-set, super-majority, witness list, and the
+    witnesses' first-descendant coordinates as one dense matrix. This
     turns the per-event ``strongly_see`` loop in ``_round`` (and the
     per-voter loop in DecideFame's oracle) into a single vectorized
     compare — the dict-walk version is the profiled host-tail hotspot.
+
+    The matrix lives in the SAME column space as the events' coordinate
+    rows (``Hashgraph._coord_col``), ``width`` columns as the rows had when
+    it was built, so an event's last-ancestor row is compared with it as it
+    stands. ``col`` maps the peer-set's members to their columns; the
+    column of a creator outside the peer-set keeps ``_FD_MISSING`` in every
+    row, which is what having no column means.
 
     An entry of ``Hashgraph._round_ctx`` is kept equal to what
     ``_build_round_ctx`` would build by the two places that change its
@@ -110,25 +135,45 @@ class _RoundCtx:
     ctx was not told of, a round with more witnesses than peers,
     ``prune_below``, ``reset`` and the 128-entry trim."""
 
-    __slots__ = ("peer_set", "sm", "col", "wits", "row", "fd", "_rows",
-                 "n_created")
+    __slots__ = ("peer_set", "sm", "col", "width", "_out", "wits", "row",
+                 "fd", "_rows", "n_created")
 
-    def __init__(self, peer_set, wits, fd, n_created):
+    def __init__(self, peer_set, col, width, wits, first_descendants,
+                 n_created):
         self.peer_set = peer_set
         self.sm = peer_set.super_majority()
-        self.col = {pk: i for i, pk in enumerate(peer_set.pub_keys())}
+        self.col = col
+        self.width = width
+        out = np.ones(width, dtype=bool)
+        out[list(col.values())] = False
+        # the columns of creators outside the peer-set; None when every
+        # column is a member's (a ring with no churn)
+        self._out = out if out.any() else None
         self.wits = wits
         self.row = {w: i for i, w in enumerate(wits)}
-        # int64 [n_wit, n_peers], missing = _FD_MISSING: the filled rows
+        # int64 [n_wit, width], missing = _FD_MISSING: the filled rows
         # of _rows, which add_witness gives room for the round's most
-        self.fd = fd
-        self._rows = fd
+        self.fd = np.full((len(wits), width), _FD_MISSING, dtype=np.int64)
+        self._rows = self.fd
+        for i, fd in enumerate(first_descendants):
+            self._fill(self.fd[i], fd)
         self.n_created = n_created
+
+    def _fill(self, row: np.ndarray, first_descendants) -> None:
+        """A witness's matrix row from its first-descendant row as it
+        stands (None: an event without coordinates, all missing; wider
+        than the matrix: the repertoire grew since, by creators who are
+        not of this peer-set)."""
+        if first_descendants is not None:
+            n = min(len(first_descendants), self.width)
+            row[:n] = first_descendants[:n]
+        if self._out is not None:
+            row[self._out] = _FD_MISSING
 
     def set_first_descendant(self, w: str, creator: str, index: int) -> bool:
         """Witness ``w`` gained ``creator``'s first descendant. False when
         the matrix has no such entry: ``w`` is not a witness of this round,
-        or the creator has no column in its peer-set."""
+        or the creator is not of its peer-set."""
         i = self.row.get(w)
         j = self.col.get(creator)
         if i is None or j is None:
@@ -145,14 +190,10 @@ class _RoundCtx:
         if n == len(self._rows):
             if n >= n_peers:
                 return False
-            rows = np.full((n_peers, n_peers), _FD_MISSING, dtype=np.int64)
+            rows = np.full((n_peers, self.width), _FD_MISSING, dtype=np.int64)
             rows[:n] = self.fd
             self._rows = rows
-        row = self._rows[n]
-        for p, e in first_descendants.items():
-            j = self.col.get(p)
-            if j is not None:
-                row[j] = e.index
+        self._fill(self._rows[n], first_descendants)
         self.fd = self._rows[: n + 1]
         self.row[w] = n
         self.wits.append(w)
@@ -264,6 +305,29 @@ class Hashgraph:
         # matrices built at lookup (first use of a round, or a dropped entry)
         self.round_ctx_patches = 0
         self.round_ctx_rebuilds = 0
+        # The column space of event coordinates: one column per
+        # participant of the store's repertoire, in order of first
+        # registration, append-only (a joiner gets the next column, a
+        # leaver keeps its own). Event.last_ancestors, first_descendants
+        # and every _RoundCtx matrix are rows over these columns,
+        # _coord_width wide when made. _chains is column -> {index:
+        # Event}: the events the first-descendant walk reaches by
+        # (creator, index). It holds what this hashgraph inserted and the
+        # store still caches, nothing else: an eviction (on_event_evicted),
+        # prune_below and reset take an event out, and an ancestor that is
+        # not here ends its chain's walk.
+        self._clear_coordinates()
+        store.on_event_evicted(self._let_go)
+        # entries the walk wrote, and how often the repertoire outgrew
+        # the rows' width (rows made before stay as narrow as they were)
+        self.fd_walk_steps = 0
+        self.coord_row_regrows = 0
+
+    def _clear_coordinates(self) -> None:
+        self._coord_col: Dict[str, int] = {}
+        self._coord_keys: List[str] = []
+        self._coord_width = 0
+        self._chains: List[Dict[int, Event]] = []
 
     def init(self, peer_set: PeerSet) -> None:
         """Set the genesis peer-set at round 0 (reference: hashgraph.go:84-89).
@@ -295,10 +359,11 @@ class Hashgraph:
     def _ancestor(self, x: str, y: str) -> bool:
         if x == y:
             return True
-        ex = self.store.get_event(x)
+        la = self.store.get_event(x).last_ancestors
         ey = self.store.get_event(y)
-        entry = ex.last_ancestors.get(ey.creator())
-        return entry is not None and entry.index >= ey.index()
+        j = self._coord_col.get(ey.creator())
+        return (la is not None and j is not None and j < len(la)
+                and bool(la[j] >= ey.index()))
 
     def self_ancestor(self, x: str, y: str) -> bool:
         """True if y is a self-ancestor of x (reference: hashgraph.go:131-158)."""
@@ -333,31 +398,31 @@ class Hashgraph:
         return ss
 
     def _strongly_see(self, x: str, y: str, peers: PeerSet) -> bool:
-        ex = self.store.get_event(x)
-        ey = self.store.get_event(y)
+        """The definition, one pair of events and one peer at a time (the
+        insert path compares a whole round at once, _strongly_seen_mask).
+        A missing entry is a sentinel or a column the row never had."""
+        la = self.store.get_event(x).last_ancestors
+        fd = self.store.get_event(y).first_descendants
+        if la is None or fd is None:
+            return False
+        n = min(len(la), len(fd))
         c = 0
         for p in peers.pub_keys():
-            xla = ex.last_ancestors.get(p)
-            yfd = ey.first_descendants.get(p)
-            if xla is not None and yfd is not None and xla.index >= yfd.index:
+            j = self._coord_col.get(p)
+            if j is not None and j < n and la[j] >= fd[j]:
                 c += 1
         return c >= peers.super_majority()
 
     def _build_round_ctx(self, peer_set, wits, n_created) -> _RoundCtx:
-        """Densify the witnesses' first-descendant coordinates into one
-        int64 matrix so strongly-see against ALL of a round's witnesses is
-        a single vectorized compare (the exact computation the device
+        """Stack the witnesses' first-descendant rows into one int64
+        matrix so strongly-see against ALL of a round's witnesses is a
+        single vectorized compare (the exact computation the device
         voting window performs on its fd/la tables — see ops/voting)."""
-        fd = np.full(
-            (len(wits), len(peer_set.pub_keys())), _FD_MISSING, dtype=np.int64
+        col = {pk: self._column_of(pk) for pk in peer_set.pub_keys()}
+        fds = [self.store.get_event(w).first_descendants for w in wits]
+        return _RoundCtx(
+            peer_set, col, self._coord_width, wits, fds, n_created
         )
-        col = {pk: i for i, pk in enumerate(peer_set.pub_keys())}
-        for i, w in enumerate(wits):
-            for p, e in self.store.get_event(w).first_descendants.items():
-                j = col.get(p)
-                if j is not None:
-                    fd[i, j] = e.index
-        return _RoundCtx(peer_set, wits, fd, n_created)
 
     def _round_ctx_for(self, r: int, round_info, peer_set) -> _RoundCtx:
         """Cached per-round ctx, revalidated cheaply on every lookup: a
@@ -412,13 +477,14 @@ class Hashgraph:
         """Boolean mask over ctx.wits: which witnesses x strongly sees.
         Missing-coordinate sentinels guarantee ``la >= fd`` is False when
         either side is absent, for any real (non-negative) index."""
-        ex = self.store.get_event(x)
-        la = np.full((len(ctx.col),), _LA_MISSING, dtype=np.int64)
-        for p, e in ex.last_ancestors.items():
-            j = ctx.col.get(p)
-            if j is not None:
-                la[j] = e.index
-        return (la[None, :] >= ctx.fd).sum(axis=1) >= ctx.sm
+        la = self.store.get_event(x).last_ancestors
+        if la is None:
+            return np.zeros(len(ctx.wits), dtype=bool)
+        if len(la) > ctx.width:
+            la = la[: ctx.width]
+        elif len(la) < ctx.width:
+            la = _widened(la, ctx.width)
+        return (la >= ctx.fd).sum(axis=1) >= ctx.sm
 
     # =========================================================================
     # Round / witness / timestamps
@@ -468,7 +534,7 @@ class Hashgraph:
         ctx = self._round_ctx_for(
             parent_round, parent_round_obj, parent_round_peer_set
         )
-        c = int(self._strongly_seen_mask(x, ctx).sum()) if ctx.wits else 0
+        c = np.count_nonzero(self._strongly_seen_mask(x, ctx)) if ctx.wits else 0
         if c >= parent_round_peer_set.super_majority():
             round_ += 1
         return round_
@@ -584,75 +650,163 @@ class Hashgraph:
             except StoreError:
                 raise UnknownParentError("other-parent not known")
 
+    def _column_of(self, pub_key: str) -> int:
+        """The coordinate column of a participant. One that has none yet
+        brings in whatever the store's repertoire has gained, in its order
+        (a dict in order of first registration), then itself."""
+        col = self._coord_col.get(pub_key)
+        if col is not None:
+            return col
+        for pk in (*self.store.repertoire_by_pub_key(), pub_key):
+            if pk not in self._coord_col:
+                self._coord_col[pk] = len(self._coord_keys)
+                self._coord_keys.append(pk)
+                self._chains.append({})
+        n = len(self._coord_keys)
+        width = -(-n // _COORD_ROW_STEP) * _COORD_ROW_STEP
+        if width != self._coord_width:
+            if self._coord_width:
+                self.coord_row_regrows += 1
+            self._coord_width = width
+        return self._coord_col[pub_key]
+
+    def coord_columns(self, pub_keys) -> np.ndarray:
+        """The coordinate columns of ``pub_keys``, for a reader that keeps
+        peer columns of its own (the device's windows sort the repertoire):
+        ``row[coord_columns(keys)]`` is a row in the reader's order."""
+        return np.array([self._column_of(pk) for pk in pub_keys], dtype=np.intp)
+
+    def window_coordinates(self, event: Event, src: np.ndarray) -> tuple:
+        """``event``'s two rows as the device's windows hold them: int32,
+        column j taken from coordinate column ``src[j]`` (coord_columns),
+        missing = -1 / INT32_MAX."""
+        width = self._coord_width
+        la = event.last_ancestors
+        if la is None:
+            la = np.full(len(src), -1, dtype=np.int32)
+        else:
+            if len(la) < width:
+                la = _widened(la, width)
+            la = np.maximum(la[src], -1).astype(np.int32)
+        fd = np.full(width, INT32_MAX, dtype=np.int64)
+        if event.first_descendants is not None:
+            fd[: len(event.first_descendants)] = event.first_descendants
+        return la, np.minimum(fd[src], INT32_MAX).astype(np.int32)
+
+    def last_ancestors(self, x: str) -> Dict[str, EventCoordinates]:
+        """``x``'s last ancestors as the reference's map, pub key ->
+        (hash, index). Cold: for tests and debugging."""
+        return self._coordinates(
+            self.store.get_event(x).last_ancestors, _LA_MISSING)
+
+    def first_descendants(self, x: str) -> Dict[str, EventCoordinates]:
+        """``x``'s first descendants as the reference's map. Cold."""
+        return self._coordinates(
+            self.store.get_event(x).first_descendants, _FD_MISSING)
+
+    def _coordinates(self, row, missing: int) -> Dict[str, EventCoordinates]:
+        out = {}
+        for j, i in enumerate(() if row is None else row):
+            if i != missing:
+                pk = self._coord_keys[j]
+                out[pk] = EventCoordinates(
+                    self.store.participant_event(pk, int(i)), int(i))
+        return out
+
+    def _let_go(self, event: Event) -> None:
+        """The store no longer holds ``event``: neither does its chain."""
+        col = self._coord_col.get(event.creator())
+        if col is not None:
+            chain = self._chains[col]
+            if chain.get(event.index()) is event:
+                del chain[event.index()]
+
     def _init_event_coordinates(self, event: Event) -> None:
         """lastAncestors = element-wise max of parents' lastAncestors;
         firstDescendants/lastAncestors get the event itself for its creator
-        (reference: hashgraph.go:445-483)."""
-        event.last_ancestors = {}
-        event.first_descendants = {}
-
-        self_parent: Optional[Event] = None
-        other_parent: Optional[Event] = None
-        try:
-            self_parent = self.store.get_event(event.self_parent())
-        except StoreError:
-            pass
-        try:
-            other_parent = self.store.get_event(event.other_parent())
-        except StoreError:
-            pass
-
-        if self_parent is None and other_parent is not None:
-            event.last_ancestors = dict(other_parent.last_ancestors)
-        elif other_parent is None and self_parent is not None:
-            event.last_ancestors = dict(self_parent.last_ancestors)
-        elif self_parent is not None and other_parent is not None:
-            event.last_ancestors = dict(self_parent.last_ancestors)
-            for p, ola in other_parent.last_ancestors.items():
-                sla = event.last_ancestors.get(p)
-                if sla is None or sla.index < ola.index:
-                    event.last_ancestors[p] = EventCoordinates(ola.hash, ola.index)
-
-        me = EventCoordinates(event.hex(), event.index())
-        event.first_descendants[event.creator()] = me
-        event.last_ancestors[event.creator()] = me
+        (reference: hashgraph.go:445-483). A parent that is unknown, or has
+        no coordinates (reloaded from a persistent store's row), gives
+        nothing."""
+        col = self._column_of(event.creator())
+        width = self._coord_width
+        rows = []
+        for parent in event.body.parents:
+            if parent == "":
+                continue
+            try:
+                row = self.store.get_event(parent).last_ancestors
+            except StoreError:
+                continue
+            if row is not None:
+                rows.append(row if len(row) == width else _widened(row, width))
+        if len(rows) == 2:
+            la = np.maximum(rows[0], rows[1])
+        elif rows:
+            la = rows[0].copy()
+        else:
+            la = np.full(width, _LA_MISSING, dtype=np.int64)
+        index = event.index()
+        la[col] = index
+        fd = [_FD_MISSING] * width
+        fd[col] = index
+        event.last_ancestors = la
+        event.first_descendants = fd
 
     def _update_ancestor_first_descendant(self, event: Event) -> None:
         """Walk each last-ancestor's self-parent chain, recording this event
         as first descendant, stopping at witnesses or already-filled entries
-        (reference: hashgraph.go:486-519)."""
+        (reference: hashgraph.go:486-519). An ancestor is reached by
+        (creator column, index) in _chains, which the event joins first; a
+        step reads and writes one int of the ancestor's row. What else a
+        new first descendant changes hangs on witnesses alone — a cached
+        round matrix's entry, a resident window's witness row — so it is
+        seen to where the walk stops."""
         creator = event.creator()
-        coords = EventCoordinates(event.hex(), event.index())
-        for c in list(event.last_ancestors.values()):
-            ah = c.hash
+        col = self._coord_col[creator]
+        index = event.index()
+        chains = self._chains
+        chains[col][index] = event
+        steps = 0
+        for c, i in enumerate(event.last_ancestors.tolist()):
+            if i < 0 or c == col:
+                continue
+            chain = chains[c]
             while True:
+                a = chain.get(i)
+                if a is None:
+                    break  # the store let it go (or never had it)
+                fd = a.first_descendants
+                if col >= len(fd):
+                    fd.extend([_FD_MISSING] * (self._coord_width - len(fd)))
+                elif fd[col] != _FD_MISSING:
+                    break
+                fd[col] = index
+                steps += 1
+                # Stop at witnesses so the walk doesn't descend to the
+                # bottom of the graph (reference: hashgraph.go:503-512).
                 try:
-                    a = self.store.get_event(ah)
+                    stop = self.witness(a.hex())
                 except StoreError:
-                    break
-                if creator not in a.first_descendants:
-                    a.first_descendants[creator] = coords
-                    self.store.set_event(a)
-                    if self._accel_track_delta:
-                        self._accel_fd_dirty.add(ah)
-                    # A cached round-ctx matrix holds witness fds; this is
-                    # the one mutation its lookup-time checks cannot see.
-                    if a.round is not None:
-                        ctx = self._round_ctx.get(a.round)
-                        if ctx is not None and ctx.set_first_descendant(
-                            ah, creator, coords.index
-                        ):
-                            self.round_ctx_patches += 1
-                    # Stop at witnesses so the walk doesn't descend to the
-                    # bottom of the graph (reference: hashgraph.go:503-512).
-                    try:
-                        if self.witness(ah):
-                            break
-                    except StoreError:
-                        pass
-                    ah = a.self_parent()
-                else:
-                    break
+                    stop = None  # not known: no stop, but it may have a row
+                if stop is not False:
+                    self._witness_gained_descendant(a, creator, index)
+                    if stop:
+                        break
+                i -= 1
+        self.fd_walk_steps += steps
+
+    def _witness_gained_descendant(self, a: Event, creator: str,
+                                   index: int) -> None:
+        if self._accel_track_delta:
+            self._accel_fd_dirty.add(a.hex())
+        # A cached round-ctx matrix holds witness fds; this is the one
+        # mutation its lookup-time checks cannot see.
+        if a.round is not None:
+            ctx = self._round_ctx.get(a.round)
+            if ctx is not None and ctx.set_first_descendant(
+                a.hex(), creator, index
+            ):
+                self.round_ctx_patches += 1
 
     def set_wire_info(self, event: Event) -> None:
         """Fill the (creatorID, parent index) wire fields
@@ -1436,6 +1590,7 @@ class Hashgraph:
         # evicted) already — re-listing it only re-issues a no-op delete.
         dropped: set = set()
         drop_events: List[str] = []
+        let_go: List[Event] = []
         scan_base = self._prune_scan_base
         for r in range(scan_base, floor_round):
             try:
@@ -1456,6 +1611,7 @@ class Hashgraph:
                     continue
                 dropped.add(h)
                 drop_events.append(h)
+                let_go.append(ev)
 
         # A round goes only when ALL its created events are gone: an
         # event created below the floor but received above it (or still
@@ -1474,6 +1630,8 @@ class Hashgraph:
                 new_scan_base = r
 
         self.store.prune_below(floor_round, drop_events, drop_rounds, floors)
+        for ev in let_go:
+            self._let_go(ev)
 
         self._prune_scan_base = new_scan_base
         self.prune_floor = floor_round
@@ -1510,6 +1668,9 @@ class Hashgraph:
         self._membership_pending = []
         self._voted_topo = 0
         self._round_ctx = {}
+        # the store's repertoire starts again from the frame's peer-sets,
+        # and every event that comes back is given new rows
+        self._clear_coordinates()
         if self.accel is not None:
             # An in-flight sweep's snapshot no longer describes this store.
             self.accel.invalidate()

@@ -199,6 +199,9 @@ class PersistentStore:
                 self._unpersist_event(event)
             raise
 
+    def on_event_evicted(self, fn) -> None:
+        self._inmem.on_event_evicted(fn)
+
     def _persist_event(self, event: Event) -> bool:
         """Write through to the DB; returns True when the rows are new
         (vs. a re-set of an already-durable event)."""

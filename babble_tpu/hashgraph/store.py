@@ -33,6 +33,10 @@ class Store(Protocol):
     def repertoire_by_id(self) -> Dict[int, Peer]: ...
     def get_event(self, hash_: str) -> Event: ...
     def set_event(self, event: Event) -> None: ...
+    # ``fn(event)`` for every event the cache evicts from then on (one
+    # listener, the hashgraph on this store: what it holds by (creator,
+    # index) for the first-descendant walk follows the cache's window).
+    def on_event_evicted(self, fn) -> None: ...
     def participant_events(self, participant: str, skip: int) -> List[str]: ...
     def participant_event(self, participant: str, index: int) -> str: ...
     def last_event_from(self, participant: str) -> str: ...
@@ -78,7 +82,8 @@ class InmemStore:
 
     def __init__(self, cache_size: int = 10000):
         self._cache_size = cache_size
-        self._event_cache = LRU(cache_size)
+        self._event_evicted = None
+        self._event_cache = LRU(cache_size, self._evicted)
         self._round_cache = LRU(cache_size)
         self._block_cache = LRU(cache_size)
         self._frame_cache = LRU(cache_size)
@@ -143,6 +148,13 @@ class InmemStore:
         if key not in self._event_cache:
             self._participant_events_cache.set(event.creator(), key, event.index())
         self._event_cache.add(key, event)
+
+    def on_event_evicted(self, fn) -> None:
+        self._event_evicted = fn
+
+    def _evicted(self, _key: str, event: Event) -> None:
+        if self._event_evicted is not None:
+            self._event_evicted(event)
 
     def participant_events(self, participant: str, skip: int) -> List[str]:
         return self._participant_events_cache.get(participant, skip)
@@ -249,7 +261,7 @@ class InmemStore:
         (reference: inmem_store.go:286-311)."""
         cs = self._cache_size
         self._peer_set_cache = PeerSetCache()
-        self._event_cache = LRU(cs)
+        self._event_cache = LRU(cs, self._evicted)
         self._round_cache = LRU(cs)
         self._block_cache = LRU(cs)
         self._frame_cache = LRU(cs)

@@ -676,9 +676,13 @@ class Node(StateManager):
                 "sync_creator_stalls": self.core.sync_creator_stalls,
                 "peer_set_waits": self.core.hg.peer_set_waits,
                 # DivideRounds' per-round witness matrices: entries and
-                # rows written in place, and matrices built at lookup
+                # rows written in place, and matrices built at lookup;
+                # entries the first-descendant walk wrote, and how often
+                # the repertoire outgrew the coordinate rows' width
                 "round_ctx_patches": self.core.hg.round_ctx_patches,
                 "round_ctx_rebuilds": self.core.hg.round_ctx_rebuilds,
+                "fd_walk_steps": self.core.hg.fd_walk_steps,
+                "coord_row_regrows": self.core.hg.coord_row_regrows,
             }
         )
         # Mempool surface (docs/mempool.md): admission verdict counters,

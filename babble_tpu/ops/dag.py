@@ -9,7 +9,9 @@ masked comparisons, matmuls, and fixpoint sweeps:
 
 - events are rows; peers are columns (``PeerSet.peer_index`` fixes the
   coordinate of each peer).
-- ``last_ancestors``/``first_descendants`` become ``[E, P] int32`` tensors.
+- ``last_ancestors``/``first_descendants`` become ``[E, P] int32`` tensors:
+  each event's two integer rows (``Hashgraph.window_coordinates``), their
+  columns permuted from the hashgraph's column space to the peer columns.
 - ``stronglySee`` becomes a broadcast compare + super-majority reduction —
   an ``[E, E, P]`` masked tensor summed over P.
 - round assignment becomes a bounded fixpoint sweep (``lax.while_loop``):
@@ -45,7 +47,8 @@ class DagSnapshot:
     """Dense struct-of-arrays view of a DAG window.
 
     E = number of events (topological order), P = number of peers.
-    Missing coordinates: last_ancestors = -1, first_descendants = INT32_MAX.
+    Missing coordinates: last_ancestors = -1, first_descendants = INT32_MAX
+    (the host rows' ``_LA_MISSING`` / ``_FD_MISSING``, clamped to int32).
     """
 
     creator: np.ndarray  # [E] int32, peer index of each event's creator
@@ -106,18 +109,14 @@ def snapshot_from_hashgraph(h, event_hashes: Optional[List[str]] = None) -> DagS
     fd = np.full((E, n_peers), INT32_MAX, np.int32)
     mid = np.zeros(E, bool)
 
+    src = h.coord_columns(pub_keys)
     for i, eh in enumerate(event_hashes):
         ev = store.get_event(eh)
         creator[i] = peer_col[ev.creator()]
         index[i] = ev.index()
         self_parent[i] = row.get(ev.self_parent(), -1)
         other_parent[i] = row.get(ev.other_parent(), -1)
-        for pk, coords in ev.last_ancestors.items():
-            if pk in peer_col:
-                la[i, peer_col[pk]] = coords.index
-        for pk, coords in ev.first_descendants.items():
-            if pk in peer_col:
-                fd[i, peer_col[pk]] = coords.index
+        la[i], fd[i] = h.window_coordinates(ev, src)
         mid[i] = middle_bit(eh)
 
     return DagSnapshot(

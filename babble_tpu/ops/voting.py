@@ -428,6 +428,7 @@ def build_voting_window(hg) -> Optional[VotingWindow]:
 
     from babble_tpu.hashgraph.hashgraph import middle_bit
 
+    src = hg.coord_columns(pub_keys)
     for h, i in rows.items():
         ev = ev_cache.get(h)
         if ev is None:
@@ -447,14 +448,8 @@ def build_voting_window(hg) -> Optional[VotingWindow]:
             valid_w[w] = True
             fame0_w[w] = _fame_init(witness_info[h][1])
             mid_w[w] = middle_bit(h)
-            for pk, coords in ev.last_ancestors.items():
-                c = peer_col.get(pk)
-                if c is not None:
-                    la_w[w, c] = coords.index
-            for pk, coords in ev.first_descendants.items():
-                c = peer_col.get(pk)
-                if c is not None:
-                    fd_w[w, c] = coords.index
+            la_w[w, :n_peers], fd_w[w, :n_peers] = hg.window_coordinates(
+                ev, src)
 
     # Per-round peer-sets: one slot per distinct set effective in the window
     # (interval semantics of PeerSetCache.get, caches.go:169-193). Rounds

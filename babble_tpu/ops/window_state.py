@@ -248,6 +248,8 @@ class WindowState:
         self.key: Optional[tuple] = None  # (W, E, P, S, R)
         self.pub_keys: tuple = ()
         self.peer_col: Dict[str, int] = {}
+        # per peer column, the hashgraph's coordinate column of that peer
+        self.coord_src: Optional[np.ndarray] = None
         self.exists_prev: Optional[np.ndarray] = None
         # The ONLY live reference to the resident device buffers (donation
         # ownership rule: dispatch consumes and replaces it atomically).
@@ -388,6 +390,7 @@ class WindowState:
         rep = hg.store.repertoire_by_pub_key()
         self.pub_keys = tuple(sorted(rep.keys()))
         self.peer_col = {pk: i for i, pk in enumerate(self.pub_keys)}
+        self.coord_src = hg.coord_columns(self.pub_keys)
         self.exists_prev = np.asarray(win.exists_r)
         self.device = None  # reseeded by the next full dispatch
         self._mask_cache.clear()
@@ -502,7 +505,7 @@ class WindowState:
                 raise _Rebuild("witness-bucket-overflow")
             w = self.free_w.pop()
             self.wit_row[h] = w
-            w_upd[w] = self._pack_witness(ev, i, r - self.base, fame0=0)
+            w_upd[w] = self._pack_witness(hg, ev, i, r - self.base, fame0=0)
 
         # 4. witnesses whose first_descendants mutated since the last
         #    snapshot (the one post-insert per-row mutation) repack.
@@ -514,7 +517,7 @@ class WindowState:
             if ev is None:
                 ev = store.get_event(h)
             w_upd[w] = self._pack_witness(
-                ev, int(m["wit_idx"][w]), int(m["rounds_w"][w]),
+                hg, ev, int(m["wit_idx"][w]), int(m["rounds_w"][w]),
                 fame0=int(m["fame0_w"][w]),
             )
 
@@ -593,21 +596,15 @@ class WindowState:
             ),
         )
 
-    def _pack_witness(self, ev, e_row: int, round_rebased: int,
+    def _pack_witness(self, hg, ev, e_row: int, round_rebased: int,
                       fame0: int) -> dict:
         from babble_tpu.hashgraph.hashgraph import middle_bit
 
         P = self.key[2]
+        n = len(self.coord_src)
         la = np.full(P, -1, np.int32)
         fd = np.full(P, INT32_MAX, np.int32)
-        for pk, coords in ev.last_ancestors.items():
-            c = self.peer_col.get(pk)
-            if c is not None:
-                la[c] = coords.index
-        for pk, coords in ev.first_descendants.items():
-            c = self.peer_col.get(pk)
-            if c is not None:
-                fd[c] = coords.index
+        la[:n], fd[:n] = hg.window_coordinates(ev, self.coord_src)
         return {
             "wit_idx": e_row,
             "la_w": la,

@@ -1,0 +1,398 @@
+"""An event's coordinates are integer rows in the hashgraph's column space
+(``Event.last_ancestors`` an int64 row, ``first_descendants`` a list of
+ints, ``Hashgraph._chains`` the events by (creator column, index) for the
+walk). The plain reference is the code as it was before: dicts ``pub_key ->
+EventCoordinates(hash, index)``, merged entry by entry, walked by hash. It
+is kept HERE (``DictReference``), runs beside the hashgraph on the same
+inserts, and after EVERY insert the rows equal the dicts entry for entry,
+missing for missing: the new event, every event its walk visited, and every
+event the store holds each 64th insert and at the end. The benchmark cannot
+give this guard: its references run the same ``Hashgraph``.
+
+The reference also states round, witness flag and Lamport timestamp from
+its own dicts, one pair of events and one peer at a time, and the
+hashgraph's are held to them.
+
+The DAGs are ``tests/test_round_ctx.py``'s five (gossip at 16 creators, the
+laggard that mints witnesses into old rounds, joins and leaves at-round and
+eager, a hashgraph that prunes, ``reset`` + ``insert_frame_event``), a
+churn script that crosses a row's capacity, a bounded store, and a
+``PersistentStore`` reopened mid-stream.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+from babble_tpu.hashgraph import Hashgraph, InmemStore
+from babble_tpu.hashgraph.event import EventCoordinates, FrameEvent
+from babble_tpu.hashgraph.hashgraph import _FD_MISSING, _LA_MISSING
+from babble_tpu.hashgraph.persistent_store import PersistentStore
+from babble_tpu.peers.peer_set import PeerSet
+from benchmark.harness import churn, data
+from test_round_ctx import (  # noqa: F401  (gossip16 is a fixture)
+    DAG_SEED,
+    N,
+    SEED,
+    _churn_backlog,
+    _fresh,
+    _ring,
+    gossip16,
+    laggard_events,
+)
+
+
+# -- the plain reference: the code as it was before PR 32 ---------------------
+
+class DictReference:
+    """``_init_event_coordinates`` and ``_update_ancestor_first_descendant``
+    on dicts of ``EventCoordinates``, as ``hashgraph.py`` had them, beside
+    ``hg``. It reads the store's cache without refreshing it (the walk it
+    checks no longer does), and an event is *held* while that cache has the
+    object this reference watched being inserted: an evicted one ends its
+    chain's walk as ``StoreError`` did, and one a ``PersistentStore``
+    reloads has no coordinates."""
+
+    def __init__(self, hg: Hashgraph):
+        self.hg = hg
+        self.la, self.fd = {}, {}  # id(Event) -> {pub_key: EventCoordinates}
+        self.events = []  # keeps every id above its object's own
+        self.round, self.lamport, self.witness = {}, {}, {}  # by hash
+        self.wits = {}  # round -> [witness hashes]
+
+    def held(self, h: str):
+        store = self.hg.store
+        ev, _ok = getattr(store, "_inmem", store)._event_cache.peek(h)
+        return ev if id(ev) in self.fd else None
+
+    def inserted(self, event, frame=None) -> list:
+        """``event`` went into the hashgraph (and was divided, or came with
+        a frame's verdict). Returns the events whose dicts the walk read."""
+        sp, op = self.held(event.self_parent()), self.held(event.other_parent())
+        la = dict(self.la[id(sp)]) if sp else {}
+        if op:
+            for p, ola in self.la[id(op)].items():
+                sla = la.get(p)
+                if sla is None or sla.index < ola.index:
+                    la[p] = EventCoordinates(ola.hash, ola.index)
+        creator = event.creator()
+        me = EventCoordinates(event.hex(), event.index())
+        la[creator] = me
+        self.la[id(event)], self.fd[id(event)] = la, {creator: me}
+        self.events.append(event)
+        visited = []
+        for c in list(la.values()):
+            ah = c.hash
+            while True:
+                a = self.held(ah)
+                if a is None:
+                    break
+                visited.append(a)
+                if creator in self.fd[id(a)]:
+                    break
+                self.fd[id(a)][creator] = me
+                if self.witness[ah]:
+                    break  # the witness stop (hashgraph.go:503-512)
+                ah = a.self_parent()
+        self._divide(event, frame)
+        return visited
+
+    def _strongly_sees(self, x, w, peers) -> bool:
+        la, fd = self.la[id(x)], self.fd[id(self.held(w))]
+        return sum(
+            p in la and p in fd and la[p].index >= fd[p].index
+            for p in peers.pub_keys()) >= peers.super_majority()
+
+    def _divide(self, event, frame) -> None:
+        h = event.hex()
+        if frame is not None:
+            r, lt, flag = frame.round, frame.lamport_timestamp, frame.witness
+        else:
+            parents = [p for p in event.body.parents if p != ""]
+            parent_round = max((self.round[p] for p in parents), default=-1)
+            r = 0
+            if parent_round >= 0:
+                peers = self.hg.store.get_peer_set(parent_round)
+                seen = sum(self._strongly_sees(event, w, peers)
+                           for w in self.wits.get(parent_round, ()))
+                r = parent_round + (seen >= peers.super_majority())
+            member = event.creator() in self.hg.store.get_peer_set(r).by_pub_key
+            flag = member and r > self.round.get(event.self_parent(), -1)
+            lt = max((self.lamport.get(p, -1) for p in parents), default=-1) + 1
+        self.round[h], self.lamport[h], self.witness[h] = r, lt, flag
+        if flag:
+            self.wits.setdefault(r, []).append(h)
+
+
+# -- the comparison -----------------------------------------------------------
+
+def same_coordinates(hg: Hashgraph, ref: DictReference, ev) -> None:
+    """Entry for entry, missing for missing, hashes included."""
+    h = ev.hex()
+    assert hg.last_ancestors(h) == ref.la.get(id(ev), {}), f"la of {h}"
+    assert hg.first_descendants(h) == ref.fd.get(id(ev), {}), f"fd of {h}"
+    if id(ev) in ref.fd:
+        assert ev.last_ancestors.dtype == np.int64
+        assert type(ev.first_descendants) is list
+    else:
+        assert ev.last_ancestors is None and ev.first_descendants is None
+
+
+def held_events(hg: Hashgraph) -> list:
+    store = getattr(hg.store, "_inmem", hg.store)
+    return [store._event_cache.peek(h)[0] for h in store._event_cache.keys()]
+
+
+def whole_check(hg: Hashgraph, ref: DictReference) -> None:
+    held = held_events(hg)
+    for ev in held:
+        same_coordinates(hg, ref, ev)
+    # nothing is held by index that the store let go, and everything this
+    # hashgraph gave rows to and the store still has, is
+    by_index = {id(e) for chain in hg._chains for e in chain.values()}
+    assert by_index == {id(e) for e in held if id(e) in ref.fd}
+    for chain in hg._chains:
+        assert all(e.index() == i for i, e in chain.items())
+
+
+class Beside:
+    """Feeds the hashgraph and the reference the same inserts."""
+
+    def __init__(self, hg: Hashgraph):
+        self.hg, self.ref, self.n = hg, DictReference(hg), 0
+
+    def check(self, ev, frame=None) -> None:
+        visited = self.ref.inserted(ev, frame)
+        same_coordinates(self.hg, self.ref, ev)
+        for a in visited:
+            same_coordinates(self.hg, self.ref, a)
+        h = ev.hex()
+        flag = self.hg.store.get_round(ev.round).created_events[h].witness
+        assert (ev.round, ev.lamport_timestamp, flag) == (
+            self.ref.round[h], self.ref.lamport[h], self.ref.witness[h]), h
+        self.n += 1
+        if self.n % 64 == 0:
+            whole_check(self.hg, self.ref)
+
+    def insert(self, ev, consensus: bool = False) -> None:
+        if consensus:
+            self.hg.insert_event_and_run_consensus(ev, set_wire_info=True)
+        else:
+            self.hg.insert_event(ev, set_wire_info=True)
+            self.hg.divide_rounds()
+        self.check(ev)
+
+
+# -- the five DAGs of test_round_ctx ------------------------------------------
+
+def test_random_gossip_at_16_creators(gossip16):
+    _keys, peers, wires = gossip16
+    both = Beside(_fresh(peers))
+    for we in wires:
+        both.insert(both.hg.read_wire_info(we))
+    whole_check(both.hg, both.ref)
+    hg = both.hg
+    assert hg.coord_row_regrows == 0 and hg._coord_width == 16
+    # the walk's write count is the dict walk's
+    written = sum(len(fd) - 1 for fd in both.ref.fd.values())
+    assert hg.fd_walk_steps == written > 8 * len(wires)
+    # a witness below a later witness of its creator stays unfilled
+    assert any(_FD_MISSING in e.first_descendants[:N]
+               for e in held_events(hg)[:400])
+
+
+def test_a_silent_creator_that_returns_mints_witnesses_into_old_rounds():
+    keys, peers = _ring(seed=SEED + 2)
+    both = Beside(_fresh(peers))
+    for ev in laggard_events(keys, 1600, DAG_SEED + 1, 5, 150, 1100):
+        both.insert(ev)
+    whole_check(both.hg, both.ref)
+
+
+@pytest.mark.parametrize("eager", [False, True], ids=["at-round", "eager"])
+def test_joins_and_leaves(eager):
+    genesis, wires = _churn_backlog(eager)
+    hg, plus_six = churn.sequential_hashgraph(genesis, len(wires))
+    both = Beside(hg)
+    for we in wires:
+        ev = hg.read_wire_info(we)
+        hg.insert_event_and_run_consensus(ev, set_wire_info=False)
+        both.check(ev)
+    whole_check(hg, both.ref)
+    assert len(plus_six.changes) == 3
+    # 4 + 2 participants fit the first eight columns
+    assert hg.coord_row_regrows == 0 and len(hg._coord_keys) == 6
+
+
+def test_a_hashgraph_that_prunes(gossip16):
+    _keys, peers, wires = gossip16
+    both = Beside(_fresh(peers))
+    hg = both.hg
+    pruned = 0
+    for k, we in enumerate(wires[:1200]):
+        both.insert(hg.read_wire_info(we), consensus=True)
+        if k % 300 == 299 and hg.last_consensus_round is not None:
+            before = sum(len(c) for c in hg._chains)
+            gone = hg.prune_below(hg.last_consensus_round - 1)["events_pruned"]
+            # (a hash listed again by a later prune counts as pruned twice)
+            assert before - gone <= sum(len(c) for c in hg._chains) <= before
+            pruned += gone
+            whole_check(hg, both.ref)  # the chains lost what the store did
+    assert pruned > 100
+    whole_check(hg, both.ref)
+
+
+def test_a_hashgraph_that_lands_on_a_frame_and_goes_on(gossip16):
+    _keys, peers, wires = gossip16
+    h = _fresh(peers)
+    for we in wires[:900]:
+        h.insert_event_and_run_consensus(h.read_wire_info(we),
+                                         set_wire_info=True)
+    block = h.store.get_block(h.store.last_block_index() // 2)
+    frame = h.get_frame(block.round_received())
+    # the frame's events over the wire, as a joiner gets them: the Event
+    # objects of ``h`` keep the rows ``h`` gave them
+    frame = type(frame).from_dict(frame.to_dict())
+    h2 = Hashgraph(InmemStore(100000))
+    both = Beside(h2)
+    h2.reset(block, frame)
+    for fe in frame.sorted_frame_events():
+        both.ref.inserted(fe.core, frame=fe)
+    whole_check(h2, both.ref)
+    diff = []
+    for id_, ct in h2.store.known_events().items():
+        pk = peers.by_id[id_].pub_key_hex
+        diff += [h.store.get_event(x) for x in h.store.participant_events(pk, ct)]
+    diff.sort(key=lambda e: e.topological_index)
+    trusted = 0
+    for k, orig in enumerate(diff):
+        ev = h2.read_wire_info(orig.to_wire())
+        flag = h.store.get_round(orig.round).created_events[orig.hex()].witness
+        if k > 100 and flag and trusted < 6:
+            fe = FrameEvent(ev, orig.round, orig.lamport_timestamp, True)
+            h2.insert_frame_event(fe)
+            both.check(ev, frame=fe)
+            trusted += 1
+        else:
+            both.insert(ev)
+    assert trusted == 6 and len(diff) > 300
+    whole_check(h2, both.ref)
+    # a second landing starts the column space and the chains again
+    h2.reset(block, frame)
+    assert sum(len(c) for c in h2._chains) == len(frame.events) + sum(
+        len(r.events) for r in frame.roots.values())
+
+
+# -- a repertoire that outgrows the rows --------------------------------------
+
+def test_a_churn_script_that_crosses_a_rows_capacity():
+    n_genesis, n_joiners = 7, 2
+    keys = data.seeded_keys(n_genesis + n_joiners, SEED + 4)
+    peers = churn.all_peers(keys, n_genesis)
+    genesis = PeerSet(peers[:n_genesis])
+    requests = churn.parse_requests(["+x0", "+x1"], n_genesis)
+    _script, wires = churn.churn_script(
+        keys, peers, genesis, list(range(1, n_genesis)), requests, 700,
+        2147489957, 40, 150, 100)
+    hg, plus_six = churn.sequential_hashgraph(genesis, len(wires))
+    both = Beside(hg)
+    for we in wires:
+        ev = hg.read_wire_info(we)
+        hg.insert_event_and_run_consensus(ev, set_wire_info=False)
+        both.check(ev)
+    whole_check(hg, both.ref)
+    assert len(plus_six.changes) == 2
+    # 7 participants in rows of 8, the ninth widens them to 16, once
+    assert hg.coord_row_regrows == 1 and hg._coord_width == 16
+    widths = {len(e.last_ancestors) for e in held_events(hg)}
+    assert widths == {8, 16}
+    # the second joiner (column 8) has events, whose parents' rows are of
+    # the old width: np.maximum met rows of two widths, and its walk
+    # lengthened first-descendant rows made at 8
+    x1 = peers[n_genesis + 1].pub_key_hex
+    assert hg._coord_col[x1] == 8 and len(hg._chains[8]) > 10
+    assert any(len(e.last_ancestors) == 8 and len(e.first_descendants) == 16
+               for e in held_events(hg))
+    # matrices of both widths were compared with rows of both
+    assert {ctx.width for ctx in hg._round_ctx.values()} == {8, 16}
+
+
+# -- a store that forgets -----------------------------------------------------
+
+def test_a_bounded_store_holds_nothing_by_index_that_it_evicted():
+    keys, peers = _ring(seed=SEED + 6)
+    wires = data.backlog_wire_events(
+        keys, peers, list(range(N)), 2000, DAG_SEED + 3, 100)
+    hg = Hashgraph(InmemStore(500))
+    hg.init(peers)
+    both = Beside(hg)
+    for we in wires:
+        both.insert(hg.read_wire_info(we))
+        assert sum(len(c) for c in hg._chains) <= 500
+    whole_check(hg, both.ref)
+    assert len(held_events(hg)) == 500
+    assert sum(len(c) for c in hg._chains) == 500
+    assert hg.store.last_round() > 15
+
+
+def test_a_persistent_store_reopened_mid_stream(tmp_path, gossip16):
+    """A cache much smaller than the stream: what the store reloads from a
+    row has no coordinates and reads as missing everywhere (no ancestor, no
+    strongly-seeing, an all-missing matrix row), and the walk ends at what
+    the cache let go. Then the store is closed, reopened and bootstrapped,
+    and the stream goes on."""
+    _keys, peers, wires = gossip16
+    path = str(tmp_path / "babble.db")
+
+    def opened():
+        hg = Hashgraph(PersistentStore(300, path))
+        hg.init(peers)
+        return Beside(hg)
+
+    both = opened()
+    for we in wires[:700]:
+        both.insert(both.hg.read_wire_info(we), consensus=True)
+    whole_check(both.hg, both.ref)
+    hg = both.hg
+    old = hg.store.participant_event(peers.peers[3].pub_key_hex, 2)
+    reloaded = hg.store.get_event(old)  # long evicted: made from its row
+    assert reloaded.last_ancestors is None
+    assert reloaded.first_descendants is None
+    assert hg.last_ancestors(old) == {} == hg.first_descendants(old)
+    head = hg.store.last_event_from(peers.peers[3].pub_key_hex)
+    assert not hg._ancestor(old, head) and not hg._strongly_see(old, head, peers)
+    assert hg._ancestor(head, head)
+    ctx = hg._build_round_ctx(peers, [old], 0)
+    assert (ctx.fd == _FD_MISSING).all()
+    assert not hg._strongly_seen_mask(old, ctx).any()
+    assignments = {
+        h: (hg.store.get_event(h).round, hg.store.get_event(h).lamport_timestamp)
+        for h in both.ref.round}
+    hg.store.close()
+
+    both = opened()
+    hg = both.hg
+    replay = hg.insert_event_and_run_consensus
+
+    def watched(ev, set_wire_info=False):
+        replay(ev, set_wire_info)
+        both.check(ev)
+
+    hg.insert_event_and_run_consensus = watched
+    hg.bootstrap()
+    hg.insert_event_and_run_consensus = replay
+    assert both.n == 700
+    assert {h: (both.ref.round[h], both.ref.lamport[h])
+            for h in both.ref.round} == assignments
+    for we in wires[700:1100]:
+        both.insert(hg.read_wire_info(we), consensus=True)
+    whole_check(hg, both.ref)
+    assert sum(len(c) for c in hg._chains) <= 300
+    hg.store.close()
+
+
+def test_the_sentinels_never_compare_as_seen():
+    assert _LA_MISSING < 0 < _FD_MISSING
+    assert not (np.int64(_LA_MISSING) >= np.int64(0))
+    assert not (np.int64(2**40) >= np.int64(_FD_MISSING))

@@ -299,24 +299,24 @@ def test_insert_event_coordinates(round_graph):
     assert e0.body.other_parent_creator_id == 0
     assert e0.body.other_parent_index == -1
     assert e0.body.creator_id == nodes[0].pub_id
-    assert e0.first_descendants == {
+    assert h.first_descendants(index["e0"]) == {
         p0: EventCoordinates(index["e0"], 0),
         p1: EventCoordinates(index["e10"], 1),
         p2: EventCoordinates(index["e21"], 2),
     }
-    assert e0.last_ancestors == {p0: EventCoordinates(index["e0"], 0)}
+    assert h.last_ancestors(index["e0"]) == {p0: EventCoordinates(index["e0"], 0)}
 
     e21 = h.store.get_event(index["e21"])
     assert e21.body.self_parent_index == 1
     assert e21.body.other_parent_creator_id == nodes[1].pub_id
     assert e21.body.other_parent_index == 1
     assert e21.body.creator_id == nodes[2].pub_id
-    assert e21.first_descendants == {
+    assert h.first_descendants(index["e21"]) == {
         p0: EventCoordinates(index["e02"], 2),
         p1: EventCoordinates(index["f1"], 3),
         p2: EventCoordinates(index["e21"], 2),
     }
-    assert e21.last_ancestors == {
+    assert h.last_ancestors(index["e21"]) == {
         p0: EventCoordinates(index["e0"], 0),
         p1: EventCoordinates(index["e10"], 1),
         p2: EventCoordinates(index["e21"], 2),
@@ -327,8 +327,8 @@ def test_insert_event_coordinates(round_graph):
     assert f1.body.other_parent_creator_id == nodes[0].pub_id
     assert f1.body.other_parent_index == 2
     assert f1.body.creator_id == nodes[1].pub_id
-    assert f1.first_descendants == {p1: EventCoordinates(index["f1"], 3)}
-    assert f1.last_ancestors == {
+    assert h.first_descendants(index["f1"]) == {p1: EventCoordinates(index["f1"], 3)}
+    assert h.last_ancestors(index["f1"]) == {
         p0: EventCoordinates(index["e02"], 2),
         p1: EventCoordinates(index["f1"], 3),
         p2: EventCoordinates(index["e21"], 2),

@@ -4,7 +4,7 @@ witness's new first descendant changes, a new witness gets its row. Two
 checks after EVERY insert of five signed DAGs:
 
 - the plain definition: the round and witness flag the hashgraph gave the
-  event equal those of the per-pair dict walk ``Hashgraph._strongly_see``
+  event equal those of the per-pair, per-peer ``Hashgraph._strongly_see``
   (parent round, + 1 on a super-majority of that round's witnesses under
   that round's peer-set). The benchmark cannot give this guard: its
   references run the same ``Hashgraph._round``.
@@ -333,6 +333,10 @@ def test_the_counters_are_in_the_nodes_snapshot(gossip16):
         assert snap["round_ctx_patches"] == hg.round_ctx_patches > 0
         assert snap["round_ctx_rebuilds"] == hg.round_ctx_rebuilds > 0
         assert snap["round_ctx_rebuilds"] < 0.1 * hg.topological_index
+        # the coordinate rows' counters ride beside them: the entries the
+        # first-descendant walk wrote, and a ring of 16 fills rows of 16
+        assert snap["fd_walk_steps"] == hg.fd_walk_steps > 5 * len(wires)
+        assert snap["coord_row_regrows"] == hg.coord_row_regrows == 0
         assert "peer_set_waits" in snap
         shadow_check(hg)
     finally:
