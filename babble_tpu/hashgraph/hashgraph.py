@@ -304,6 +304,8 @@ class Hashgraph:
         # matrix entries written + witness rows appended in place, and
         # matrices built at lookup (first use of a round, or a dropped entry)
         self.round_ctx_patches = 0
+        # events `bootstrap` has replayed from a persistent store
+        self.bootstrap_events_replayed = 0
         self.round_ctx_rebuilds = 0
         # The column space of event coordinates: one column per
         # participant of the store's repertoire, in order of first
@@ -1692,6 +1694,7 @@ class Hashgraph:
         self._set_last_consensus_round(block.round_received())
         self.round_lower_bound = block.round_received()
 
+    @staged("bootstrap")
     def bootstrap(self) -> None:
         """Replay a persistent store's events through consensus in
         topological order — only from index 0 (reference: hashgraph.go:1481-1536).
@@ -1700,6 +1703,7 @@ class Hashgraph:
         topo = getattr(self.store, "topological_events", None)
         if topo is None:
             return
+        obs = self.stage_observer
         maintenance = getattr(self.store, "set_maintenance_mode", None)
         if maintenance is not None:
             maintenance(True)
@@ -1707,14 +1711,22 @@ class Hashgraph:
             batch_size = 100
             index = 0
             while True:
-                events = topo(index * batch_size, batch_size)
+                with NULL_STAGE if obs is None else obs.span("bootstrap_load"):
+                    events = topo(index * batch_size, batch_size)
                 for e in events:
                     self.insert_event_and_run_consensus(e, set_wire_info=True)
+                self.bootstrap_events_replayed += len(events)
                 self.flush_consensus()
                 self.process_sig_pool()
                 if len(events) < batch_size:
                     break
                 index += 1
+            # Deferred voting trails the inserts by a sweep in flight: what
+            # it still decides is part of the replay, and is applied before
+            # the write gate reopens — or the tail of the recomputation
+            # would be written over the previous incarnation's rows.
+            self.drain_consensus()
+            self.process_sig_pool()
         finally:
             if maintenance is not None:
                 maintenance(False)

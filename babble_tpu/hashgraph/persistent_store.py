@@ -11,7 +11,9 @@ Bootstrap (`--bootstrap`) replays the whole DB topologically through
 consensus to rebuild in-memory state — "WE CAN ONLY BOOTSTRAP FROM 0"
 (reference: hashgraph.go:1481-1536); Hashgraph.bootstrap drives it via
 ``topological_events`` and flips ``set_maintenance_mode`` so the replay
-doesn't rewrite the DB.
+doesn't rewrite the DB — nor read it as its own: while the mode is on, a
+round, frame or block comes back from the DB only once the replay has set
+that key itself (``_fetch_derived``).
 """
 
 from __future__ import annotations
@@ -29,6 +31,7 @@ from babble_tpu.hashgraph.event import Event, EventBody
 from babble_tpu.hashgraph.frame import Frame, Root
 from babble_tpu.hashgraph.round_info import RoundInfo
 from babble_tpu.hashgraph.store import InmemStore
+from babble_tpu.obs.trace import NULL_STAGE
 from babble_tpu.peers.peer import Peer
 from babble_tpu.peers.peer_set import PeerSet
 
@@ -74,6 +77,20 @@ class PersistentStore:
         # maintenanceMode disables DB writes during bootstrap replay
         # (reference: badger_store.go:848-855)
         self._maintenance = False
+        # Derived rows (table -> keys) the replay has itself recomputed.
+        # While the write gate is shut the rounds / frames / blocks tables
+        # still hold the PREVIOUS incarnation's decisions: a cache miss may
+        # fall back to such a row only once this incarnation has set the
+        # same key (it then lost it to the LRU, long decided) — never
+        # before, or the replay would take a round's witnesses and their
+        # fame from a future it has not inserted yet.
+        self._replayed: Dict[str, set] = {}
+        # A node's span tracer (obs/trace.py; Core hands it over), or None:
+        # `store_write` spans around every write-through. The tallies are
+        # plain ints read by Node.get_stats_snapshot().
+        self.stage_observer = None
+        self.commits = 0  # SQLite transactions committed by a write
+        self.db_reads = 0  # reads that fell through the cache to the DB
         # NOTE: persisted peer-sets are deliberately NOT preloaded into the
         # interval cache. The reference's design comment
         # (badger_store.go:109-118) applies verbatim: membership state must
@@ -86,6 +103,22 @@ class PersistentStore:
 
     def set_maintenance_mode(self, on: bool) -> None:
         self._maintenance = on
+        self._replayed = {}
+
+    def _fetch_derived(self, table: str, sql: str, key: int) -> Optional[tuple]:
+        """DB fallback for a rounds / frames / blocks row; during a
+        bootstrap replay only for a key the replay has already set."""
+        if self._maintenance and key not in self._replayed.get(table, ()):
+            return None
+        return self._fetch(sql, (key,))
+
+    def _write_derived(self, table: str, sql: str, key: int, data: dict) -> None:
+        """Write a rounds / frames / blocks row through. Gated off during a
+        bootstrap replay, which only notes the key as recomputed."""
+        if self._maintenance:
+            self._replayed.setdefault(table, set()).add(key)
+            return
+        self._write(sql, (key, canonical_dumps(data).decode()))
 
     # -- passthroughs to the cache -----------------------------------------
 
@@ -225,7 +258,7 @@ class PersistentStore:
             d["Lamport"] = event.lamport_timestamp
         if event.round_received is not None:
             d["RoundReceived"] = event.round_received
-        with self._db_lock:
+        with self._write_span(), self._db_lock:
             if self._db is None:
                 raise StoreError(
                     "PersistentStore", StoreErrorKind.CLOSED, key
@@ -245,6 +278,7 @@ class PersistentStore:
                 (key, topo, canonical_dumps(d).decode()),
             )
             self._db.commit()
+            self.commits += 1
             return row is None
 
     def _unpersist_event(self, event: Event) -> None:
@@ -266,6 +300,7 @@ class PersistentStore:
         except StoreError as err:
             if err.kind != StoreErrorKind.TOO_LATE:
                 raise
+            self.db_reads += 1
             with self._db_lock:
                 if self._db is None:
                     raise err  # shutdown race: surface the original miss
@@ -296,8 +331,8 @@ class PersistentStore:
         try:
             return self._inmem.get_round(round_index)
         except StoreError:
-            row = self._fetch(
-                "SELECT data FROM rounds WHERE idx = ?", (round_index,)
+            row = self._fetch_derived(
+                "rounds", "SELECT data FROM rounds WHERE idx = ?", round_index
             )
             if row is None:
                 raise
@@ -305,9 +340,9 @@ class PersistentStore:
 
     def set_round(self, round_index: int, round_info: RoundInfo) -> None:
         self._inmem.set_round(round_index, round_info)
-        self._write(
-            "INSERT OR REPLACE INTO rounds (idx, data) VALUES (?, ?)",
-            (round_index, canonical_dumps(round_info.to_dict()).decode()),
+        self._write_derived(
+            "rounds", "INSERT OR REPLACE INTO rounds (idx, data) VALUES (?, ?)",
+            round_index, round_info.to_dict(),
         )
 
     # -- blocks -------------------------------------------------------------
@@ -316,16 +351,18 @@ class PersistentStore:
         try:
             return self._inmem.get_block(index)
         except StoreError:
-            row = self._fetch("SELECT data FROM blocks WHERE idx = ?", (index,))
+            row = self._fetch_derived(
+                "blocks", "SELECT data FROM blocks WHERE idx = ?", index
+            )
             if row is None:
                 raise
             return Block.from_dict(json.loads(row[0]))
 
     def set_block(self, block: Block) -> None:
         self._inmem.set_block(block)
-        self._write(
-            "INSERT OR REPLACE INTO blocks (idx, data) VALUES (?, ?)",
-            (block.index(), canonical_dumps(block.to_dict()).decode()),
+        self._write_derived(
+            "blocks", "INSERT OR REPLACE INTO blocks (idx, data) VALUES (?, ?)",
+            block.index(), block.to_dict(),
         )
 
     # -- frames -------------------------------------------------------------
@@ -334,8 +371,9 @@ class PersistentStore:
         try:
             return self._inmem.get_frame(round_received)
         except StoreError:
-            row = self._fetch(
-                "SELECT data FROM frames WHERE round = ?", (round_received,)
+            row = self._fetch_derived(
+                "frames", "SELECT data FROM frames WHERE round = ?",
+                round_received,
             )
             if row is None:
                 raise
@@ -343,9 +381,9 @@ class PersistentStore:
 
     def set_frame(self, frame: Frame) -> None:
         self._inmem.set_frame(frame)
-        self._write(
-            "INSERT OR REPLACE INTO frames (round, data) VALUES (?, ?)",
-            (frame.round, canonical_dumps(frame.to_dict()).decode()),
+        self._write_derived(
+            "frames", "INSERT OR REPLACE INTO frames (round, data) VALUES (?, ?)",
+            frame.round, frame.to_dict(),
         )
 
     # -- bootstrap support ---------------------------------------------------
@@ -511,7 +549,12 @@ class PersistentStore:
 
     # -- helpers -------------------------------------------------------------
 
+    def _write_span(self):
+        obs = self.stage_observer
+        return NULL_STAGE if obs is None else obs.span("store_write")
+
     def _fetch(self, sql: str, args: tuple) -> Optional[tuple]:
+        self.db_reads += 1
         with self._db_lock:
             if self._db is None:
                 # a gossip thread outliving shutdown's bounded wait must
@@ -524,7 +567,7 @@ class PersistentStore:
     def _write(self, sql: str, args: tuple) -> None:
         if self._maintenance:
             return
-        with self._db_lock:
+        with self._write_span(), self._db_lock:
             if self._db is None:
                 # Same fail-closed policy as events: a silently dropped
                 # write leaves the durable history behind what this
@@ -537,6 +580,7 @@ class PersistentStore:
                 )
             self._db.execute(sql, args)
             self._db.commit()
+            self.commits += 1
 
 
 def _event_from_json(data: str, annotated: bool = True) -> Event:

@@ -201,6 +201,9 @@ class Core:
         # BABBLE_OBS=0: no span opens and no clock is read.
         self.stage_observer = self.obs.stage_observer
         self.hg.stage_observer = self.stage_observer
+        if hasattr(self.hg.store, "stage_observer"):
+            # a PersistentStore's write-throughs are `store_write` spans
+            self.hg.store.stage_observer = self.stage_observer
 
     def _span(self, stage: str):
         """A stage that is part of a method, as a span to enter with

@@ -637,6 +637,7 @@ class Node(StateManager):
         from ..crypto.canonical import NORM_CACHE
         from ..hashgraph.event import WIRE_CACHE
 
+        store = self.core.hg.store
         stats.update(
             {
                 "ingest_syncs": self.core.ingest_syncs,
@@ -683,6 +684,14 @@ class Node(StateManager):
                 "round_ctx_rebuilds": self.core.hg.round_ctx_rebuilds,
                 "fd_walk_steps": self.core.hg.fd_walk_steps,
                 "coord_row_regrows": self.core.hg.coord_row_regrows,
+                # the durable store (0 with an InmemStore): SQLite
+                # transactions its writes committed, reads that fell
+                # through its cache to the database, and the events a
+                # --bootstrap replayed from it
+                "store_commits": getattr(store, "commits", 0),
+                "store_db_reads": getattr(store, "db_reads", 0),
+                "bootstrap_events_replayed":
+                    self.core.hg.bootstrap_events_replayed,
             }
         )
         # Mempool surface (docs/mempool.md): admission verdict counters,

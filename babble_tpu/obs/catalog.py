@@ -45,7 +45,8 @@ CATALOG: Tuple[Instrument, ...] = (
         "decide_fame, round_received, commit, proxy_deliver, "
         "process_sig_pool, diff, eager_sync, mempool_drain, self_event, "
         "sync, prepare_sync, flush, record_heads, membership, "
-        "creator_stall, peer_set_wait. Inclusive: a span's whole "
+        "creator_stall, peer_set_wait, store_write, bootstrap, "
+        "bootstrap_load. Inclusive: a span's whole "
         "duration, its children's included.",
     ),
     Instrument(
@@ -59,7 +60,8 @@ CATALOG: Tuple[Instrument, ...] = (
         "sync_stage_cpu_seconds", _H, ("stage",), "node",
         "Thread CPU time (time.thread_time) inside the COARSE spans "
         "only: sync, prepare_sync, decode, batch_verify, flush, commit, "
-        "self_event, creator_stall, peer_set_wait and the accel spans "
+        "self_event, creator_stall, peer_set_wait, bootstrap, "
+        "bootstrap_load and the accel spans "
         "build, snapshot (delta_scan + "
         "pack), dispatch, readback, apply. Wall minus CPU is time the "
         "thread did not run: GIL, sleep, device wait. Empty on a "
@@ -597,6 +599,7 @@ SYNC_STAGES = (
     "process_sig_pool", "diff", "eager_sync", "mempool_drain",
     "self_event", "sync", "prepare_sync", "flush", "record_heads",
     "membership", "creator_stall", "peer_set_wait",
+    "store_write", "bootstrap", "bootstrap_load",
 )
 # COARSE spans open at most a few times per sync: obs/trace.py also
 # reads the thread CPU clock and writes a profiler annotation for them.
@@ -607,6 +610,7 @@ SYNC_STAGES = (
 COARSE_STAGES = (
     "sync", "prepare_sync", "decode", "batch_verify", "flush", "commit",
     "self_event", "creator_stall", "peer_set_wait",
+    "bootstrap", "bootstrap_load",
     "build", "snapshot", "dispatch", "readback", "apply",
 )
 TX_STAGES = ("mempool_wait", "consensus")

@@ -1,0 +1,298 @@
+"""A ``--store`` validator catches up onto disk, is stopped, and recovers by
+``--bootstrap`` (deployment ``durable16``) at a small size on the CPU: 4
+validators, 600 events, the flush gate at 16 events.
+
+- the program — ``Core.prepare_sync`` / ``Core.sync`` / ``process_sig_pool``
+  on a ``PersistentStore`` — against the benchmark's plain reference
+  (``benchmark/harness/durable.py``: the database FILE read with ``sqlite3``
+  and ``json`` alone, its events through a sequential host hashgraph), before
+  the stop and after the replay, on the host path and on the lane a chip
+  resolves (pipelined sweeps through the batcher);
+- what the deployment forced in the program: a replay reads no derived row of
+  the previous incarnation back (``PersistentStore._fetch_derived``), applies
+  the last deferred sweep before the write gate reopens
+  (``Hashgraph.bootstrap`` drains), and so leaves the file as it found it;
+- the spans and counters the durable store brought, which a validator with
+  an ``InmemStore`` never opens.
+"""
+
+from __future__ import annotations
+
+import os
+
+import pytest
+
+from babble_tpu.common.errors import StoreError
+from babble_tpu.hashgraph import InmemStore
+from babble_tpu.hashgraph.persistent_store import PersistentStore
+from babble_tpu.hashgraph.round_info import RoundInfo
+from babble_tpu.node.core import Core
+from babble_tpu.node.validator import Validator
+from babble_tpu.proxy.proxy import dummy_commit_response
+from benchmark.harness import data, durable
+from benchmark.harness.counters import node_snapshot
+
+N, ME, EVENTS, SYNC = 4, 0, 600, 300
+SEED, DAG_SEED = 3000000019, 2147487920
+
+
+def _backlog():
+    keys = data.seeded_keys(N, SEED)
+    peers = data.peer_set(keys, [f"inmem://v{i}" for i in range(N)])
+    creators = [i for i in range(N) if i != ME]
+    wires = data.backlog_wire_events(keys, peers, creators, EVENTS, DAG_SEED,
+                                     100)
+    from_id = peers.by_pub_key[keys[creators[0]].public_key.hex()].id
+    return keys, peers, wires, from_id
+
+
+def _core(keys, peers, store, mode):
+    """v0's core as ``Node`` builds it, with ``--accelerator`` in mode
+    ``chip-lane``: the flush gate scaled to a 4-validator window, compiles
+    inline, pipelined sweeps through the batcher."""
+    core = Core(Validator(keys[ME], "v0"), peers, peers, store,
+                dummy_commit_response, accelerated_verify=mode != "host")
+    tc = core.hg.accel
+    if tc is not None:
+        tc.min_window, tc.async_compile = 16, False
+        tc.pipeline, tc.batcher = True, True
+    return core
+
+
+def _sampled(hg, every=100):
+    """The undetermined window after every ``every``-th insert."""
+    samples, inserts, insert = [], [0], hg.insert_event_and_run_consensus
+
+    def counted(event, set_wire_info=False):
+        insert(event, set_wire_info)
+        inserts[0] += 1
+        if inserts[0] % every == 0:
+            samples.append(len(hg.undetermined_events))
+
+    hg.insert_event_and_run_consensus = counted
+    return samples
+
+
+def _ingest(core, wires, from_id):
+    for chunk in data.chunks(wires, SYNC):
+        prepared = core.prepare_sync(chunk)
+        core.sync(from_id, chunk, prepared)
+        core.process_sig_pool()
+    core.hg.drain_consensus()
+
+
+class _Run:
+    """One validator's life: ingest, the file read from outside, a clean
+    stop, the replay — everything the tests below look at."""
+
+    def __init__(self, path, mode):
+        keys, self.peers, wires, from_id = _backlog()
+        self.own = keys[ME].public_key.hex()
+        store = PersistentStore(10000, path)
+        core = _core(keys, self.peers, store, mode)
+        self.ingest_window = _sampled(core.hg)
+        _ingest(core, wires, from_id)
+        self.before = durable.read(path)  # a second connection, no close yet
+        self.ingested = durable.state_of(core.hg)
+        self.ingest_commits = store.commits
+        self.ingest_reads = store.db_reads
+        self.ingest_sweeps = (core.hg.accel.stats()["accel_sweeps"]
+                              if core.hg.accel is not None else 0)
+        store.close()
+
+        store = PersistentStore(10000, path)
+        core = _core(keys, self.peers, store, mode)
+        self.replay_window = _sampled(core.hg)
+        # Hashgraph.init has written the genesis peer-set row again, as it
+        # was: one commit before the replay, none inside it
+        commits, reads = store.commits, store.db_reads
+        core.bootstrap()
+        core.set_head_and_seq()
+        self.replay_commits = store.commits - commits
+        self.replay_reads = store.db_reads - reads
+        self.replayed = durable.state_of(core.hg)
+        self.events_replayed = core.hg.bootstrap_events_replayed
+        self.head_seq = (core.head, core.seq)
+        self.after = durable.read(path)
+        store.close()
+        self.want = durable.replay(self.before, self.peers)
+
+
+@pytest.fixture(scope="module", params=["host", "chip-lane"])
+def run(request, tmp_path_factory):
+    path = tmp_path_factory.mktemp("durable") / "babble.db"
+    return _Run(str(path), request.param), request.param
+
+
+def test_acknowledged_means_durable(run):
+    r, _mode = run
+    assert EVENTS - r.before.events_from_others(r.own) == 0
+    counts = r.before.row_counts()
+    assert counts["events"] == counts["participant_events"] > EVENTS
+    assert r.before.max_topo == counts["events"] - 1
+    assert counts["blocks"] == len(r.ingested.blocks) > 50
+    assert durable.blocks_on_disk_differing(r.before, r.want.blocks) == 0
+
+
+def test_the_validator_equals_the_reference_before_the_stop(run):
+    r, mode = run
+    assert r.ingested == r.want
+    assert r.want.ordered > EVENTS - 50 and len(r.want.undetermined) > 10
+    if mode != "host":
+        assert r.ingest_sweeps > 0
+
+
+def test_bootstrap_recomputes_what_a_sequential_validator_reaches(run):
+    r, _mode = run
+    assert r.events_replayed == len(r.before.event_rows)
+    assert durable.blocks_differing(r.replayed.blocks, r.want.blocks) == 0
+    assert r.replayed.ordered == r.want.ordered
+    assert r.replayed.last_consensus_round == r.want.last_consensus_round
+    assert r.replayed.undetermined == r.want.undetermined  # the SET
+    assert r.replayed.pending_rounds == r.want.pending_rounds
+    # the validator's own events came back too: it resumes after them
+    assert r.head_seq[1] == r.before.row_counts()["events"] - EVENTS - 1
+
+
+def test_the_replay_leaves_the_database_as_it_found_it(run):
+    r, _mode = run
+    assert durable.rows_changed(r.before, r.after) == 0
+    assert r.replay_commits == 0
+    # and reads nothing of the previous incarnation's back
+    assert r.replay_reads == 0
+
+
+def test_the_replay_window_stays_as_bounded_as_the_ingest_window(run):
+    """With the previous incarnation's rounds read back, the host path's
+    window read 301, 801, 1,502 after 300, 800, 1,500 replayed events."""
+    r, _mode = run
+    assert len(r.replay_window) == len(r.ingest_window) == 6
+    assert max(r.replay_window) <= max(r.ingest_window) + 100
+
+
+def test_ingest_commits_and_reads(run):
+    r, _mode = run
+    n = r.before.row_counts()["events"]
+    # an event is written at insert and again as its round, Lamport time
+    # and round received are assigned; rounds, blocks and frames besides
+    assert 3 * n < r.ingest_commits < 8 * n
+    # a parent that came in the same sync is asked of the store first
+    # (by index), and a new round's and frame's first lookups: no more
+    assert EVENTS < r.ingest_reads < 3 * n
+
+
+def test_a_store_that_drops_every_other_event_write_reads_not_on_disk(
+        tmp_path):
+    class Dropping(PersistentStore):
+        """Every other event it is given is acknowledged and never
+        persisted, at insert or when its annotations are set."""
+
+        def __init__(self, *args):
+            super().__init__(*args)
+            self.seen = {}
+
+        def _persist_event(self, event):
+            drop = self.seen.setdefault(event.hex(), len(self.seen) % 2 == 1)
+            return False if drop else super()._persist_event(event)
+
+    keys, peers, wires, from_id = _backlog()
+    path = str(tmp_path / "babble.db")
+    store = Dropping(10000, path)
+    _ingest(_core(keys, peers, store, "host"), wires, from_id)
+    db = durable.read(path)
+    store.close()
+    own = keys[ME].public_key.hex()
+    assert EVENTS - db.events_from_others(own) > EVENTS // 3
+
+
+def test_a_replay_reads_a_derived_row_back_only_once_it_has_set_it(tmp_path):
+    path = str(tmp_path / "babble.db")
+    store = PersistentStore(2, path)
+    for r in range(3):
+        store.set_round(r, RoundInfo())
+    store.close()
+
+    store = PersistentStore(2, path)  # the next incarnation: a cold cache
+    assert store.db_reads == 0
+    store.set_maintenance_mode(True)
+    with pytest.raises(StoreError):
+        store.get_round(0)  # the previous incarnation's: not this one's
+    assert store.db_reads == 0
+    for r in range(3):
+        store.set_round(r, RoundInfo())  # recomputed; round 0 leaves the LRU
+    assert isinstance(store.get_round(0), RoundInfo)
+    assert store.db_reads == 1 and store.commits == 0
+    with pytest.raises(StoreError):
+        store.get_block(0)
+    store.set_maintenance_mode(False)
+    assert isinstance(store.get_round(1), RoundInfo)
+    store.close()
+
+
+def _node(store, bootstrap=False):
+    """A ``Node`` as ``engine.py`` builds it around ``store``, host path,
+    through ``Node.init()`` (which replays when ``bootstrap``), not started."""
+    from babble_tpu.config.config import Config
+    from babble_tpu.dummy.state import State as DummyState
+    from babble_tpu.net.inmem import InmemNetwork
+    from babble_tpu.node.node import Node
+    from babble_tpu.proxy.proxy import InmemProxy
+
+    keys, peers, wires, from_id = _backlog()
+    durable_store = isinstance(store, PersistentStore)
+    conf = Config(
+        bind_addr="inmem://v0", moniker="v0", log_level="error",
+        no_service=True, store=durable_store, bootstrap=bootstrap,
+        database_dir=(os.path.dirname(store.store_path())
+                      if durable_store else ""))
+    node = Node(conf, Validator(keys[ME], "v0"), peers, peers, store,
+                InmemNetwork().new_transport("inmem://v0"),
+                InmemProxy(DummyState()))
+    node.init()
+    return node, wires, from_id
+
+
+def test_the_store_spans_and_counters_reach_a_node_snapshot(tmp_path):
+    path = str(tmp_path / "babble.db")
+    node, wires, from_id = _node(PersistentStore(10000, path))
+    _ingest(node.core, wires, from_id)
+    snap = node_snapshot(node)
+    store = node.core.hg.store
+    assert snap["store_commits"] == store.commits > 3 * EVENTS
+    assert snap["store_db_reads"] == store.db_reads > EVENTS
+    # every commit is inside a `store_write` span, a child of what wrote,
+    # but the genesis peer-set's, written before the core has a tracer
+    assert snap["sync_stage_seconds.store_write.count"] == store.commits - 1
+    assert 0 < snap["sync_stage_seconds.store_write.sum"] < (
+        snap["sync_stage_seconds.sync.sum"])
+    assert snap["bootstrap_events_replayed"] == 0
+    assert "sync_stage_seconds.bootstrap.count" not in snap
+    node.shutdown()
+
+    # the restart, as engine.py does it: bootstrap=True on the same file
+    again, _wires, _from_id = _node(PersistentStore(10000, path),
+                                    bootstrap=True)  # replays inside init
+    snap = node_snapshot(again)
+    n = snap["bootstrap_events_replayed"]
+    assert n == snap["sync_stage_seconds.insert.count"] > EVENTS
+    assert snap["sync_stage_seconds.bootstrap.count"] == 1
+    assert snap["sync_stage_seconds.bootstrap_load.count"] == n // 100 + 1
+    assert snap["sync_stage_seconds.bootstrap_load.sum"] < (
+        snap["sync_stage_seconds.bootstrap.sum"])
+    # the genesis peer-set row, written again as it was before the replay
+    assert snap["store_commits"] == 1 and snap["store_db_reads"] == 0
+    assert "sync_stage_seconds.store_write.count" not in snap
+    assert again.core.seq == n - EVENTS - 1
+    again.shutdown()
+
+
+def test_a_validator_with_an_inmem_store_opens_none_of_it():
+    node, wires, from_id = _node(InmemStore(10000))
+    _ingest(node.core, wires, from_id)
+    snap = node_snapshot(node)
+    assert snap["store_commits"] == snap["store_db_reads"] == 0
+    assert snap["bootstrap_events_replayed"] == 0
+    assert not [k for k in snap if "store_write" in k or "bootstrap." in k
+                or "bootstrap_load" in k]
+    assert snap["sync_stage_seconds.insert.count"] > EVENTS
+    node.shutdown()
