@@ -25,7 +25,11 @@ import threading
 from typing import Dict, List, Optional
 
 from babble_tpu.common.errors import StoreError, StoreErrorKind
-from babble_tpu.crypto.canonical import canonical_dumps, canonical_loads
+from babble_tpu.crypto.canonical import (
+    PreNormalized,
+    canonical_dumps,
+    canonical_loads,
+)
 from babble_tpu.hashgraph.block import Block
 from babble_tpu.hashgraph.event import Event, EventBody
 from babble_tpu.hashgraph.frame import Frame, Root
@@ -35,9 +39,20 @@ from babble_tpu.obs.trace import NULL_STAGE
 from babble_tpu.peers.peer import Peer
 from babble_tpu.peers.peer_set import PeerSet
 
+# An event's row is written once: `data` holds its body and signature and
+# is never touched again; the consensus annotations (write-once once
+# assigned, NULL until then) are integer columns set in place. Beside each
+# column, the key a row written before the columns existed carries in its
+# JSON instead.
+_EVENT_ANNOTATIONS = (
+    ("round", "Round"),
+    ("lamport", "Lamport"),
+    ("round_received", "RoundReceived"),
+)
 _SCHEMA = """
 CREATE TABLE IF NOT EXISTS events (
-    key TEXT PRIMARY KEY, topo INTEGER NOT NULL, data TEXT NOT NULL);
+    key TEXT PRIMARY KEY, topo INTEGER NOT NULL, data TEXT NOT NULL,
+    round INTEGER, lamport INTEGER, round_received INTEGER);
 CREATE INDEX IF NOT EXISTS events_topo ON events(topo);
 CREATE TABLE IF NOT EXISTS participant_events (
     participant TEXT NOT NULL, idx INTEGER NOT NULL, hash TEXT NOT NULL,
@@ -70,6 +85,16 @@ class PersistentStore:
             # that upgrade path.
             self._db.execute("PRAGMA auto_vacuum=INCREMENTAL")
             self._db.executescript(_SCHEMA)
+            # A file written before the annotations were columns keeps
+            # them inside each row's JSON: it gains the columns here, and
+            # a row whose column is NULL is read by its JSON key
+            # (_event_from_row).
+            have = {r[1] for r in self._db.execute("PRAGMA table_info(events)")}
+            for column, _legacy_key in _EVENT_ANNOTATIONS:
+                if column not in have:
+                    self._db.execute(
+                        f"ALTER TABLE events ADD COLUMN {column} INTEGER"
+                    )
             self._db.execute("PRAGMA journal_mode=WAL")
             self._db.execute("PRAGMA synchronous=NORMAL")
             row = self._db.execute("SELECT MAX(topo) FROM events").fetchone()
@@ -91,6 +116,8 @@ class PersistentStore:
         self.stage_observer = None
         self.commits = 0  # SQLite transactions committed by a write
         self.db_reads = 0  # reads that fell through the cache to the DB
+        self.event_inserts = 0  # set_event calls that wrote the event's row
+        self.event_updates = 0  # ... that set a durable row's annotations
         # NOTE: persisted peer-sets are deliberately NOT preloaded into the
         # interval cache. The reference's design comment
         # (badger_store.go:109-118) applies verbatim: membership state must
@@ -202,10 +229,14 @@ class PersistentStore:
         try:
             return self._inmem.get_event(hash_)
         except StoreError:
-            row = self._fetch("SELECT data FROM events WHERE key = ?", (hash_,))
+            row = self._fetch(
+                "SELECT data, round, lamport, round_received FROM events "
+                "WHERE key = ?",
+                (hash_,),
+            )
             if row is None:
                 raise
-            return _event_from_json(row[0])
+            return _event_from_row(row[0], row[1:])
 
     def set_event(self, event: Event) -> None:
         # DB first, memory second: an event must be DURABLE before it can
@@ -239,47 +270,53 @@ class PersistentStore:
         """Write through to the DB; returns True when the rows are new
         (vs. a re-set of an already-durable event)."""
         key = event.hex()
-        from babble_tpu.crypto.canonical import PreNormalized
-
-        # memoized body form: byte-identical stored JSON, reusing the
-        # normalization the insert-path hash already paid for
-        d = {
-            "Body": PreNormalized(event.body.normalized()),
-            "Signature": event.signature,
-        }
-        # Consensus annotations (write-once once assigned) ride along so a
-        # cache-evicted event reloads with its round/lamport intact —
-        # after compaction the recursive recomputation may no longer have
-        # the parents to rebuild them from. Bootstrap replay strips them
-        # (topological_events) so the from-zero recompute stays pristine.
-        if event.round is not None:
-            d["Round"] = event.round
-        if event.lamport_timestamp is not None:
-            d["Lamport"] = event.lamport_timestamp
-        if event.round_received is not None:
-            d["RoundReceived"] = event.round_received
         with self._write_span(), self._db_lock:
             if self._db is None:
                 raise StoreError(
                     "PersistentStore", StoreErrorKind.CLOSED, key
                 )
-            cur = self._db.execute("SELECT topo FROM events WHERE key = ?", (key,))
-            row = cur.fetchone()
-            topo = row[0] if row else self._next_topo
-            if row is None:
-                self._next_topo += 1
+            # Consensus annotations (write-once once assigned) are durable
+            # so a cache-evicted event reloads with its round/lamport
+            # intact — after compaction the recursive recomputation may no
+            # longer have the parents to rebuild them from. Bootstrap
+            # replay leaves them behind (topological_events) so the
+            # from-zero recompute stays pristine.
+            annotations = (
+                event.round, event.lamport_timestamp, event.round_received
+            )
+            # The database says whether the event is durable already: a
+            # re-set changes three integers of the row it finds, and only
+            # an event it does not find is serialised and inserted.
+            fresh = self._db.execute(
+                "UPDATE events SET round = ?, lamport = ?, round_received = ? "
+                "WHERE key = ?",
+                (*annotations, key),
+            ).rowcount == 0
+            if fresh:
                 self._db.execute(
                     "INSERT OR REPLACE INTO participant_events "
                     "(participant, idx, hash) VALUES (?, ?, ?)",
                     (event.creator(), event.index(), key),
                 )
-            self._db.execute(
-                "INSERT OR REPLACE INTO events (key, topo, data) VALUES (?, ?, ?)",
-                (key, topo, canonical_dumps(d).decode()),
-            )
+                # memoized body form: reuses the normalization the
+                # insert-path hash already paid for
+                data = canonical_dumps({
+                    "Body": PreNormalized(event.body.normalized()),
+                    "Signature": event.signature,
+                })
+                self._db.execute(
+                    "INSERT INTO events "
+                    "(key, topo, data, round, lamport, round_received) "
+                    "VALUES (?, ?, ?, ?, ?, ?)",
+                    (key, self._next_topo, data.decode(), *annotations),
+                )
+                self._next_topo += 1
+                self.event_inserts += 1
+            else:
+                self.event_updates += 1
             self._db.commit()
             self.commits += 1
-            return row is None
+            return fresh
 
     def _unpersist_event(self, event: Event) -> None:
         key = event.hex()
@@ -398,7 +435,7 @@ class PersistentStore:
                 "SELECT data FROM events ORDER BY topo LIMIT ? OFFSET ?",
                 (count, skip),
             ).fetchall()
-        return [_event_from_json(r[0], annotated=False) for r in rows]
+        return [_event_from_row(r[0]) for r in rows]
 
     def db_peer_set(self, round: int) -> PeerSet:
         """The persisted peer-set registered at EXACTLY this round (raw DB
@@ -583,14 +620,17 @@ class PersistentStore:
             self.commits += 1
 
 
-def _event_from_json(data: str, annotated: bool = True) -> Event:
+def _event_from_row(data: str, annotations: Optional[tuple] = None) -> Event:
+    """An event from its row: body and signature from ``data`` and, where
+    the caller selected them, the annotation columns (in
+    ``_EVENT_ANNOTATIONS``' order). A NULL column falls back to the key a
+    row written before the columns existed carries in its JSON."""
     d = json.loads(data)
     ev = Event(EventBody.from_dict(d["Body"]), signature=d["Signature"])
-    if annotated:
-        if d.get("Round") is not None:
-            ev.set_round(d["Round"])
-        if d.get("Lamport") is not None:
-            ev.set_lamport_timestamp(d["Lamport"])
-        if d.get("RoundReceived") is not None:
-            ev.set_round_received(d["RoundReceived"])
+    if annotations is not None:
+        ev.round, ev.lamport_timestamp, ev.round_received = (
+            d.get(legacy_key) if value is None else value
+            for (_column, legacy_key), value
+            in zip(_EVENT_ANNOTATIONS, annotations)
+        )
     return ev

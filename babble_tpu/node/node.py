@@ -685,10 +685,13 @@ class Node(StateManager):
                 "fd_walk_steps": self.core.hg.fd_walk_steps,
                 "coord_row_regrows": self.core.hg.coord_row_regrows,
                 # the durable store (0 with an InmemStore): SQLite
-                # transactions its writes committed, reads that fell
-                # through its cache to the database, and the events a
-                # --bootstrap replayed from it
+                # transactions its writes committed (of them: event rows
+                # written, and annotations set on a durable row), reads
+                # that fell through its cache to the database, and the
+                # events a --bootstrap replayed from it
                 "store_commits": getattr(store, "commits", 0),
+                "store_event_inserts": getattr(store, "event_inserts", 0),
+                "store_event_updates": getattr(store, "event_updates", 0),
                 "store_db_reads": getattr(store, "db_reads", 0),
                 "bootstrap_events_replayed":
                     self.core.hg.bootstrap_events_replayed,

@@ -18,7 +18,9 @@ validators, 600 events, the flush gate at 16 events.
 
 from __future__ import annotations
 
+import json
 import os
+import sqlite3
 
 import pytest
 
@@ -88,6 +90,7 @@ class _Run:
     def __init__(self, path, mode):
         keys, self.peers, wires, from_id = _backlog()
         self.own = keys[ME].public_key.hex()
+        self.path = path
         store = PersistentStore(10000, path)
         core = _core(keys, self.peers, store, mode)
         self.ingest_window = _sampled(core.hg)
@@ -96,6 +99,7 @@ class _Run:
         self.ingested = durable.state_of(core.hg)
         self.ingest_commits = store.commits
         self.ingest_reads = store.db_reads
+        self.event_writes = (store.event_inserts, store.event_updates)
         self.ingest_sweeps = (core.hg.accel.stats()["accel_sweeps"]
                               if core.hg.accel is not None else 0)
         store.close()
@@ -181,6 +185,38 @@ def test_ingest_commits_and_reads(run):
     assert EVENTS < r.ingest_reads < 3 * n
 
 
+# SQLite transactions an ingest commits, as read on the parent of the PR
+# that made the annotations columns (host: the same count every time; on the
+# chip's lane a round is set once a sweep, and the sweeps, 2 to 3, are the
+# pipeline's timing: 2,787 and 2,813)
+PARENT_COMMITS = {"host": (4003, 4003), "chip-lane": (2700, 2900)}
+
+
+def test_an_event_row_is_inserted_once_and_annotated_in_place_twice(run):
+    """One commit a ``set_event`` as before: only what it carries changed."""
+    r, mode = run
+    n = r.before.row_counts()["events"]
+    inserts, updates = r.event_writes
+    assert inserts == n
+    # the round and the Lamport time at the insert's own DivideRounds, the
+    # round received once a sweep's result (host: the pass) is applied
+    assert updates == n + r.want.ordered
+    least, most = PARENT_COMMITS[mode]
+    assert least <= r.ingest_commits <= most
+    # and the file holds them as columns, the row's JSON as first written
+    db = sqlite3.connect(r.path)
+    try:
+        rows = db.execute(
+            "SELECT data, round, lamport, round_received FROM events"
+        ).fetchall()
+    finally:
+        db.close()
+    assert all(set(json.loads(data)) == {"Body", "Signature"}
+               and rnd is not None and lamport is not None
+               for data, rnd, lamport, _received in rows)
+    assert sum(rr is not None for *_, rr in rows) == r.want.ordered
+
+
 def test_a_store_that_drops_every_other_event_write_reads_not_on_disk(
         tmp_path):
     class Dropping(PersistentStore):
@@ -260,6 +296,9 @@ def test_the_store_spans_and_counters_reach_a_node_snapshot(tmp_path):
     store = node.core.hg.store
     assert snap["store_commits"] == store.commits > 3 * EVENTS
     assert snap["store_db_reads"] == store.db_reads > EVENTS
+    assert snap["store_event_inserts"] == store.event_inserts > EVENTS
+    assert snap["store_event_updates"] == store.event_updates > (
+        store.event_inserts)
     # every commit is inside a `store_write` span, a child of what wrote,
     # but the genesis peer-set's, written before the core has a tracer
     assert snap["sync_stage_seconds.store_write.count"] == store.commits - 1
@@ -281,6 +320,7 @@ def test_the_store_spans_and_counters_reach_a_node_snapshot(tmp_path):
         snap["sync_stage_seconds.bootstrap.sum"])
     # the genesis peer-set row, written again as it was before the replay
     assert snap["store_commits"] == 1 and snap["store_db_reads"] == 0
+    assert snap["store_event_inserts"] == snap["store_event_updates"] == 0
     assert "sync_stage_seconds.store_write.count" not in snap
     assert again.core.seq == n - EVENTS - 1
     again.shutdown()
@@ -291,6 +331,7 @@ def test_a_validator_with_an_inmem_store_opens_none_of_it():
     _ingest(node.core, wires, from_id)
     snap = node_snapshot(node)
     assert snap["store_commits"] == snap["store_db_reads"] == 0
+    assert snap["store_event_inserts"] == snap["store_event_updates"] == 0
     assert snap["bootstrap_events_replayed"] == 0
     assert not [k for k in snap if "store_write" in k or "bootstrap." in k
                 or "bootstrap_load" in k]

@@ -8,12 +8,17 @@ kill-all/recycle/resume)."""
 
 from __future__ import annotations
 
+import json
+import shutil
+import sqlite3
 import time
 from typing import List
 
 import pytest
 
+from babble_tpu.common.errors import StoreError, StoreErrorKind
 from babble_tpu.config.config import Config
+from babble_tpu.crypto.canonical import canonical_dumps
 from babble_tpu.crypto.keys import generate_key
 from babble_tpu.dummy.state import State as DummyState
 from babble_tpu.hashgraph.block import Block, BlockBody
@@ -360,8 +365,6 @@ def test_closed_store_refuses_event_writes(tmp_path):
     gossip an event, lose it at shutdown, and re-sign a different event at
     the same index after bootstrap — a cross-incarnation self-fork that
     permanently wedges peers holding the first incarnation's event."""
-    from babble_tpu.common.errors import StoreError, StoreErrorKind
-
     key = generate_key()
     store = PersistentStore(cache_size=100, path=str(tmp_path / "c.db"))
     peers = make_peers([key])
@@ -390,3 +393,205 @@ def test_closed_store_refuses_event_writes(tmp_path):
     with pytest.raises(StoreError):
         store2.get_event(e1.hex())
     store2.close()
+
+
+# -- an event's row is written once; its annotations are integer columns ----
+
+
+def _chain(store, n):
+    """``n`` signed events, each on the one before, of one creator that
+    ``store`` is told of."""
+    key = generate_key()
+    store.set_peer_set(0, make_peers([key]))
+    events, prev = [], ""
+    for i in range(n):
+        ev = Event.new([f"tx{i}".encode()], [], [], [prev, ""],
+                       key.public_key.bytes(), i)
+        ev.sign(key)
+        events.append(ev)
+        prev = ev.hex()
+    return events
+
+
+def _file_rows(path):
+    """The events and participant_events tables, read from outside the
+    program: {hash: (topo, data bytes, round, lamport, round received)} and
+    {(participant, index, hash)}."""
+    db = sqlite3.connect(path)
+    try:
+        events = {r[0]: r[1:] for r in db.execute(
+            "SELECT key, topo, CAST(data AS BLOB), round, lamport, "
+            "round_received FROM events")}
+        index = set(db.execute(
+            "SELECT participant, idx, hash FROM participant_events"))
+    finally:
+        db.close()
+    return events, index
+
+
+def _annotations(ev):
+    return ev.round, ev.lamport_timestamp, ev.round_received
+
+
+# what an event's three write-throughs carry, in the order the hashgraph
+# makes them: insert_event, DivideRounds, a sweep's result applied
+WRITES = [{}, {"round": 3, "lamport_timestamp": 11}, {"round_received": 5}]
+
+
+@pytest.mark.parametrize("writes", [1, 2, 3])
+def test_an_event_row_is_written_once_and_annotated_in_place(tmp_path, writes):
+    path = str(tmp_path / "s.db")
+    store = PersistentStore(100, path)
+    first, ev = _chain(store, 2)
+    store.set_event(first)  # the row under test is not the table's first
+    seen = []
+    for write in WRITES[:writes]:
+        for annotation, value in write.items():
+            setattr(ev, annotation, value)
+        commits = store.commits
+        store.set_event(ev)
+        # one committed transaction a call, fresh or re-set: a second
+        # connection reads what it carried as soon as the call returns
+        assert store.commits == commits + 1
+        events, index = _file_rows(path)
+        seen.append(events[ev.hex()])
+    topo, data = seen[0][:2]
+    assert topo == 1 and set(json.loads(data)) == {"Body", "Signature"}
+    assert all(row[:2] == (topo, data) for row in seen)
+    assert seen[-1][2:] == [(None,) * 3, (3, 11, None), (3, 11, 5)][writes - 1]
+    assert index == {(ev.creator(), 0, first.hex()),
+                     (ev.creator(), 1, ev.hex())}
+    assert (store.event_inserts, store.event_updates) == (2, writes - 1)
+    store.close()
+
+
+def test_an_evicted_event_reloads_annotated_and_a_replay_loads_none(tmp_path):
+    store = PersistentStore(4, str(tmp_path / "s.db"))
+    events = _chain(store, 12)
+    for i, ev in enumerate(events):
+        store.set_event(ev)
+        ev.set_round(i // 3)
+        ev.set_lamport_timestamp(i)
+        store.set_event(ev)
+        if i < 9:  # the last three stay undetermined
+            ev.set_round_received(i // 3 + 1)
+            store.set_event(ev)
+    reads = store.db_reads
+    for i in (0, 4, 10):  # the cache holds 4: the first two come from disk
+        got = store.get_event(events[i].hex())
+        assert got.hex() == events[i].hex() and got.verify()
+        assert _annotations(got) == _annotations(events[i])
+    assert store.db_reads == reads + 2
+    assert _annotations(events[10]) == (3, 10, None)
+    replayed = store.topological_events(0, 100)
+    assert [e.hex() for e in replayed] == [e.hex() for e in events]
+    assert all(_annotations(e) == (None,) * 3 for e in replayed)
+    store.close()
+
+
+@pytest.mark.parametrize("refused", ["fresh", "re-set"])
+def test_an_event_the_cache_refuses_after_the_database_took_it(
+        tmp_path, refused):
+    """The database is written before memory. A fresh event the cache then
+    refuses loses both its rows again; a re-set it refuses leaves the
+    durable row where it is."""
+    path = str(tmp_path / "s.db")
+    store = PersistentStore(4, path)
+    events = _chain(store, 14)
+    for ev in events[:12]:
+        store.set_event(ev)
+    before = _file_rows(path)
+    if refused == "fresh":
+        with pytest.raises(StoreError) as err:
+            store.set_event(events[13])  # index 12 never came
+        assert err.value.kind == StoreErrorKind.SKIPPED_INDEX
+        assert _file_rows(path) == before
+        assert (store.event_inserts, store.event_updates) == (13, 0)
+    else:
+        ev = events[0]  # let go by the cache, behind its rolling window
+        ev.set_round(2)
+        with pytest.raises(StoreError) as err:
+            store.set_event(ev)
+        assert err.value.kind == StoreErrorKind.TOO_LATE
+        rows, index = _file_rows(path)
+        assert index == before[1] and rows.keys() == before[0].keys()
+        assert rows[ev.hex()] == before[0][ev.hex()][:2] + (2, None, None)
+        assert (store.event_inserts, store.event_updates) == (12, 1)
+    store.close()
+
+
+def _as_written_before_the_columns(path):
+    """Rewrite the file at ``path`` (closed) into the format before an
+    event's annotations were columns: the events table of that time, each
+    row's round, Lamport time and round received inside its JSON."""
+    db = sqlite3.connect(path)
+    try:
+        rows = db.execute(
+            "SELECT key, topo, data, round, lamport, round_received "
+            "FROM events").fetchall()
+        db.executescript("""
+            DROP INDEX events_topo; DROP TABLE events;
+            CREATE TABLE events (
+                key TEXT PRIMARY KEY, topo INTEGER NOT NULL, data TEXT NOT NULL);
+            CREATE INDEX events_topo ON events(topo);
+        """)
+        for key, topo, data, *annotations in rows:
+            d = json.loads(data)
+            d.update({k: v for k, v in
+                      zip(("Round", "Lamport", "RoundReceived"), annotations)
+                      if v is not None})
+            db.execute("INSERT INTO events VALUES (?, ?, ?)",
+                       (key, topo, canonical_dumps(d).decode()))
+        db.commit()
+    finally:
+        db.close()
+
+
+def test_a_file_written_before_the_columns_opens_reloads_and_replays(tmp_path):
+    """An operator who upgrades a ``--store`` validator: the older file gains
+    the columns on open, a row not yet re-set is read by its JSON keys, a
+    re-set lands in the columns, and ``--bootstrap`` replays the file."""
+    from benchmark.harness import durable
+    from test_durable_catchup import _backlog, _core, _ingest
+
+    keys, peers, wires, from_id = _backlog()
+    path = str(tmp_path / "babble.db")
+    store = PersistentStore(10000, path)
+    core = _core(keys, peers, store, "host")
+    _ingest(core, wires, from_id)
+    want = durable.state_of(core.hg)
+    store.close()
+    written, _index = _file_rows(path)
+    _as_written_before_the_columns(path)
+    db = sqlite3.connect(path)
+    assert [r[1] for r in db.execute("PRAGMA table_info(events)")] == [
+        "key", "topo", "data"]
+    db.close()
+
+    store = PersistentStore(10000, path)  # opens, and adds the columns
+    older, _index = _file_rows(path)
+    assert older.keys() == written.keys()
+    assert all(row[2:] == (None,) * 3 for row in older.values())
+    assert any("RoundReceived" in json.loads(row[1]) for row in older.values())
+    for key, row in written.items():  # a cold cache: every one from the file
+        assert _annotations(store.get_event(key)) == row[2:]
+    store.set_peer_set(0, peers)
+    undetermined = next(k for k, row in written.items() if row[4] is None)
+    ev = store.get_event(undetermined)
+    ev.set_round_received(99)
+    store.set_event(ev)
+    assert (store.event_inserts, store.event_updates) == (0, 1)
+    store.close()
+    rows, _index = _file_rows(path)
+    assert rows[undetermined] == older[undetermined][:2] + (
+        written[undetermined][2:4] + (99,))
+    del rows[undetermined], older[undetermined]
+    assert rows == older
+
+    store = PersistentStore(10000, path)
+    assert _annotations(store.get_event(undetermined))[2] == 99
+    core = _core(keys, peers, store, "host")
+    core.bootstrap()
+    assert durable.state_of(core.hg) == want
+    assert core.hg.bootstrap_events_replayed == len(written)
+    store.close()
