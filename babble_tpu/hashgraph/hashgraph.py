@@ -306,6 +306,10 @@ class Hashgraph:
         self.round_ctx_patches = 0
         # events `bootstrap` has replayed from a persistent store
         self.bootstrap_events_replayed = 0
+        # fast-sync: Frame events inserted as trusted by a reset, and
+        # block signatures check_block verified
+        self.frame_events_inserted = 0
+        self.anchor_signatures_checked = 0
         self.round_ctx_rebuilds = 0
         # The column space of event coordinates: one column per
         # participant of the store's repertoire, in order of first
@@ -1034,6 +1038,7 @@ class Hashgraph:
         )
         self._update_ancestor_first_descendant(event)
         self.store.add_consensus_event(event)
+        self.frame_events_inserted += 1
 
     # =========================================================================
     # Consensus pipeline
@@ -1807,6 +1812,7 @@ class Hashgraph:
         for s in block.get_signatures():
             if s.validator_hex() not in peer_set.by_pub_key:
                 continue
+            self.anchor_signatures_checked += 1
             if block.verify_signature(s):
                 valid += 1
         if valid <= peer_set.trust_count():

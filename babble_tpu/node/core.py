@@ -640,12 +640,14 @@ class Core:
         (reference: core.go:367-402)."""
         peer_set = frame.peers
 
-        self.hg.check_block(block, peer_set)
+        with self._span("ff_check"):
+            self.hg.check_block(block, peer_set)
 
-        if block.frame_hash() != frame.hash():
-            raise ValueError("invalid frame hash")
+            if block.frame_hash() != frame.hash():
+                raise ValueError("invalid frame hash")
 
-        self.hg.reset(block, frame)
+        with self._span("ff_reset"):
+            self.hg.reset(block, frame)
         self.set_head_and_seq()
         self.set_peers(peer_set)
         self.validators = peer_set

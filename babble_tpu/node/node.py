@@ -161,6 +161,11 @@ class Node(StateManager):
         # stall watchdog's gossip-liveness signal.
         self.trace_ctx_rpcs = 0
         self.last_gossip_ok: Optional[float] = None
+        # Fast-sync landings (_fast_forward): those that reset the
+        # hashgraph onto a peer's anchor block, and those refused — the
+        # exception is logged and the node stays CATCHING_UP.
+        self.fast_forwards = 0
+        self.fast_forward_failures = 0
         # Provenance knobs ride the Config; the table itself was built
         # by the core's NodeTelemetry (so standalone cores trace too).
         self.telemetry.provenance.configure(
@@ -695,6 +700,14 @@ class Node(StateManager):
                 "store_db_reads": getattr(store, "db_reads", 0),
                 "bootstrap_events_replayed":
                     self.core.hg.bootstrap_events_replayed,
+                # fast-sync (0 on a validator that never lands): landings
+                # made and refused, the Frame events they inserted as
+                # trusted, the block signatures check_block verified
+                "fast_forwards": self.fast_forwards,
+                "fast_forward_failures": self.fast_forward_failures,
+                "frame_events_inserted": self.core.hg.frame_events_inserted,
+                "anchor_signatures_checked":
+                    self.core.hg.anchor_signatures_checked,
             }
         )
         # Mempool surface (docs/mempool.md): admission verdict counters,
@@ -1200,28 +1213,37 @@ class Node(StateManager):
     # -- catching up --------------------------------------------------------
 
     def _fast_forward(self) -> None:
-        """reference: node.go:622-666."""
-        self.logger.info("CATCHING-UP")
-        self.wait_routines(timeout=2.0)
+        """reference: node.go:622-666. One landing is the root span
+        ``fast_forward`` on this thread; a landing that is refused (a
+        block without enough signatures, a Frame that is not the block's,
+        an application that cannot restore) is counted and leaves the node
+        CATCHING_UP for the run loop to poll again."""
+        with self.core._span("fast_forward"):
+            self.logger.info("CATCHING-UP")
+            self.wait_routines(timeout=2.0)
 
-        resp = self._get_best_fast_forward_response()
-        if resp is None:
+            with self.core._span("ff_poll"):
+                resp = self._get_best_fast_forward_response()
+            if resp is None:
+                self._transition(State.BABBLING)
+                return
+
+            try:
+                with self.core._span("ff_restore"):
+                    self.proxy.restore(resp.snapshot)
+                with self.core_lock:
+                    self.core.fast_forward(resp.block, resp.frame)
+                self.core.process_accepted_internal_transactions(
+                    resp.block.round_received(),
+                    resp.block.internal_transaction_receipts(),
+                )
+            except Exception as err:
+                self.fast_forward_failures += 1
+                self.logger.error("fast-forward failed: %s", err)
+                return
+
+            self.fast_forwards += 1
             self._transition(State.BABBLING)
-            return
-
-        try:
-            self.proxy.restore(resp.snapshot)
-            with self.core_lock:
-                self.core.fast_forward(resp.block, resp.frame)
-            self.core.process_accepted_internal_transactions(
-                resp.block.round_received(),
-                resp.block.internal_transaction_receipts(),
-            )
-        except Exception as err:
-            self.logger.error("fast-forward failed: %s", err)
-            return
-
-        self._transition(State.BABBLING)
 
     def _get_best_fast_forward_response(self) -> Optional[FastForwardResponse]:
         """Poll all peers, keep the highest block (reference: node.go:670-701).

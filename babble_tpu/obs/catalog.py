@@ -46,7 +46,8 @@ CATALOG: Tuple[Instrument, ...] = (
         "process_sig_pool, diff, eager_sync, mempool_drain, self_event, "
         "sync, prepare_sync, flush, record_heads, membership, "
         "creator_stall, peer_set_wait, store_write, bootstrap, "
-        "bootstrap_load. Inclusive: a span's whole "
+        "bootstrap_load, fast_forward, ff_poll, ff_restore, ff_check, "
+        "ff_reset. Inclusive: a span's whole "
         "duration, its children's included.",
     ),
     Instrument(
@@ -61,7 +62,8 @@ CATALOG: Tuple[Instrument, ...] = (
         "Thread CPU time (time.thread_time) inside the COARSE spans "
         "only: sync, prepare_sync, decode, batch_verify, flush, commit, "
         "self_event, creator_stall, peer_set_wait, bootstrap, "
-        "bootstrap_load and the accel spans "
+        "bootstrap_load, fast_forward, ff_poll, ff_restore, ff_check, "
+        "ff_reset and the accel spans "
         "build, snapshot (delta_scan + "
         "pack), dispatch, readback, apply. Wall minus CPU is time the "
         "thread did not run: GIL, sleep, device wait. Empty on a "
@@ -385,6 +387,28 @@ CATALOG: Tuple[Instrument, ...] = (
         "trace context.",
     ),
     Instrument(
+        "fast_forwards_total", _C, (), "node",
+        "Fast-sync landings: Node._fast_forward reset the hashgraph onto "
+        "a peer's anchor block and its Frame and went on to BABBLING.",
+    ),
+    Instrument(
+        "fast_forward_failures_total", _C, (), "node",
+        "Fast-sync landings refused: the anchor block had too few valid "
+        "signatures, the Frame was not the block's, or the restore or "
+        "the reset raised. The node stays CATCHING_UP and polls again.",
+    ),
+    Instrument(
+        "frame_events_inserted_total", _C, (), "node",
+        "Frame events inserted as trusted (no signature or parent check) "
+        "by Hashgraph.reset: the Roots' and the anchor round's.",
+    ),
+    Instrument(
+        "anchor_signatures_checked_total", _C, (), "node",
+        "Block signatures Hashgraph.check_block verified before a "
+        "fast-sync landing (signers outside the peer-set are skipped "
+        "unverified).",
+    ),
+    Instrument(
         "watchdog_trips_total", _C, (), "node",
         "Stall-watchdog trips (busy node, no consensus progress past "
         "the threshold).",
@@ -600,6 +624,7 @@ SYNC_STAGES = (
     "self_event", "sync", "prepare_sync", "flush", "record_heads",
     "membership", "creator_stall", "peer_set_wait",
     "store_write", "bootstrap", "bootstrap_load",
+    "fast_forward", "ff_poll", "ff_restore", "ff_check", "ff_reset",
 )
 # COARSE spans open at most a few times per sync: obs/trace.py also
 # reads the thread CPU clock and writes a profiler annotation for them.
@@ -611,6 +636,7 @@ COARSE_STAGES = (
     "sync", "prepare_sync", "decode", "batch_verify", "flush", "commit",
     "self_event", "creator_stall", "peer_set_wait",
     "bootstrap", "bootstrap_load",
+    "fast_forward", "ff_poll", "ff_restore", "ff_check", "ff_reset",
     "build", "snapshot", "dispatch", "readback", "apply",
 )
 TX_STAGES = ("mempool_wait", "consensus")

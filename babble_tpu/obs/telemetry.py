@@ -400,6 +400,21 @@ class NodeTelemetry:
         self._func(
             "trace_ctx_rpcs_total", lambda: node.trace_ctx_rpcs
         )
+        # Fast-sync (docs/fastsync.md): landings made and refused, and
+        # what a landing inserted and verified.
+        self._func("fast_forwards_total", lambda: node.fast_forwards)
+        self._func(
+            "fast_forward_failures_total",
+            lambda: node.fast_forward_failures,
+        )
+        self._func(
+            "frame_events_inserted_total",
+            lambda: node.core.hg.frame_events_inserted,
+        )
+        self._func(
+            "anchor_signatures_checked_total",
+            lambda: node.core.hg.anchor_signatures_checked,
+        )
         # Async gossip engine (docs/gossip.md): pipeline occupancy.
         # node.pipeline is None when the pipeline is disabled (sim clock
         # or config) — the instruments then read 0.
