@@ -304,8 +304,11 @@ class Hashgraph:
         # matrix entries written + witness rows appended in place, and
         # matrices built at lookup (first use of a round, or a dropped entry)
         self.round_ctx_patches = 0
-        # events `bootstrap` has replayed from a persistent store
+        # events `bootstrap` has replayed from a persistent store, and
+        # those of them whose signature verdict the caller's batch verifier
+        # had cached before their insert
         self.bootstrap_events_replayed = 0
+        self.bootstrap_events_batch_verified = 0
         # fast-sync: Frame events inserted as trusted by a reset, and
         # block signatures check_block verified
         self.frame_events_inserted = 0
@@ -1700,11 +1703,19 @@ class Hashgraph:
         self.round_lower_bound = block.round_received()
 
     @staged("bootstrap")
-    def bootstrap(self) -> None:
+    def bootstrap(self, prevalidate=None) -> None:
         """Replay a persistent store's events through consensus in
         topological order — only from index 0 (reference: hashgraph.go:1481-1536).
         The persistent store provides topological_events(); InmemStore has
-        nothing to replay."""
+        nothing to replay.
+
+        ``prevalidate`` is the caller's batch verifier (a sync's:
+        Core._batch_prevalidate), given each loaded batch before its first
+        insert: it caches every event's verdict, so insert_event's
+        verify() is a cache hit. The inserts stay sequential — an event
+        with a bad signature is refused at ITS insert, the batch's earlier
+        events in and none after it. Without one each event is verified
+        alone at its insert."""
         topo = getattr(self.store, "topological_events", None)
         if topo is None:
             return
@@ -1718,9 +1729,13 @@ class Hashgraph:
             while True:
                 with NULL_STAGE if obs is None else obs.span("bootstrap_load"):
                     events = topo(index * batch_size, batch_size)
+                if prevalidate is not None and events:
+                    prevalidate(events)
+                verified = sum(e.prevalidated() is not None for e in events)
                 for e in events:
                     self.insert_event_and_run_consensus(e, set_wire_info=True)
                 self.bootstrap_events_replayed += len(events)
+                self.bootstrap_events_batch_verified += verified
                 self.flush_consensus()
                 self.process_sig_pool()
                 if len(events) < batch_size:

@@ -229,7 +229,11 @@ class Core:
         self.seq = seq
 
     def bootstrap(self) -> None:
-        self.hg.bootstrap()
+        """Replay the store. Where a sync's signatures are verified in
+        one native batch call (prepare_sync's condition), so is each
+        batch the replay loads; else by singles at insert."""
+        batched = self.accelerated_verify or self._host_batch_verify
+        self.hg.bootstrap(self._batch_prevalidate if batched else None)
 
     def set_peers(self, ps: PeerSet) -> None:
         """reference: core.go:185-188. ``prior`` carries the surviving

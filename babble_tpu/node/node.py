@@ -692,14 +692,17 @@ class Node(StateManager):
                 # the durable store (0 with an InmemStore): SQLite
                 # transactions its writes committed (of them: event rows
                 # written, and annotations set on a durable row), reads
-                # that fell through its cache to the database, and the
-                # events a --bootstrap replayed from it
+                # that fell through its cache to the database, the events
+                # a --bootstrap replayed from it, and those of them whose
+                # signatures a batch call had verified before their insert
                 "store_commits": getattr(store, "commits", 0),
                 "store_event_inserts": getattr(store, "event_inserts", 0),
                 "store_event_updates": getattr(store, "event_updates", 0),
                 "store_db_reads": getattr(store, "db_reads", 0),
                 "bootstrap_events_replayed":
                     self.core.hg.bootstrap_events_replayed,
+                "bootstrap_events_batch_verified":
+                    self.core.hg.bootstrap_events_batch_verified,
                 # fast-sync (0 on a validator that never lands): landings
                 # made and refused, the Frame events they inserted as
                 # trusted, the block signatures check_block verified

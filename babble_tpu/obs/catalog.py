@@ -91,7 +91,8 @@ CATALOG: Tuple[Instrument, ...] = (
     Instrument(
         "ingest_batch_verifies_total", _C, (), "node",
         "Native batch signature-verification calls (one per sync chunk on "
-        "the happy path).",
+        "the happy path, and one per batch of 100 a --bootstrap replay "
+        "loads).",
     ),
     Instrument(
         "ingest_batch_size_max", _G, (), "node",
@@ -407,6 +408,19 @@ CATALOG: Tuple[Instrument, ...] = (
         "Block signatures Hashgraph.check_block verified before a "
         "fast-sync landing (signers outside the peer-set are skipped "
         "unverified).",
+    ),
+    Instrument(
+        "bootstrap_events_replayed_total", _C, (), "node",
+        "Events a --bootstrap restart replayed from the persistent store "
+        "(whole batches of 100; 0 with an InmemStore).",
+    ),
+    Instrument(
+        "bootstrap_events_batch_verified_total", _C, (), "node",
+        "Of the replayed events, those whose signatures the native batch "
+        "verifier had checked, one call a loaded batch, before their "
+        "insert. Equal to bootstrap_events_replayed_total where the "
+        "native library is there; 0 where each is verified alone at "
+        "insert.",
     ),
     Instrument(
         "watchdog_trips_total", _C, (), "node",

@@ -415,6 +415,16 @@ class NodeTelemetry:
             "anchor_signatures_checked_total",
             lambda: node.core.hg.anchor_signatures_checked,
         )
+        # A --bootstrap restart (docs/lifecycle.md): events replayed, and
+        # those the batch verifier had checked before their insert.
+        self._func(
+            "bootstrap_events_replayed_total",
+            lambda: node.core.hg.bootstrap_events_replayed,
+        )
+        self._func(
+            "bootstrap_events_batch_verified_total",
+            lambda: node.core.hg.bootstrap_events_batch_verified,
+        )
         # Async gossip engine (docs/gossip.md): pipeline occupancy.
         # node.pipeline is None when the pipeline is disabled (sim clock
         # or config) — the instruments then read 0.

@@ -13,25 +13,36 @@ validators, 600 events, the flush gate at 16 events.
   the last deferred sweep before the write gate reopens
   (``Hashgraph.bootstrap`` drains), and so leaves the file as it found it;
 - the spans and counters the durable store brought, which a validator with
-  an ``InmemStore`` never opens.
+  an ``InmemStore`` never opens;
+- a replay verifies each batch it loads in one native call before the
+  batch's first insert, as a sync does (``Core.bootstrap`` hands
+  ``Hashgraph.bootstrap`` ``Core._batch_prevalidate``), or by singles at
+  insert where there is no batch verifier: the same state, every signature
+  verified, and a row altered from outside refused where it stands.
 """
 
 from __future__ import annotations
 
+import base64
 import json
 import os
+import shutil
 import sqlite3
 
 import pytest
 
+from babble_tpu import native_crypto
 from babble_tpu.common.errors import StoreError
+from babble_tpu.crypto import batch as sig_batch
 from babble_tpu.hashgraph import InmemStore
+from babble_tpu.hashgraph.errors import InvalidSignatureError
 from babble_tpu.hashgraph.persistent_store import PersistentStore
 from babble_tpu.hashgraph.round_info import RoundInfo
 from babble_tpu.node.core import Core
 from babble_tpu.node.validator import Validator
+from babble_tpu.peers.peer_set import PeerSet
 from babble_tpu.proxy.proxy import dummy_commit_response
-from benchmark.harness import data, durable
+from benchmark.harness import churn, data, durable
 from benchmark.harness.counters import node_snapshot
 
 N, ME, EVENTS, SYNC = 4, 0, 600, 300
@@ -116,6 +127,7 @@ class _Run:
         self.replay_reads = store.db_reads - reads
         self.replayed = durable.state_of(core.hg)
         self.events_replayed = core.hg.bootstrap_events_replayed
+        self.events_batch_verified = core.hg.bootstrap_events_batch_verified
         self.head_seq = (core.head, core.seq)
         self.after = durable.read(path)
         store.close()
@@ -149,6 +161,8 @@ def test_the_validator_equals_the_reference_before_the_stop(run):
 def test_bootstrap_recomputes_what_a_sequential_validator_reaches(run):
     r, _mode = run
     assert r.events_replayed == len(r.before.event_rows)
+    if sig_batch.available():  # `Core` then verifies by the batch
+        assert r.events_batch_verified == r.events_replayed
     assert durable.blocks_differing(r.replayed.blocks, r.want.blocks) == 0
     assert r.replayed.ordered == r.want.ordered
     assert r.replayed.last_consensus_round == r.want.last_consensus_round
@@ -265,6 +279,152 @@ def test_a_replay_reads_a_derived_row_back_only_once_it_has_set_it(tmp_path):
     store.close()
 
 
+# -- a replay's signatures: one native call a loaded batch, or singles -------
+
+LANES = pytest.mark.parametrize("lane", ["batch", "singles"])
+
+
+class _Stopped:
+    """A database a host-path validator left behind, read from outside,
+    with what the reference makes of it (``want`` is None for a file the
+    reference is not asked about)."""
+
+    def __init__(self, path, keys, peers, wires, from_id, referee=True):
+        self.path, self.keys, self.peers = path, keys, peers
+        store = PersistentStore(10000, path)
+        _ingest(_core(keys, peers, store, "host"), wires, from_id)
+        self.db = durable.read(path)
+        store.close()
+        self.want = durable.replay(self.db, peers) if referee else None
+
+    def restart(self, lane, path=None):
+        """v0's next incarnation on ``path``, before its replay."""
+        if lane == "batch" and not sig_batch.available():
+            pytest.skip("no native batch verifier on this host")
+        store = PersistentStore(10000, path or self.path)
+        core = _core(self.keys, self.peers, store, "host")
+        if lane == "singles":
+            core._host_batch_verify = False  # a host without the library
+        return core, store
+
+
+@pytest.fixture(scope="module")
+def stopped(tmp_path_factory):
+    path = tmp_path_factory.mktemp("stopped") / "babble.db"
+    keys, peers, wires, from_id = _backlog()
+    return _Stopped(str(path), keys, peers, wires, from_id)
+
+
+@pytest.fixture(scope="module")
+def stopped_after_changes(tmp_path_factory):
+    """A file whose history holds three membership changes: 4 genesis
+    validators, 2 joiners, one leaver (``test_churn_catchup.py``'s)."""
+    path = tmp_path_factory.mktemp("changes") / "babble.db"
+    keys = data.seeded_keys(6, SEED)
+    peers = churn.all_peers(keys, N)
+    genesis = PeerSet(peers[:N])
+    _script, wires = churn.churn_script(
+        keys, peers, genesis, [i for i in range(N) if i != ME],
+        churn.parse_requests(["+x0", "-v3", "+x1"], N), EVENTS, 2147489957,
+        40, 180, 100)
+    return _Stopped(str(path), keys, genesis, wires, peers[1].id,
+                    referee=False)
+
+
+@LANES
+def test_a_replay_verifies_every_signature_in_either_lane(
+        stopped, lane, monkeypatch):
+    core, store = stopped.restart(lane)
+    singles, verify_one = [0], native_crypto.verify_one
+
+    def counted(*args):
+        singles[0] += 1
+        return verify_one(*args)
+
+    monkeypatch.setattr(native_crypto, "verify_one", counted)
+    with sig_batch._VERDICTS_LOCK:
+        sig_batch._VERDICTS.purge()  # a cold verdict cache, as a restart's
+    misses = sig_batch.VERIFY_CACHE.misses
+    core.bootstrap()
+    got = durable.state_of(core.hg)
+    store.close()
+    n = len(stopped.db.event_rows)  # one signature each: no request rides
+    # v0's own events carry its signatures of the blocks it committed:
+    # the sig pool verifies each alone, in a replay as after a sync
+    block_sigs = sum(len(json.loads(row)["Body"]["BlockSignatures"])
+                     for row in stopped.db.event_rows)
+    # the same blocks, last round, ordered count and SET of undetermined
+    # events as the sequential host hashgraph, so as the other lane
+    assert got == stopped.want
+    assert core.hg.bootstrap_events_replayed == n > EVENTS
+    # nothing skipped: every signature went to the verifier, once
+    if lane == "batch":
+        assert core.hg.bootstrap_events_batch_verified == n
+        assert sig_batch.VERIFY_CACHE.misses - misses == n
+        assert core.ingest_batch_verifies == -(-n // 100)  # one a batch
+        assert core.ingest_fallback_singles == 0
+        assert singles[0] == block_sigs > 0
+    else:
+        assert core.hg.bootstrap_events_batch_verified == 0
+        assert sig_batch.VERIFY_CACHE.misses - misses == 0
+        assert core.ingest_batch_verifies == 0
+        assert singles[0] == n + block_sigs
+
+
+def _alter_signature(path, internal):
+    """From outside, with ``sqlite3`` alone: one character of a row's
+    ``Signature`` — the creator's, or its internal transaction's — becomes
+    another. An event's hash covers its internal transactions' signatures,
+    so the second alters both verdicts; nothing else of the file moves.
+    Returns the row's place in the replay and who made it."""
+    db = sqlite3.connect(path)
+    try:
+        rows = db.execute("SELECT topo, data FROM events ORDER BY topo")
+        rows = [(k, json.loads(d)) for k, d in rows]
+        if internal:
+            k, row = [(k, r) for k, r in rows
+                      if r["Body"]["InternalTransactions"]][-1]
+            holder = row["Body"]["InternalTransactions"][0]
+        else:
+            k, row = rows[250]
+            holder = row
+        sig = holder["Signature"]
+        holder["Signature"] = sig[:-1] + ("1" if sig[-1] == "0" else "0")
+        db.execute("UPDATE events SET data = ? WHERE topo = ?",
+                   (json.dumps(row), k))
+        db.commit()
+    finally:
+        db.close()
+    return k, (row["Body"]["Creator"], row["Body"]["Index"])
+
+
+@LANES
+@pytest.mark.parametrize("altered", ["creator", "internal-transaction"])
+def test_a_row_altered_from_outside_stops_the_replay_where_it_stands(
+        stopped, stopped_after_changes, altered, lane, tmp_path):
+    internal = altered != "creator"
+    origin = stopped_after_changes if internal else stopped
+    # a store that was closed leaves one file, no `-wal` beside it
+    path = shutil.copy(origin.path, str(tmp_path / "babble.db"))
+    k, (creator, index) = _alter_signature(path, internal)
+    assert k % 100 and k > 100  # inside a batch, whole batches before it
+    found = durable.read(path)
+    core, store = origin.restart(lane, path)
+    with pytest.raises(InvalidSignatureError) as refused:
+        core.bootstrap()
+    ev = refused.value.event
+    assert (base64.b64encode(ev.body.creator).decode(), ev.index()) == (
+        creator, index)
+    # every earlier event of its batch is in, none after it
+    assert core.hg.topological_index == k
+    assert sum(i + 1 for i in core.hg.store.known_events().values()) == k
+    assert core.hg.bootstrap_events_replayed == k - k % 100  # whole batches
+    # the batch flagged it; the scalar verifier had the last word
+    assert core.ingest_fallback_singles == (1 if lane == "batch" else 0)
+    assert durable.rows_changed(found, durable.read(path)) == 0
+    store.close()
+
+
 def _node(store, bootstrap=False):
     """A ``Node`` as ``engine.py`` builds it around ``store``, host path,
     through ``Node.init()`` (which replays when ``bootstrap``), not started."""
@@ -314,6 +474,11 @@ def test_the_store_spans_and_counters_reach_a_node_snapshot(tmp_path):
     snap = node_snapshot(again)
     n = snap["bootstrap_events_replayed"]
     assert n == snap["sync_stage_seconds.insert.count"] > EVENTS
+    if sig_batch.available():  # each loaded batch in one `batch_verify`
+        assert snap["bootstrap_events_batch_verified"] == n
+        assert snap["sync_stage_seconds.batch_verify.count"] == -(-n // 100)
+        assert snap["sync_stage_seconds.batch_verify.sum"] < (
+            snap["sync_stage_seconds.bootstrap.sum"])
     assert snap["sync_stage_seconds.bootstrap.count"] == 1
     assert snap["sync_stage_seconds.bootstrap_load.count"] == n // 100 + 1
     assert snap["sync_stage_seconds.bootstrap_load.sum"] < (
@@ -333,6 +498,7 @@ def test_a_validator_with_an_inmem_store_opens_none_of_it():
     assert snap["store_commits"] == snap["store_db_reads"] == 0
     assert snap["store_event_inserts"] == snap["store_event_updates"] == 0
     assert snap["bootstrap_events_replayed"] == 0
+    assert snap["bootstrap_events_batch_verified"] == 0
     assert not [k for k in snap if "store_write" in k or "bootstrap." in k
                 or "bootstrap_load" in k]
     assert snap["sync_stage_seconds.insert.count"] > EVENTS
