@@ -387,8 +387,6 @@ class TensorConsensus:
         # topological index of the hashgraph at the snapshot of the sweep
         # applied last: what Hashgraph.voting_deferred compares
         self.applied_topo = -1
-        self.last_sweep_s = 0.0
-        self.total_sweep_s = 0.0
         self.last_window_events = 0
         # Per-stage rolling sums (seconds) for /debug and bench breakdowns.
         # snapshot cost = build (full rebuilds) + delta_scan + pack
@@ -1021,7 +1019,7 @@ class TensorConsensus:
             self.stale_drops += 1
             self.breaker.cancel()  # not the device's fault: no verdict
             return False
-        with self._span("apply") as applying:
+        with self._span("apply"):
             try:
                 fame, rr = inf.result
                 _decided, fame_applied = voting.apply_fame(hg, inf.win, fame)
@@ -1031,8 +1029,6 @@ class TensorConsensus:
                 return False
             if inf.snap is not None and state is not None:
                 state.note_applied(fame_applied, received)
-        t_apply = applying.seconds
-        kernel_s = inf.t_done - inf.t_launch  # dispatch+kernel+readback
         self._stage("readback", inf.readback_s)
         if inf.wake_s is not None:
             self._stage("wake", inf.wake_s)
@@ -1042,11 +1038,6 @@ class TensorConsensus:
         self.sweeps += 1
         self.applied_topo = inf.topo
         self.last_window_events = len(inf.win.hashes)
-        # Sweep cost, not launch-to-apply wall time (the latter includes
-        # the idle wait for this flush and would read as the flush
-        # interval in /stats).
-        self.last_sweep_s = kernel_s + t_apply
-        self.total_sweep_s += self.last_sweep_s
         return True
 
     # -- synchronous sweep ---------------------------------------------------
@@ -1056,7 +1047,6 @@ class TensorConsensus:
         fall back to the oracle pipeline."""
         from babble_tpu.ops import voting
 
-        t0 = time.perf_counter()
         try:
             win, snap = self._snapshot(hg, for_batcher=bool(self.batcher))
             if win is None:
@@ -1115,8 +1105,6 @@ class TensorConsensus:
         self.sweeps += 1
         self.applied_topo = hg.topological_index
         self.last_window_events = len(win.hashes)
-        self.last_sweep_s = time.perf_counter() - t0
-        self.total_sweep_s += self.last_sweep_s
         return True
 
     def _note_fallback(self, err: BaseException) -> None:
@@ -1144,9 +1132,6 @@ class TensorConsensus:
     def stats(self) -> dict:
         from babble_tpu.ops import voting as _voting
 
-        avg_ms = (
-            1000.0 * self.total_sweep_s / self.sweeps if self.sweeps else 0.0
-        )
         out = {
             "consensus_engine": "device",
             # which strongly-see path the sweep kernels trace: "tpu" =
@@ -1167,8 +1152,6 @@ class TensorConsensus:
                 if self.mesh is not None
                 else None
             ),
-            "accel_last_sweep_ms": round(1000.0 * self.last_sweep_s, 3),
-            "accel_avg_sweep_ms": round(avg_ms, 3),
             "accel_last_window_events": self.last_window_events,
             # Per-stage breakdown (ms totals): snapshot cost is build (full
             # rebuilds) + delta_scan + pack (incremental); dispatch and

@@ -111,13 +111,17 @@ class PersistentStore:
         # fame from a future it has not inserted yet.
         self._replayed: Dict[str, set] = {}
         # A node's span tracer (obs/trace.py; Core hands it over), or None:
-        # `store_write` spans around every write-through. The tallies are
-        # plain ints read by Node.get_stats_snapshot().
+        # `store_write` spans around every write-through, `store_encode`
+        # around a derived row's serialisation. The tallies are plain ints
+        # read by Node.get_stats_snapshot().
         self.stage_observer = None
         self.commits = 0  # SQLite transactions committed by a write
         self.db_reads = 0  # reads that fell through the cache to the DB
         self.event_inserts = 0  # set_event calls that wrote the event's row
         self.event_updates = 0  # ... that set a durable row's annotations
+        # bytes of the derived rows serialised and committed, and by table
+        self.encoded_bytes = 0
+        self.encoded_bytes_by_table = {"rounds": 0, "frames": 0, "blocks": 0}
         # NOTE: persisted peer-sets are deliberately NOT preloaded into the
         # interval cache. The reference's design comment
         # (badger_store.go:109-118) applies verbatim: membership state must
@@ -139,13 +143,22 @@ class PersistentStore:
             return None
         return self._fetch(sql, (key,))
 
-    def _write_derived(self, table: str, sql: str, key: int, data: dict) -> None:
-        """Write a rounds / frames / blocks row through. Gated off during a
-        bootstrap replay, which only notes the key as recomputed."""
-        if self._maintenance:
-            self._replayed.setdefault(table, set()).add(key)
-            return
-        self._write(sql, (key, canonical_dumps(data).decode()))
+    def _write_derived(self, table: str, sql: str, key: int, obj) -> None:
+        """Write a rounds / frames / blocks row through: ``obj.to_dict()``
+        and its ``canonical_dumps`` (the span ``store_encode``), then
+        ``_write``. Gated off during a bootstrap replay, which only notes
+        the key as recomputed — after the ``to_dict``, which a replay
+        builds for nothing (ROADMAP A2)."""
+        with self._span("store_encode"):
+            data = obj.to_dict()
+            if self._maintenance:
+                self._replayed.setdefault(table, set()).add(key)
+                return
+            row = canonical_dumps(data)
+            text = row.decode()
+        self._write(sql, (key, text))
+        self.encoded_bytes += len(row)
+        self.encoded_bytes_by_table[table] += len(row)
 
     # -- passthroughs to the cache -----------------------------------------
 
@@ -270,7 +283,7 @@ class PersistentStore:
         """Write through to the DB; returns True when the rows are new
         (vs. a re-set of an already-durable event)."""
         key = event.hex()
-        with self._write_span(), self._db_lock:
+        with self._span("store_write"), self._db_lock:
             if self._db is None:
                 raise StoreError(
                     "PersistentStore", StoreErrorKind.CLOSED, key
@@ -379,7 +392,7 @@ class PersistentStore:
         self._inmem.set_round(round_index, round_info)
         self._write_derived(
             "rounds", "INSERT OR REPLACE INTO rounds (idx, data) VALUES (?, ?)",
-            round_index, round_info.to_dict(),
+            round_index, round_info,
         )
 
     # -- blocks -------------------------------------------------------------
@@ -399,7 +412,7 @@ class PersistentStore:
         self._inmem.set_block(block)
         self._write_derived(
             "blocks", "INSERT OR REPLACE INTO blocks (idx, data) VALUES (?, ?)",
-            block.index(), block.to_dict(),
+            block.index(), block,
         )
 
     # -- frames -------------------------------------------------------------
@@ -420,7 +433,7 @@ class PersistentStore:
         self._inmem.set_frame(frame)
         self._write_derived(
             "frames", "INSERT OR REPLACE INTO frames (round, data) VALUES (?, ?)",
-            frame.round, frame.to_dict(),
+            frame.round, frame,
         )
 
     # -- bootstrap support ---------------------------------------------------
@@ -586,9 +599,9 @@ class PersistentStore:
 
     # -- helpers -------------------------------------------------------------
 
-    def _write_span(self):
+    def _span(self, stage: str):
         obs = self.stage_observer
-        return NULL_STAGE if obs is None else obs.span("store_write")
+        return NULL_STAGE if obs is None else obs.span(stage)
 
     def _fetch(self, sql: str, args: tuple) -> Optional[tuple]:
         self.db_reads += 1
@@ -604,7 +617,7 @@ class PersistentStore:
     def _write(self, sql: str, args: tuple) -> None:
         if self._maintenance:
             return
-        with self._write_span(), self._db_lock:
+        with self._span("store_write"), self._db_lock:
             if self._db is None:
                 # Same fail-closed policy as events: a silently dropped
                 # write leaves the durable history behind what this

@@ -461,6 +461,7 @@ class Node(StateManager):
             if self.trans is not None:
                 self.trans.close()
             self.core.hg.store.close()
+            self.telemetry.close()
 
     def suspend(self) -> None:
         """Stop gossiping but keep answering sync requests
@@ -698,11 +699,22 @@ class Node(StateManager):
                 "store_commits": getattr(store, "commits", 0),
                 "store_event_inserts": getattr(store, "event_inserts", 0),
                 "store_event_updates": getattr(store, "event_updates", 0),
+                # ... and the bytes of the round, frame and block rows it
+                # serialised and committed (none in a replay)
+                "store_encoded_bytes": getattr(store, "encoded_bytes", 0),
+                "store_encoded_bytes_by_table": dict(
+                    getattr(store, "encoded_bytes_by_table", {})
+                ),
                 "store_db_reads": getattr(store, "db_reads", 0),
                 "bootstrap_events_replayed":
                     self.core.hg.bootstrap_events_replayed,
                 "bootstrap_events_batch_verified":
                     self.core.hg.bootstrap_events_batch_verified,
+                # the collector's pauses charged to this node, by the span
+                # they interrupted (obs/gcwatch.py; empty unwatched)
+                "gc_pause_seconds": self.telemetry.gc.pause_seconds(),
+                "gc_collections_total":
+                    self.telemetry.gc.collections_by_generation(),
                 # fast-sync (0 on a validator that never lands): landings
                 # made and refused, the Frame events they inserted as
                 # trusted, the block signatures check_block verified

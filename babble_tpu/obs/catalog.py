@@ -45,9 +45,9 @@ CATALOG: Tuple[Instrument, ...] = (
         "decide_fame, round_received, commit, proxy_deliver, "
         "process_sig_pool, diff, eager_sync, mempool_drain, self_event, "
         "sync, prepare_sync, flush, record_heads, membership, "
-        "creator_stall, peer_set_wait, store_write, bootstrap, "
-        "bootstrap_load, fast_forward, ff_poll, ff_restore, ff_check, "
-        "ff_reset. Inclusive: a span's whole "
+        "creator_stall, peer_set_wait, store_write, store_encode, "
+        "bootstrap, bootstrap_load, fast_forward, ff_poll, ff_restore, "
+        "ff_check, ff_reset. Inclusive: a span's whole "
         "duration, its children's included.",
     ),
     Instrument(
@@ -423,6 +423,21 @@ CATALOG: Tuple[Instrument, ...] = (
         "insert.",
     ),
     Instrument(
+        "gc_pause_seconds", _C, ("stage",), "node",
+        "Seconds the garbage collector paused while charged to this node, "
+        "by the span it interrupted (the innermost span open on the "
+        "collecting thread; none where no span was open). A pause is "
+        "charged once, to one node (obs/gcwatch.py); /stats carries its "
+        "sum and count per stage. Empty on a simulated clock or with "
+        "BABBLE_OBS=0.",
+    ),
+    Instrument(
+        "gc_collections_total", _C, ("generation",), "node",
+        "Garbage collections charged to this node, by generation (0, 1, "
+        "2). Over every node of a process plus the watcher's process "
+        "tally they add up to gc.get_stats()' collections.",
+    ),
+    Instrument(
         "watchdog_trips_total", _C, (), "node",
         "Stall-watchdog trips (busy node, no consensus progress past "
         "the threshold).",
@@ -637,7 +652,7 @@ SYNC_STAGES = (
     "process_sig_pool", "diff", "eager_sync", "mempool_drain",
     "self_event", "sync", "prepare_sync", "flush", "record_heads",
     "membership", "creator_stall", "peer_set_wait",
-    "store_write", "bootstrap", "bootstrap_load",
+    "store_write", "store_encode", "bootstrap", "bootstrap_load",
     "fast_forward", "ff_poll", "ff_restore", "ff_check", "ff_reset",
 )
 # COARSE spans open at most a few times per sync: obs/trace.py also

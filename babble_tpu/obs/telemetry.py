@@ -11,7 +11,9 @@ extended by ``Node`` via ``bind_node``. It owns:
 - **function-backed instruments** over every subsystem's existing
   counters (core ingest_*, mempool, sentry, selector, accel, node RPC
   counters) — zero hot-path cost, evaluated at scrape;
-- the **tracer** (span ring served at ``/telemetry``);
+- the **tracer** (span ring served at ``/telemetry``), and the node's
+  ``GcTally``: the collector's pauses the process-wide watcher
+  (``obs/gcwatch.py``) charges to this node, by the span they interrupted;
 - the **legacy snapshot**: ``stats_snapshot()`` yields the typed
   ``get_stats`` payload (numbers stay numbers; ``Node.get_stats``
   stringifies at the edge — the compatibility contract recorded in
@@ -31,6 +33,7 @@ import time
 from typing import Dict, Optional
 
 from . import catalog
+from .gcwatch import WATCHER, GcTally
 from .metrics import (
     GLOBAL,
     LATENCY_BUCKETS,
@@ -88,6 +91,15 @@ class NodeTelemetry:
             cpu_sink=self._stage_sink(cpu_hist) if wall else None,
             owner=(v.moniker or v.public_key_hex()[:16]) if wall else None,
         )
+        # The collector's pauses charged to this node (obs/gcwatch.py):
+        # watched only when enabled and on the wall clock, so a simulated
+        # process never registers a gc callback.
+        self.gc = GcTally(self.tracer, (
+            f"{v.moniker or v.public_key_hex()[:16]}:",
+            f"{v.public_key_hex()}:",
+        ))
+        if self.enabled and wall:
+            WATCHER.add(self.gc)
         # Per-transaction commit provenance (docs/observability.md
         # §"Causal tracing"): admit/drain/first-seen/commit stamps keyed
         # by tx hash, deterministically sampled so every node traces the
@@ -105,11 +117,22 @@ class NodeTelemetry:
         )
 
         self._wire_core(core)
+        self._func(
+            "gc_pause_seconds",
+            lambda: {k: v["sum"] for k, v in self.gc.pause_seconds().items()},
+        )
+        self._func(
+            "gc_collections_total", self.gc.collections_by_generation
+        )
         self._wire_mempool(core.mempool)
         self._wire_sentry(core.sentry)
         self._wire_selector(core)
         if core.hg.accel is not None:
             self._wire_accel(core.hg.accel)
+
+    def close(self) -> None:
+        """Take this node's tally off the collector watcher (idempotent)."""
+        WATCHER.remove(self.gc)
 
     # -- registration helpers ----------------------------------------------
 

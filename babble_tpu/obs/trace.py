@@ -284,8 +284,6 @@ class Tracer:
         # runs in one process)
         self._ids = itertools.count(1)
         self.clock = clock
-        self.traces_started = 0
-        self.traces_finished = 0
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -299,7 +297,6 @@ class Tracer:
     def start(self, kind: str, peer_id: int) -> SyncTrace:
         tr = SyncTrace(self, kind, peer_id)
         self._thread().trace = tr
-        self.traces_started += 1
         return tr
 
     def active(self) -> Optional[SyncTrace]:
@@ -309,7 +306,6 @@ class Tracer:
         th = self._thread()
         if th.trace is tr:
             th.trace = None
-        self.traces_finished += 1
         self._ring.append(
             {
                 "id": tr.trace_id,

@@ -464,6 +464,11 @@ def test_the_store_spans_and_counters_reach_a_node_snapshot(tmp_path):
     assert snap["sync_stage_seconds.store_write.count"] == store.commits - 1
     assert 0 < snap["sync_stage_seconds.store_write.sum"] < (
         snap["sync_stage_seconds.sync.sum"])
+    # a derived row is serialised in `store_encode`, then written
+    assert snap["store_encoded_bytes"] == sum(
+        snap[f"store_encoded_bytes_by_table.{t}"] for t in DERIVED) > 0
+    assert 0 < snap["sync_stage_seconds.store_encode.count"] < (
+        snap["sync_stage_seconds.store_write.count"])
     assert snap["bootstrap_events_replayed"] == 0
     assert "sync_stage_seconds.bootstrap.count" not in snap
     node.shutdown()
@@ -487,8 +492,73 @@ def test_the_store_spans_and_counters_reach_a_node_snapshot(tmp_path):
     assert snap["store_commits"] == 1 and snap["store_db_reads"] == 0
     assert snap["store_event_inserts"] == snap["store_event_updates"] == 0
     assert "sync_stage_seconds.store_write.count" not in snap
+    # the replay's rounds, frames and blocks: built, never serialised
+    assert snap["sync_stage_seconds.store_encode.count"] > 0
+    assert snap["store_encoded_bytes"] == 0
     assert again.core.seq == n - EVENTS - 1
     again.shutdown()
+
+
+# the table a derived row's `_write` commits to, and its key column
+DERIVED = {"rounds": "idx", "frames": "round", "blocks": "idx"}
+
+
+@pytest.mark.parametrize("mode", ["host", "chip-lane"])
+def test_the_encoded_bytes_are_those_of_the_rows_committed(tmp_path, mode):
+    """``store_encoded_bytes_by_table`` against the rows ``_write``
+    committed, each read back from the file by a connection of its own
+    right after its commit (a round row is rewritten many times)."""
+    path = str(tmp_path / "babble.db")
+    keys, peers, wires, from_id = _backlog()
+    store = PersistentStore(10000, path)
+    core = _core(keys, peers, store, mode)
+    outside = sqlite3.connect(path)
+    committed = {table: [0, 0] for table in DERIVED}  # writes, bytes
+    write = store._write
+
+    def read_back(sql, args):
+        write(sql, args)
+        table = sql.split()[4]  # INSERT OR REPLACE INTO <table> ...
+        if table in DERIVED:
+            (n,) = outside.execute(
+                f"SELECT length(CAST(data AS BLOB)) FROM {table} "
+                f"WHERE {DERIVED[table]} = ?", (args[0],)).fetchone()
+            committed[table][0] += 1
+            committed[table][1] += n
+
+    store._write = read_back
+    _ingest(core, wires, from_id)
+    outside.close()
+    spans = core.obs.registry.snapshot()["sync_stage_seconds"]
+    store.close()
+    got = store.encoded_bytes_by_table
+    assert got == {table: b for table, (_w, b) in committed.items()}
+    assert store.encoded_bytes == sum(got.values())
+    assert all(b > 0 for b in got.values())
+    # a round row is the largest share, and written once a DivideRounds
+    # that changed it: about once an event
+    assert got["rounds"] > got["blocks"]
+    assert committed["rounds"][0] > EVENTS / 2
+    # one `store_encode` a derived row, beside its `store_write`
+    assert spans["store_encode"]["count"] == sum(
+        w for w, _b in committed.values())
+
+
+@LANES
+def test_a_replay_opens_store_encode_and_encodes_nothing(stopped, lane):
+    """The write gate shuts after the row's ``to_dict()``: the span opens
+    for each round, frame and block the replay sets, and no byte is
+    serialised or written."""
+    core, store = stopped.restart(lane)
+    core.bootstrap()
+    spans = core.obs.registry.snapshot()["sync_stage_seconds"]
+    store.close()
+    assert spans["store_encode"]["count"] >= (
+        stopped.db.row_counts()["blocks"] + stopped.db.row_counts()["rounds"])
+    assert spans["store_encode"]["sum"] > 0
+    assert "store_write" not in spans
+    assert store.encoded_bytes == 0
+    assert store.encoded_bytes_by_table == dict.fromkeys(DERIVED, 0)
 
 
 def test_a_validator_with_an_inmem_store_opens_none_of_it():
@@ -499,7 +569,8 @@ def test_a_validator_with_an_inmem_store_opens_none_of_it():
     assert snap["store_event_inserts"] == snap["store_event_updates"] == 0
     assert snap["bootstrap_events_replayed"] == 0
     assert snap["bootstrap_events_batch_verified"] == 0
+    assert snap["store_encoded_bytes"] == 0
     assert not [k for k in snap if "store_write" in k or "bootstrap." in k
-                or "bootstrap_load" in k]
+                or "bootstrap_load" in k or ".store_encode." in k]
     assert snap["sync_stage_seconds.insert.count"] > EVENTS
     node.shutdown()

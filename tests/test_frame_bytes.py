@@ -381,8 +381,8 @@ def test_the_reuse_metric_reads_the_two_counters(counters, want):
     from benchmark.harness import layer, spec
 
     cell = spec.resolve_cell(spec.load_benchmark(), "catchup16.backlog8k")
-    entry = cell.per_layer[-1]
-    assert entry["name"] == "frame_event_reuse_pct.catchup"
+    entry = next(m for m in cell.per_layer
+                 if m["name"] == "frame_event_reuse_pct.catchup")
     assert entry["layer"] == "apply + commit"
     assert entry["moves"] == "catchup_events_per_s"
     got = layer.evaluate(
