@@ -22,13 +22,17 @@ import json
 import os
 import sqlite3
 import threading
-from typing import Dict, List, Optional
+from bisect import bisect_left
+from json.encoder import encode_basestring_ascii
+from operator import attrgetter
+from typing import Dict, List, NamedTuple, Optional
 
 from babble_tpu.common.errors import StoreError, StoreErrorKind
 from babble_tpu.crypto.canonical import (
     PreNormalized,
     canonical_dumps,
     canonical_loads,
+    canonical_scalar,
 )
 from babble_tpu.hashgraph.block import Block
 from babble_tpu.hashgraph.event import Event, EventBody
@@ -64,6 +68,33 @@ CREATE TABLE IF NOT EXISTS peer_sets (round INTEGER PRIMARY KEY, data TEXT NOT N
 CREATE TABLE IF NOT EXISTS roots (participant TEXT PRIMARY KEY, data TEXT NOT NULL);
 CREATE TABLE IF NOT EXISTS evidence (key TEXT PRIMARY KEY, data TEXT NOT NULL);
 """
+
+# A round's row is `canonical_dumps(round_info.to_dict())`; the store keeps
+# on the RoundInfo (``store_row``) what it encoded that row from, and encodes
+# the next one from the entries that changed since (``_encode_round``).
+_ENTRY_STATE = attrgetter("witness", "famous")
+
+
+class _RoundRow(NamedTuple):
+    keys: list  # created events, in the dict's order
+    states: list  # their (witness, famous), in the same order
+    sorted_keys: list  # the same keys, in the row's (sorted) order
+    fragments: list  # '"<hash>":{"Famous":f,"Witness":w}', in that order
+    created_text: str  # the fragments joined
+    received: list  # the received events encoded
+    received_text: str  # ... joined
+
+
+_EMPTY_ROW = _RoundRow([], [], [], [], "", [], "")
+
+
+def _entry_fragment(key: str, state: tuple) -> str:
+    """One created event as ``canonical_dumps`` writes it inside the row."""
+    witness, famous = state
+    return (
+        f'{encode_basestring_ascii(key)}:{{"Famous":{int(famous)},'
+        f'"Witness":{canonical_scalar(witness).decode()}}}'
+    )
 
 
 class PersistentStore:
@@ -122,6 +153,10 @@ class PersistentStore:
         # bytes of the derived rows serialised and committed, and by table
         self.encoded_bytes = 0
         self.encoded_bytes_by_table = {"rounds": 0, "frames": 0, "blocks": 0}
+        # of the round rows' entries (created and received events), those
+        # taken from the kept state and those encoded anew (_encode_round)
+        self.round_entries_reused = 0
+        self.round_entries_encoded = 0
         # NOTE: persisted peer-sets are deliberately NOT preloaded into the
         # interval cache. The reference's design comment
         # (badger_store.go:109-118) applies verbatim: membership state must
@@ -143,22 +178,84 @@ class PersistentStore:
             return None
         return self._fetch(sql, (key,))
 
-    def _write_derived(self, table: str, sql: str, key: int, obj) -> None:
-        """Write a rounds / frames / blocks row through: ``obj.to_dict()``
-        and its ``canonical_dumps`` (the span ``store_encode``), then
-        ``_write``. Gated off during a bootstrap replay, which only notes
-        the key as recomputed — after the ``to_dict``, which a replay
-        builds for nothing (ROADMAP A2)."""
+    def _write_derived(
+        self, table: str, sql: str, key: int, obj, encode=None
+    ) -> None:
+        """Write a rounds / frames / blocks row through: its encoding (the
+        span ``store_encode``; ``canonical_dumps(obj.to_dict())`` unless
+        ``encode`` is given), then ``_write``. Gated off during a bootstrap
+        replay, which only notes the key as recomputed and builds nothing."""
         with self._span("store_encode"):
-            data = obj.to_dict()
             if self._maintenance:
                 self._replayed.setdefault(table, set()).add(key)
                 return
-            row = canonical_dumps(data)
-            text = row.decode()
+            if encode is None:
+                text = canonical_dumps(obj.to_dict()).decode()
+            else:
+                text = encode(obj)
         self._write(sql, (key, text))
-        self.encoded_bytes += len(row)
-        self.encoded_bytes_by_table[table] += len(row)
+        # canonical JSON is ASCII: a character is a byte
+        self.encoded_bytes += len(text)
+        self.encoded_bytes_by_table[table] += len(text)
+
+    def _encode_round(self, ri: RoundInfo) -> str:
+        """``canonical_dumps(ri.to_dict())``, byte for byte, from what
+        ``ri.store_row`` kept of the last encoding: a created event is
+        encoded again only if it is new or its (witness, famous) differs
+        from the kept state, a received event only past the kept prefix.
+        Where the round no longer extends what was kept (a created key
+        gone, a received list that is not the kept prefix followed by
+        more), that part is encoded from nothing."""
+        created = ri.created_events
+        keys = list(created)
+        states = list(map(_ENTRY_STATE, created.values()))
+        received = list(ri.received_events)
+        kept = ri.store_row or _EMPTY_ROW
+        n, kept_states = len(kept.keys), kept.states
+        if keys[:n] != kept.keys:
+            n, kept_states = 0, []
+        sorted_keys, fragments = kept.sorted_keys, kept.fragments
+        created_text = kept.created_text
+        changed = ()
+        if states[:n] != kept_states:
+            changed = [
+                i for i, state in enumerate(kept_states) if states[i] != state
+            ]
+        encoded = len(keys) - n + len(changed)
+        if n == 0:
+            order = sorted(range(len(keys)), key=keys.__getitem__)
+            sorted_keys = [keys[i] for i in order]
+            fragments = [_entry_fragment(keys[i], states[i]) for i in order]
+        elif encoded:
+            # copies, so that the kept state is always one whole encoding
+            sorted_keys, fragments = sorted_keys[:], fragments[:]
+            for i in changed:
+                at = bisect_left(sorted_keys, keys[i])
+                fragments[at] = _entry_fragment(keys[i], states[i])
+            for i in range(n, len(keys)):
+                at = bisect_left(sorted_keys, keys[i])
+                sorted_keys.insert(at, keys[i])
+                fragments.insert(at, _entry_fragment(keys[i], states[i]))
+        if encoded:
+            created_text = ",".join(fragments)
+        m = len(kept.received)
+        received_text = kept.received_text
+        if received[:m] != kept.received:
+            m, received_text = 0, ""
+        if len(received) > m:
+            more = canonical_dumps(received[m:]).decode()[1:-1]
+            received_text = received_text + "," + more if m else more
+            encoded += len(received) - m
+        ri.store_row = _RoundRow(
+            keys, states, sorted_keys, fragments, created_text,
+            received, received_text,
+        )
+        self.round_entries_encoded += encoded
+        self.round_entries_reused += len(keys) + len(received) - encoded
+        return (
+            f'{{"CreatedEvents":{{{created_text}}},'
+            f'"ReceivedEvents":[{received_text}]}}'
+        )
 
     # -- passthroughs to the cache -----------------------------------------
 
@@ -392,7 +489,7 @@ class PersistentStore:
         self._inmem.set_round(round_index, round_info)
         self._write_derived(
             "rounds", "INSERT OR REPLACE INTO rounds (idx, data) VALUES (?, ?)",
-            round_index, round_info,
+            round_index, round_info, self._encode_round,
         )
 
     # -- blocks -------------------------------------------------------------

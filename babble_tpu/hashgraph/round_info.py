@@ -23,6 +23,11 @@ class RoundInfo:
     decided it stays decided even if new witnesses appear later
     (roundInfo.go:73-96)."""
 
+    # What a PersistentStore last encoded this round's row from (its
+    # ``_RoundRow``); written by the store's encoder alone, never by the
+    # mutators below, so a round no durable store writes carries none.
+    store_row = None
+
     def __init__(self) -> None:
         self.created_events: Dict[str, RoundEvent] = {}
         self.received_events: List[str] = []

@@ -705,6 +705,12 @@ class Node(StateManager):
                 "store_encoded_bytes_by_table": dict(
                     getattr(store, "encoded_bytes_by_table", {})
                 ),
+                # ... of their round rows' entries, those taken from what the
+                # store kept of the row's last encoding and those encoded anew
+                "store_round_entries_reused":
+                    getattr(store, "round_entries_reused", 0),
+                "store_round_entries_encoded":
+                    getattr(store, "round_entries_encoded", 0),
                 "store_db_reads": getattr(store, "db_reads", 0),
                 "bootstrap_events_replayed":
                     self.core.hg.bootstrap_events_replayed,

@@ -34,7 +34,10 @@ import pytest
 from babble_tpu import native_crypto
 from babble_tpu.common.errors import StoreError
 from babble_tpu.crypto import batch as sig_batch
+from babble_tpu.crypto.canonical import canonical_dumps
 from babble_tpu.hashgraph import InmemStore
+from babble_tpu.hashgraph.block import Block
+from babble_tpu.hashgraph.frame import Frame
 from babble_tpu.hashgraph.errors import InvalidSignatureError
 from babble_tpu.hashgraph.persistent_store import PersistentStore
 from babble_tpu.hashgraph.round_info import RoundInfo
@@ -467,6 +470,9 @@ def test_the_store_spans_and_counters_reach_a_node_snapshot(tmp_path):
     # a derived row is serialised in `store_encode`, then written
     assert snap["store_encoded_bytes"] == sum(
         snap[f"store_encoded_bytes_by_table.{t}"] for t in DERIVED) > 0
+    # most of a round row's entries come from its last encoding
+    assert snap["store_round_entries_reused"] > (
+        snap["store_round_entries_encoded"]) > 0
     assert 0 < snap["sync_stage_seconds.store_encode.count"] < (
         snap["sync_stage_seconds.store_write.count"])
     assert snap["bootstrap_events_replayed"] == 0
@@ -492,9 +498,11 @@ def test_the_store_spans_and_counters_reach_a_node_snapshot(tmp_path):
     assert snap["store_commits"] == 1 and snap["store_db_reads"] == 0
     assert snap["store_event_inserts"] == snap["store_event_updates"] == 0
     assert "sync_stage_seconds.store_write.count" not in snap
-    # the replay's rounds, frames and blocks: built, never serialised
+    # the replay's rounds, frames and blocks: noted, never built
     assert snap["sync_stage_seconds.store_encode.count"] > 0
     assert snap["store_encoded_bytes"] == 0
+    assert snap["store_round_entries_reused"] == 0
+    assert snap["store_round_entries_encoded"] == 0
     assert again.core.seq == n - EVENTS - 1
     again.shutdown()
 
@@ -546,9 +554,9 @@ def test_the_encoded_bytes_are_those_of_the_rows_committed(tmp_path, mode):
 
 @LANES
 def test_a_replay_opens_store_encode_and_encodes_nothing(stopped, lane):
-    """The write gate shuts after the row's ``to_dict()``: the span opens
-    for each round, frame and block the replay sets, and no byte is
-    serialised or written."""
+    """The write gate shuts before anything is built: the span opens for
+    each round, frame and block the replay sets, around the note of its
+    key, and no byte is serialised or written."""
     core, store = stopped.restart(lane)
     core.bootstrap()
     spans = core.obs.registry.snapshot()["sync_stage_seconds"]
@@ -561,6 +569,69 @@ def test_a_replay_opens_store_encode_and_encodes_nothing(stopped, lane):
     assert store.encoded_bytes_by_table == dict.fromkeys(DERIVED, 0)
 
 
+@pytest.mark.parametrize("mode", ["host", "chip-lane"])
+def test_every_derived_row_is_the_plain_encoding_and_a_replay_builds_none(
+        tmp_path, mode, monkeypatch):
+    """Every rounds / frames / blocks row an ingest commits equals
+    ``canonical_dumps(obj.to_dict())`` of the object it was set from, as the
+    parent encoded it, and ``store_encoded_bytes`` adds up the same bytes;
+    most of a round row's entries are taken from its last encoding. Then a
+    replay of the file builds no row's ``to_dict()`` behind the shut write
+    gate and leaves the file as it found it."""
+    path = str(tmp_path / "babble.db")
+    keys, peers, wires, from_id = _backlog()
+    store = PersistentStore(10000, path)
+    outside = sqlite3.connect(path)
+    plain = {table: [0, 0, 0] for table in DERIVED}  # rows, bytes, differing
+    entries = [0]
+    write_derived = store._write_derived
+
+    def beside_the_parent(table, sql, key, obj, *encode):
+        want = canonical_dumps(obj.to_dict())
+        write_derived(table, sql, key, obj, *encode)
+        (row,) = outside.execute(
+            f"SELECT data FROM {table} WHERE {DERIVED[table]} = ?",
+            (key,)).fetchone()
+        tally = plain[table]
+        tally[0] += 1
+        tally[1] += len(want)
+        tally[2] += row.encode() != want
+        if table == "rounds":
+            entries[0] += len(obj.created_events) + len(obj.received_events)
+
+    store._write_derived = beside_the_parent
+    _ingest(_core(keys, peers, store, mode), wires, from_id)
+    outside.close()
+    assert all(rows > 0 and differing == 0
+               for rows, _b, differing in plain.values())
+    assert store.encoded_bytes_by_table == {
+        table: b for table, (_r, b, _d) in plain.items()}
+    reused, encoded = store.round_entries_reused, store.round_entries_encoded
+    assert reused + encoded == entries[0]
+    assert reused > 2 * encoded  # rounds of ≈ 15 events here, ≈ 100 in the cell
+    before = durable.read(path)
+    store.close()
+
+    store = PersistentStore(10000, path)
+    core = _core(keys, peers, store, mode)
+    built = []
+    for cls in (RoundInfo, Frame, Block):
+        def counted(obj, _to_dict=cls.to_dict, _name=cls.__name__):
+            if store._maintenance:
+                built.append(_name)
+            return _to_dict(obj)
+        monkeypatch.setattr(cls, "to_dict", counted)
+    core.bootstrap()
+    spans = core.obs.registry.snapshot()["sync_stage_seconds"]
+    after = durable.read(path)
+    store.close()
+    assert built == []
+    assert durable.rows_changed(before, after) == 0
+    assert spans["store_encode"]["count"] >= before.row_counts()["rounds"]
+    assert store.round_entries_reused == store.round_entries_encoded == 0
+    assert store.encoded_bytes == 0
+
+
 def test_a_validator_with_an_inmem_store_opens_none_of_it():
     node, wires, from_id = _node(InmemStore(10000))
     _ingest(node.core, wires, from_id)
@@ -570,6 +641,8 @@ def test_a_validator_with_an_inmem_store_opens_none_of_it():
     assert snap["bootstrap_events_replayed"] == 0
     assert snap["bootstrap_events_batch_verified"] == 0
     assert snap["store_encoded_bytes"] == 0
+    assert snap["store_round_entries_reused"] == 0
+    assert snap["store_round_entries_encoded"] == 0
     assert not [k for k in snap if "store_write" in k or "bootstrap." in k
                 or "bootstrap_load" in k or ".store_encode." in k]
     assert snap["sync_stage_seconds.insert.count"] > EVENTS

@@ -9,6 +9,7 @@ kill-all/recycle/resume)."""
 from __future__ import annotations
 
 import json
+import random
 import shutil
 import sqlite3
 import time
@@ -594,4 +595,71 @@ def test_a_file_written_before_the_columns_opens_reloads_and_replays(tmp_path):
     core.bootstrap()
     assert durable.state_of(core.hg) == want
     assert core.hg.bootstrap_events_replayed == len(written)
+    store.close()
+
+
+def _round_key(rng):
+    """A created event's key: an event hash as the hashgraph writes it, now
+    and then one that JSON escapes or that sorts unlike its bytes."""
+    if rng.random() < 0.9:
+        return "0X%064X" % rng.getrandbits(256)
+    return rng.choice(['0X"q"', "0X\\b", "0X\u00e9", "0x\u2603", "0X\n", "0Xa"]) + (
+        "%x" % rng.getrandbits(16))
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_a_round_row_is_the_plain_encoding_after_every_set_round(
+        tmp_path, seed):
+    """Random sequences of a round's mutators, with rounds rebuilt by
+    ``from_dict``, received lists cut or replaced and created entries
+    dropped or altered in place between writes: after
+    every ``set_round`` the row in the file is ``canonical_dumps(to_dict())``
+    byte for byte, and every entry written was either reused or encoded."""
+    rng = random.Random(seed)
+    path = str(tmp_path / "s.db")
+    store = PersistentStore(cache_size=4, path=path)
+    outside = sqlite3.connect(path)
+    rounds = {r: RoundInfo() for r in range(3)}
+    seen = {r: [] for r in rounds}  # keys a round was ever given
+    entries = writes = 0
+    for _step in range(300):
+        r = rng.randrange(len(rounds))
+        ri, keys = rounds[r], seen[r]
+        op = rng.random()
+        if op < 0.3 or not keys:
+            key = rng.choice(keys) if keys and rng.random() < 0.2 else (
+                _round_key(rng))  # first write wins on a known key
+            ri.add_created_event(key, rng.random() < 0.3)
+            keys.append(key)
+        elif op < 0.45:
+            if rng.random() < 0.2:  # a key the round never created
+                keys.append(_round_key(rng))
+            ri.set_fame(rng.choice(keys), rng.random() < 0.5)
+        elif op < 0.6:
+            ri.add_received_event(rng.choice(keys))
+        elif op < 0.64:
+            rounds[r] = RoundInfo.from_dict(json.loads(canonical_dumps(
+                ri.to_dict())))
+        elif op < 0.67:
+            del ri.received_events[rng.randrange(len(ri.received_events) + 1):]
+        elif op < 0.69:
+            ri.received_events = list(reversed(ri.received_events))
+        elif op < 0.71 and ri.created_events:  # edits no mutator makes
+            key = rng.choice(list(ri.created_events))
+            if rng.random() < 0.5:
+                del ri.created_events[key]
+            else:
+                ri.created_events[key].witness ^= True
+        else:
+            store.set_round(r, ri)
+            writes += 1
+            entries += len(ri.created_events) + len(ri.received_events)
+            (row,) = outside.execute(
+                "SELECT data FROM rounds WHERE idx = ?", (r,)).fetchone()
+            assert row.encode() == canonical_dumps(ri.to_dict())
+    outside.close()
+    assert writes > 50
+    assert store.round_entries_reused + store.round_entries_encoded == entries
+    assert store.round_entries_reused > store.round_entries_encoded > 0
+    assert store.encoded_bytes == store.encoded_bytes_by_table["rounds"]
     store.close()
