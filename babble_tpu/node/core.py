@@ -358,8 +358,11 @@ class Core:
         self.ingest_batch_verifies += 1
         if len(decoded) > self.ingest_batch_size_max:
             self.ingest_batch_size_max = len(decoded)
-        for ev in decoded:
-            if ev.prevalidated() is False:
+        flagged = [ev for ev in decoded if ev.prevalidated() is False]
+        if not flagged:
+            return
+        with self._span("verify_fallback"):
+            for ev in flagged:
                 ev.clear_prevalidation()
                 ev.prevalidate(ev.verify())
                 self.ingest_fallback_singles += 1

@@ -1420,7 +1420,10 @@ class Node(StateManager):
         if isinstance(cmd, SyncRequest):
             self._process_sync_request(rpc, cmd)
         elif isinstance(cmd, EagerSyncRequest):
-            self._process_eager_sync_request(rpc, cmd)
+            # root span: inline the whole sync; pipelined its stage 1, the
+            # insert tail being the inserter thread's own `sync`
+            with self.core._span("eager_sync_in"):
+                self._process_eager_sync_request(rpc, cmd)
         elif isinstance(cmd, FastForwardRequest):
             self._process_fast_forward_request(rpc, cmd)
         elif isinstance(cmd, JoinRequest):
