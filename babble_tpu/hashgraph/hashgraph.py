@@ -1714,8 +1714,10 @@ class Hashgraph:
         insert: it caches every event's verdict, so insert_event's
         verify() is a cache hit. The inserts stay sequential — an event
         with a bad signature is refused at ITS insert, the batch's earlier
-        events in and none after it. Without one each event is verified
-        alone at its insert."""
+        events in and none after it; so the verifier's re-checks of what
+        its batch call flagged may stop at the first event they confirm
+        bad, leaving the later flagged ones uncached. Without one each
+        event is verified alone at its insert."""
         topo = getattr(self.store, "topological_events", None)
         if topo is None:
             return

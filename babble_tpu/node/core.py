@@ -128,11 +128,14 @@ class Core:
         # Node.get_stats): on the happy path every incoming sync costs
         # exactly ONE native batch-verify call, and fallback_singles
         # counts the per-event scalar re-checks that pinpoint offenders
-        # after a batch reported failures.
+        # after a batch reported failures; fallback_skipped counts the
+        # flagged events left to insert's own verify because an earlier
+        # one of the same batch was confirmed bad.
         self.ingest_syncs = 0
         self.ingest_batch_verifies = 0
         self.ingest_batch_size_max = 0
         self.ingest_fallback_singles = 0
+        self.ingest_fallback_skipped = 0
         # Membership: accepted PEER_ADD / PEER_REMOVE requests applied, and
         # syncs that stalled on an event whose creator the repertoire did
         # not hold yet (resolved by draining voting, see sync).
@@ -323,11 +326,18 @@ class Core:
     @staged("batch_verify")
     def _batch_prevalidate(self, decoded: List[Event]) -> None:
         """Verify a decoded chunk's signatures in one batch call, then
-        pinpoint offenders: events the batch flagged are re-checked
-        through the scalar verifier one by one, so a batch-layer artifact
-        can never reject a valid event and a genuinely bad event is
-        identified exactly (its verdict stays cached for insert to
-        reject)."""
+        pinpoint the first offender: events the batch flagged are
+        re-checked through the scalar verifier in decode order, so a
+        batch-layer artifact can never reject a valid event and a
+        genuinely bad event is identified exactly (its verdict stays
+        cached for insert to reject).
+
+        The re-checks stop at the first event confirmed bad. Every caller
+        inserts in decode order and stops at that event's refusal
+        (Core.sync, Hashgraph.bootstrap), so a verdict after it would
+        never be read. The flagged events after it lose the batch's
+        verdict instead: should one ever reach insert, it is verified
+        there alone, and nothing is judged by the batch's False."""
         use_device_verify = self.accelerated_verify
         if use_device_verify:
             # The device ladder kernel is dispatch/loop-bound (no
@@ -362,10 +372,17 @@ class Core:
         if not flagged:
             return
         with self._span("verify_fallback"):
-            for ev in flagged:
+            for k, ev in enumerate(flagged):
                 ev.clear_prevalidation()
-                ev.prevalidate(ev.verify())
+                ok = ev.verify()
+                ev.prevalidate(ok)
                 self.ingest_fallback_singles += 1
+                if not ok:
+                    rest = flagged[k + 1 :]
+                    for later in rest:
+                        later.clear_prevalidation()
+                    self.ingest_fallback_skipped += len(rest)
+                    break
 
     @staged("sync")
     def sync(

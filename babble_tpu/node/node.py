@@ -650,6 +650,7 @@ class Node(StateManager):
                 "ingest_batch_verifies": self.core.ingest_batch_verifies,
                 "ingest_batch_size_max": self.core.ingest_batch_size_max,
                 "ingest_fallback_singles": self.core.ingest_fallback_singles,
+                "ingest_fallback_skipped": self.core.ingest_fallback_skipped,
                 "lock_wait_ms_total": round(
                     self.core_lock.wait_ms_total(), 1
                 ),

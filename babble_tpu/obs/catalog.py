@@ -103,6 +103,12 @@ CATALOG: Tuple[Instrument, ...] = (
         "Per-event scalar signature re-checks after a batch reported "
         "failures (offender pinpointing).",
     ),
+    Instrument(
+        "ingest_fallback_skipped_total", _C, (), "node",
+        "Flagged events left unchecked by the re-checks because an earlier "
+        "flagged event of the same batch was confirmed bad (insert "
+        "verifies them alone, should one ever reach it).",
+    ),
     # -- gossip / RPC surface ----------------------------------------------
     Instrument(
         "sync_requests_total", _C, (), "node",

@@ -210,6 +210,10 @@ class NodeTelemetry:
             lambda: core.ingest_fallback_singles,
         )
         self._func(
+            "ingest_fallback_skipped_total",
+            lambda: core.ingest_fallback_skipped,
+        )
+        self._func(
             "node_last_block_index", lambda: core.get_last_block_index()
         )
         self._func(

@@ -424,6 +424,7 @@ def test_a_row_altered_from_outside_stops_the_replay_where_it_stands(
     assert core.hg.bootstrap_events_replayed == k - k % 100  # whole batches
     # the batch flagged it; the scalar verifier had the last word
     assert core.ingest_fallback_singles == (1 if lane == "batch" else 0)
+    assert core.ingest_fallback_skipped == 0
     assert durable.rows_changed(found, durable.read(path)) == 0
     store.close()
 

@@ -111,6 +111,7 @@ class _Flooded:
             self.deferrals = sentry.quarantine_deferrals
             self.refused = sentry.refused_rpcs
             self.singles = core.ingest_fallback_singles
+            self.skipped = core.ingest_fallback_skipped
             self.spans = {s: _stage_count(node, s) for s in (
                 "eager_sync_in", "verify_fallback", "batch_verify")}
             self.observed = tracer is not None
@@ -156,11 +157,13 @@ def test_the_blocks_equal_the_host_oracles(flooded):
 
 def test_the_two_spans_open_once_a_landed_push(flooded):
     # each landed push: one batch whose every event the batch call flagged,
-    # re-checked alone; honest batches open no verify_fallback
+    # its first re-checked alone and confirmed bad, the rest left unchecked;
+    # honest batches open no verify_fallback
     assert flooded.spans["eager_sync_in"] == 5
     assert flooded.spans["verify_fallback"] == 5
     assert flooded.spans["batch_verify"] == EVENTS // SYNC + 5
-    assert flooded.singles == 5 * PUSHED
+    assert flooded.singles == 5
+    assert flooded.skipped == 5 * (PUSHED - 1)
 
 
 def test_the_insert_tail_runs_where_the_docs_say(flooded, request):

@@ -14,6 +14,7 @@ import pytest
 from babble_tpu.common.timed_lock import TimedLock
 from babble_tpu.crypto import batch as host_batch
 from babble_tpu.crypto.keys import generate_key
+from babble_tpu.hashgraph.errors import InvalidSignatureError
 from babble_tpu.hashgraph.event import WIRE_CACHE, Event, WireEvent
 
 from tests.test_core import init_cores
@@ -109,6 +110,133 @@ def test_batch_artifact_cannot_reject_valid_event():
         cores[1].known_events()[cores[0].validator.id()]
         == diff[-1].index()
     )
+
+
+# -- the re-checks stop at the first event confirmed bad ------------------
+
+
+def _chain_wires(cores, extra):
+    """Core 0's initial event and ``extra`` chained self-events, as the
+    wire events core 1 does not know yet."""
+    for _ in range(extra):
+        cores[0].add_self_event("")
+    diff = cores[0].event_diff(cores[1].known_events())
+    # copies: to_wire() memoizes, mutating in place would poison core 0
+    return diff, [WireEvent(body=w.body, signature=w.signature)
+                  for w in cores[0].to_wire(diff)]
+
+
+def _forge(wires, positions):
+    for i in positions:
+        wires[i] = WireEvent(body=wires[i].body, signature="1|1")
+
+
+def _sync_refused(core, from_id, wires):
+    """Sync ``wires`` into ``core`` through a prepared stage the test can
+    read; returns the decoded events and the refusal."""
+    prepared = core.prepare_sync(wires)
+    with pytest.raises(InvalidSignatureError) as refused:
+        core.sync(from_id, wires, prepared)
+    return prepared.decoded, refused.value
+
+
+@needs_native
+@pytest.mark.parametrize("forged", [1, 2, 6])
+def test_a_forged_run_is_rechecked_once_and_the_rest_skipped(forged):
+    cores, _, _ = init_cores(2)
+    prefix = 2
+    diff, wires = _chain_wires(cores, prefix + forged - 1)
+    assert len(wires) == prefix + forged
+    _forge(wires, range(prefix, len(wires)))
+
+    singles = cores[1].ingest_fallback_singles
+    skipped = cores[1].ingest_fallback_skipped
+    decoded, refused = _sync_refused(cores[1], cores[0].validator.id(), wires)
+    assert refused.event is decoded[prefix]
+    assert cores[1].ingest_fallback_singles == singles + 1
+    assert cores[1].ingest_fallback_skipped == skipped + forged - 1
+    # the prefix kept the batch's verdict, the re-checked event its own,
+    # the later flagged events none
+    assert [ev.prevalidated() for ev in decoded] == (
+        [True] * prefix + [False] + [None] * (forged - 1))
+    # the valid prefix inserted, nothing after it
+    assert (
+        cores[1].known_events()[cores[0].validator.id()]
+        == diff[prefix - 1].index()
+    )
+
+
+@needs_native
+def test_a_batch_artifact_before_a_bad_event_still_rejects_no_valid_one():
+    """A planted batch verdict flags every event; the third is really bad.
+    The first two are re-checked and inserted, the third refused, the
+    rest left to insert's own verify — and a valid one of them, handed to
+    insert once its parent is in, lands."""
+    cores, _, _ = init_cores(2)
+    diff, wires = _chain_wires(cores, 4)
+    assert len(wires) == 5
+    genuine = wires[2]
+    _forge(wires, [2])
+
+    orig = host_batch.prevalidate_events_host
+
+    def all_flagged(events):
+        for ev in events:
+            ev.prevalidate(False)
+        return True
+
+    singles = cores[1].ingest_fallback_singles
+    skipped = cores[1].ingest_fallback_skipped
+    host_batch.prevalidate_events_host = all_flagged
+    try:
+        decoded, refused = _sync_refused(
+            cores[1], cores[0].validator.id(), wires)
+    finally:
+        host_batch.prevalidate_events_host = orig
+    assert refused.event is decoded[2]
+    assert cores[1].ingest_fallback_singles == singles + 3
+    assert cores[1].ingest_fallback_skipped == skipped + 2
+    assert [ev.prevalidated() for ev in decoded] == [
+        True, True, False, None, None]
+    assert (
+        cores[1].known_events()[cores[0].validator.id()] == diff[1].index()
+    )
+
+    # the genuine third event, then the fourth as the sync decoded it: the
+    # batch's False on it was dropped, so the scalar verifier admits it
+    cores[1].insert_event_and_run_consensus(
+        cores[1].hg.read_wire_info(genuine), set_wire_info=False)
+    cores[1].insert_event_and_run_consensus(decoded[3], set_wire_info=False)
+    assert (
+        cores[1].known_events()[cores[0].validator.id()] == diff[3].index()
+    )
+
+
+@needs_native
+def test_a_skipped_event_handed_to_insert_alone_is_refused_by_the_scalar_path(
+        monkeypatch):
+    from babble_tpu import native_crypto
+
+    cores, _, _ = init_cores(2)
+    _diff, wires = _chain_wires(cores, 3)
+    _forge(wires, [1, 2, 3])
+    decoded, _ = _sync_refused(cores[1], cores[0].validator.id(), wires)
+    skipped = decoded[2]
+    assert skipped.prevalidated() is None
+
+    calls = []
+    verify_one = native_crypto.verify_one
+
+    def counted(*args):
+        calls.append(args)
+        return verify_one(*args)
+
+    monkeypatch.setattr(native_crypto, "verify_one", counted)
+    with pytest.raises(InvalidSignatureError) as refused:
+        cores[1].hg.insert_event(skipped)
+    assert refused.value.event is skipped
+    assert len(calls) == 1  # one scalar verification, of its signature
+    assert skipped.prevalidated() is None
 
 
 # -- verification happens OUTSIDE the core lock ---------------------------
