@@ -1183,6 +1183,10 @@ class TensorConsensus:
             # moved P or S (process-wide, see _follow_peer_axes)
             "accel_variant_compiles": variant_compiles,
             "accel_variant_backlog": variant_backlog(),
+            # programs prewarm_buckets compiled or loaded, and the seconds
+            # its passes took (process-wide, summed over prewarm threads)
+            "accel_prewarm_programs": prewarm_programs,
+            "accel_prewarm_seconds": round(prewarm_seconds, 6),
             "accel_stale_drops": self.stale_drops,
             # Mesh padding visibility: witness rows added to align W to
             # the mesh, and windows that dropped to single-device anyway
@@ -1227,50 +1231,112 @@ def batcher_default_on() -> bool:
     return on_accelerator()
 
 
-def prewarm_buckets(n_peers: int, background: bool = True, mesh=None):
-    """Compile (or load from the persistent XLA cache) the window-shape
-    buckets a freshly started node is most likely to hit, so the first
-    real backlog meets warm kernels instead of a compile wait. Called from
-    Node.init when --accelerator is on; runs in a daemon thread by default
-    (compiles happen in XLA's C++ with the GIL released). With a mesh,
-    the SHARDED kernels are warmed too (separate jit cache)."""
+# Process-wide tallies of prewarm_buckets' work: programs compiled (or
+# loaded from the persistent cache) and the seconds its passes took,
+# summed over every prewarm thread (co-located nodes run one each, so
+# the seconds can exceed the wall time); updated under _prewarm_lock.
+prewarm_programs = 0
+prewarm_seconds = 0.0
+_prewarm_lock = threading.Lock()
+
+#: Validators a ring of the legacy list was sized for: at or below it
+#: prewarm_keys returns the list the 16-validator cells were tuned on, and
+#: prewarm_buckets seeds the batcher's floor.
+_LEGACY_PEERS = 16
+
+
+def prewarm_keys(n_peers: int) -> list:
+    """The single-window buckets (W, E, P, S, R) prewarm_buckets compiles
+    for a ring of ``n_peers`` validators at one peer-set slot.
+
+    Up to 16 validators it is the list the 16-validator cells were tuned
+    on. Above, it scales with the ring: a round holds one witness per
+    validator and, in random gossip, about 11 events per validator (700 at
+    63 creators). A window of k rounds of witnesses takes W = k·n rounded
+    up to a power of two, for k = 2, 4, 8, 16; it holds between a third and
+    all of those rounds' events undetermined, E = 4W or 8W; and spans R 8
+    up to 4 rounds, R 8 or 16 at 8, R 16 or 32 at 16 (a rebuild's slack of
+    two rounds included)."""
     from babble_tpu.ops import voting
 
     P = voting._bucket_mult(n_peers, 8)
     S = 1
-    buckets = [
-        (16, 32, P, S, 8),
-        (16, 64, P, S, 8),
-        (32, 128, P, S, 8),
-        (64, 256, P, S, 8),
-        (64, 256, P, S, 16),
-        (64, 512, P, S, 16),
-        (128, 512, P, S, 16),
-        (128, 1024, P, S, 16),
-    ]
-    if n_peers >= 12:
-        # sustained backlogs at 16+ validators accumulate rounds past the
-        # R=16 bucket before decisions drain; compiling R=32 up front keeps
-        # mid-run compiles (and their single-core steal) off the hot
-        # path. Small clusters never hit these shapes — skipping them
-        # keeps their prewarm cheap.
-        buckets += [
-            (128, 1024, P, S, 32),
-            (256, 1024, P, S, 32),
+    if n_peers <= _LEGACY_PEERS:
+        keys = [
+            (16, 32, P, S, 8),
+            (16, 64, P, S, 8),
+            (32, 128, P, S, 8),
+            (64, 256, P, S, 8),
+            (64, 256, P, S, 16),
+            (64, 512, P, S, 16),
+            (128, 512, P, S, 16),
+            (128, 1024, P, S, 16),
         ]
+        if n_peers >= 12:
+            # sustained backlogs at 16+ validators accumulate rounds past
+            # the R=16 bucket before decisions drain; compiling R=32 up
+            # front keeps mid-run compiles (and their single-core steal)
+            # off the hot path. Small clusters never hit these shapes —
+            # skipping them keeps their prewarm cheap.
+            keys += [
+                (128, 1024, P, S, 32),
+                (256, 1024, P, S, 32),
+            ]
+        return keys
+    keys = []
+    for k, rs in ((2, (8,)), (4, (8,)), (8, (8, 16)), (16, (16, 32))):
+        W = voting._bucket_pow2(k * n_peers, 16)
+        keys += [(W, E, P, S, R) for R in rs for E in (4 * W, 8 * W)]
+    return keys
 
-    def work() -> None:
-        if mesh is None and batcher_default_on():
+
+def prewarm_buckets(n_peers: int, background: bool = True, mesh=None,
+                    spans: Optional[Tracer] = None):
+    """Compile (or load from the persistent XLA cache) the window-shape
+    buckets a freshly started node is most likely to hit (prewarm_keys),
+    so the first real backlog meets warm kernels instead of a compile
+    wait. Called from Node.init when --accelerator is on; runs in a daemon
+    thread by default (compiles happen in XLA's C++ with the GIL
+    released). With a mesh, the SHARDED kernels are warmed too (separate
+    jit cache). The work is the span ``prewarm`` of ``spans`` (the node's
+    tracer) on the thread that does it; ``accel_prewarm_programs`` /
+    ``accel_prewarm_seconds`` in stats() count it process-wide."""
+    from babble_tpu.ops import voting
+
+    P = voting._bucket_mult(n_peers, 8)
+    buckets = prewarm_keys(n_peers)
+    tracer = spans if spans is not None else Tracer()
+
+    def warm() -> None:
+        global prewarm_programs, prewarm_seconds
+        t0 = time.perf_counter()
+        done = 0
+        try:
+            with tracer.span("prewarm"):
+                done = work()
+        finally:
+            with _prewarm_lock:
+                prewarm_programs += done
+                prewarm_seconds += time.perf_counter() - t0
+
+    def work() -> int:
+        """Returns the programs it compiled or loaded."""
+        done = 0
+        if (mesh is None and batcher_default_on()
+                and n_peers <= _LEGACY_PEERS):
             # Seed the co-located batcher: compile the B=MAX_BATCH floor
             # bucket and pin it as the batcher's target floor, so the
             # FIRST flush wave meets a warm batched program instead of a
             # compile kick (the monotone target then stays inside this
-            # shape until windows genuinely outgrow it).
+            # shape until windows genuinely outgrow it). No floor was
+            # measured for co-located nodes of a larger ring: there the
+            # first wave sets the batcher's target.
             from babble_tpu.hashgraph.sweep_batcher import SweepBatcher
 
             floor = (
                 (128, 1024, P, 1, 32) if n_peers >= 12 else (64, 512, P, 1, 16)
             )
+
             svc = SweepBatcher.instance()
             if svc.floor_key is None or tuple(
                 max(a, b) for a, b in zip(svc.floor_key, floor)
@@ -1278,6 +1344,7 @@ def prewarm_buckets(n_peers: int, background: bool = True, mesh=None):
                 try:
                     voting.precompile_batched(SweepBatcher.MAX_BATCH, *floor)
                     svc.floor_key = floor
+                    done += 1
                 except Exception:
                     logger.warning(
                         "batched floor prewarm failed for %s", floor,
@@ -1300,6 +1367,7 @@ def prewarm_buckets(n_peers: int, background: bool = True, mesh=None):
                 if not voting_shard.bucket_ready(mesh, key):
                     try:
                         voting_shard.precompile(mesh, *key)
+                        done += 1
                     except Exception:
                         logger.warning(
                             "mesh prewarm failed for %s", key, exc_info=True
@@ -1310,6 +1378,7 @@ def prewarm_buckets(n_peers: int, background: bool = True, mesh=None):
                     if not voting_shard.resident_bucket_ready(mesh, key):
                         try:
                             voting_shard.precompile_resident(mesh, *key)
+                            done += 1
                         except Exception:
                             logger.warning(
                                 "mesh resident prewarm failed for %s", key,
@@ -1318,6 +1387,7 @@ def prewarm_buckets(n_peers: int, background: bool = True, mesh=None):
             elif not voting.bucket_ready(key):
                 try:
                     voting.precompile(*key)
+                    done += 1
                 except Exception:
                     logger.warning(
                         "prewarm failed for %s", key, exc_info=True
@@ -1334,15 +1404,17 @@ def prewarm_buckets(n_peers: int, background: bool = True, mesh=None):
                 if not ws.resident_ready(key):
                     try:
                         ws.precompile_resident(*key)
+                        done += 1
                     except Exception:
                         logger.warning(
                             "resident prewarm failed for %s", key,
                             exc_info=True,
                         )
+        return done
 
     if background:
-        t = threading.Thread(target=work, daemon=True, name="voting-prewarm")
+        t = threading.Thread(target=warm, daemon=True, name="voting-prewarm")
         t.start()
         return t
-    work()
+    warm()
     return None

@@ -346,11 +346,11 @@ class Node(StateManager):
                 # otherwise contend with the measured gossip).
                 from babble_tpu.hashgraph.accel import prewarm_buckets
 
+                accel = self.core.hg.accel
                 self._prewarm_thread = prewarm_buckets(
                     len(self.core.peers.peers),
-                    mesh=self.core.hg.accel.mesh
-                    if self.core.hg.accel is not None
-                    else None,
+                    mesh=accel.mesh if accel is not None else None,
+                    spans=accel.spans if accel is not None else None,
                 )
                 if (
                     os.environ.get("BABBLE_PREWARM_BLOCK") == "1"

@@ -47,8 +47,8 @@ CATALOG: Tuple[Instrument, ...] = (
         "sync, prepare_sync, flush, record_heads, membership, "
         "creator_stall, peer_set_wait, store_write, store_encode, "
         "bootstrap, bootstrap_load, fast_forward, ff_poll, ff_restore, "
-        "ff_check, ff_reset, verify_fallback, eager_sync_in. Inclusive: "
-        "a span's whole duration, its children's included.",
+        "ff_check, ff_reset, verify_fallback, eager_sync_in, prewarm. "
+        "Inclusive: a span's whole duration, its children's included.",
     ),
     Instrument(
         "sync_stage_self_seconds", _H, ("stage",), "node",
@@ -63,11 +63,10 @@ CATALOG: Tuple[Instrument, ...] = (
         "only: sync, prepare_sync, decode, batch_verify, flush, commit, "
         "self_event, creator_stall, peer_set_wait, bootstrap, "
         "bootstrap_load, fast_forward, ff_poll, ff_restore, ff_check, "
-        "ff_reset, verify_fallback, eager_sync_in and the accel spans "
-        "build, snapshot (delta_scan + "
-        "pack), dispatch, readback, apply. Wall minus CPU is time the "
-        "thread did not run: GIL, sleep, device wait. Empty on a "
-        "simulated clock.",
+        "ff_reset, verify_fallback, eager_sync_in, prewarm and the accel "
+        "spans build, snapshot (delta_scan + pack), dispatch, readback, "
+        "apply. Wall minus CPU is time the thread did not run: GIL, "
+        "sleep, device wait. Empty on a simulated clock.",
     ),
     Instrument(
         "core_lock_wait_seconds", _H, (), "node",
@@ -660,7 +659,7 @@ SYNC_STAGES = (
     "membership", "creator_stall", "peer_set_wait",
     "store_write", "store_encode", "bootstrap", "bootstrap_load",
     "fast_forward", "ff_poll", "ff_restore", "ff_check", "ff_reset",
-    "verify_fallback", "eager_sync_in",
+    "verify_fallback", "eager_sync_in", "prewarm",
 )
 # COARSE spans open at most a few times per sync: obs/trace.py also
 # reads the thread CPU clock and writes a profiler annotation for them.
@@ -673,7 +672,7 @@ COARSE_STAGES = (
     "self_event", "creator_stall", "peer_set_wait",
     "bootstrap", "bootstrap_load",
     "fast_forward", "ff_poll", "ff_restore", "ff_check", "ff_reset",
-    "verify_fallback", "eager_sync_in",
+    "verify_fallback", "eager_sync_in", "prewarm",
     "build", "snapshot", "dispatch", "readback", "apply",
 )
 TX_STAGES = ("mempool_wait", "consensus")

@@ -377,3 +377,63 @@ def test_drain_that_meets_a_compile_wait_ends_decided(monkeypatch, outcome,
         assert voting.bucket_ready(None)
     else:
         assert not voting.bucket_ready(None)
+
+
+def test_prewarm_is_a_span_and_counts_what_it_compiled(monkeypatch):
+    """``prewarm_buckets``' work is the coarse span ``prewarm`` of the
+    tracer it is given, and ``accel_prewarm_programs`` /
+    ``accel_prewarm_seconds`` count it: a program already ready is not
+    counted again, the span opens all the same."""
+    from babble_tpu.hashgraph import accel as accel_mod
+    from babble_tpu.obs.trace import Tracer
+    from babble_tpu.ops import voting
+
+    key = (16, 32, 8, 1, 8)
+    monkeypatch.setenv("BABBLE_ACCEL_BATCH", "0")
+    monkeypatch.setenv("BABBLE_ACCEL_RESIDENT", "0")
+    monkeypatch.setattr(accel_mod, "prewarm_keys", lambda n: [key])
+    stages, cpu = [], []
+    tracer = Tracer(stage_sink=lambda s, sec: stages.append((s, sec)),
+                    cpu_sink=lambda s, sec: cpu.append(s))
+    before = TensorConsensus().stats()
+    ready = voting.bucket_ready(key)
+    assert accel_mod.prewarm_buckets(4, background=False,
+                                     spans=tracer) is None
+    assert voting.bucket_ready(key)
+    t = accel_mod.prewarm_buckets(4, spans=tracer)  # on its own thread
+    t.join()
+    after = TensorConsensus().stats()
+    assert [s for s, _sec in stages] == ["prewarm", "prewarm"]
+    assert cpu == ["prewarm", "prewarm"]  # a coarse span
+    assert (after["accel_prewarm_programs"]
+            - before["accel_prewarm_programs"]) == (0 if ready else 1)
+    assert (after["accel_prewarm_seconds"]
+            - before["accel_prewarm_seconds"]) == pytest.approx(
+                sum(sec for _s, sec in stages), abs=1e-3)
+
+
+@pytest.mark.parametrize("n_peers,floor", [
+    (4, (64, 512, 8, 1, 16)), (16, (128, 1024, 16, 1, 32)), (64, None),
+])
+def test_prewarm_seeds_the_batcher_floor_up_to_16_validators(
+        monkeypatch, n_peers, floor):
+    """Up to 16 validators prewarm compiles the batcher's B=MAX_BATCH
+    floor and pins it; no floor was measured for a larger ring, so there
+    the first wave sets the batcher's target."""
+    from babble_tpu.hashgraph import accel as accel_mod
+    from babble_tpu.hashgraph.sweep_batcher import SweepBatcher
+    from babble_tpu.ops import voting
+
+    monkeypatch.setenv("BABBLE_ACCEL_BATCH", "1")
+    monkeypatch.setattr(accel_mod, "prewarm_keys", lambda n: [])
+    compiled = []
+    monkeypatch.setattr(voting, "precompile_batched",
+                        lambda b, *key: compiled.append((b, key)))
+    svc = SweepBatcher.instance()
+    monkeypatch.setattr(svc, "floor_key", None)
+    accel_mod.prewarm_buckets(n_peers, background=False)
+    if floor is None:
+        assert compiled == [] and svc.floor_key is None
+    else:
+        assert compiled == [(SweepBatcher.MAX_BATCH, floor)]
+        assert svc.floor_key == floor
