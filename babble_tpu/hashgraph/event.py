@@ -309,6 +309,7 @@ class Event:
         "round",
         "lamport_timestamp",
         "round_received",
+        "witness",
         "last_ancestors",
         "first_descendants",
         "_creator",
@@ -326,6 +327,12 @@ class Event:
         self.round: Optional[int] = None
         self.lamport_timestamp: Optional[int] = None
         self.round_received: Optional[int] = None
+        # Whether the event is its round's witness, as the Hashgraph that
+        # inserted it found when it set the round (or as the Frame it came
+        # in says); None until then, and on an event reloaded from a
+        # PersistentStore row. Write-once while the event is in that
+        # Hashgraph, as the round is.
+        self.witness: Optional[bool] = None
         # Coordinates, in the column space of the Hashgraph that inserted
         # the event; None until then, and on an event reloaded from a
         # PersistentStore row (which never held them): all missing.
@@ -466,6 +473,9 @@ class Event:
 
     def set_round(self, r: int) -> None:
         self.round = r
+
+    def set_witness(self, w: bool) -> None:
+        self.witness = w
 
     def set_lamport_timestamp(self, t: int) -> None:
         self.lamport_timestamp = t

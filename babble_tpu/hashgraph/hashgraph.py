@@ -327,9 +327,12 @@ class Hashgraph:
         # not here ends its chain's walk.
         self._clear_coordinates()
         store.on_event_evicted(self._let_go)
-        # entries the walk wrote, and how often the repertoire outgrew
-        # the rows' width (rows made before stay as narrow as they were)
+        # entries the walk wrote, of them those whose event carried no
+        # witness flag (so the walk asked witness()), and how often the
+        # repertoire outgrew the rows' width (rows made before stay as
+        # narrow as they were)
         self.fd_walk_steps = 0
+        self.fd_walk_flag_misses = 0
         self.coord_row_regrows = 0
 
     def _clear_coordinates(self) -> None:
@@ -766,43 +769,51 @@ class Hashgraph:
         as first descendant, stopping at witnesses or already-filled entries
         (reference: hashgraph.go:486-519). An ancestor is reached by
         (creator column, index) in _chains, which the event joins first; a
-        step reads and writes one int of the ancestor's row. What else a
-        new first descendant changes hangs on witnesses alone — a cached
-        round matrix's entry, a resident window's witness row — so it is
-        seen to where the walk stops."""
+        step reads and writes one int of the ancestor's row, and reads the
+        witness flag the ancestor got when its round was set (Event.witness),
+        asking witness() only where it has none. What else a new first
+        descendant changes hangs on witnesses alone — a cached round
+        matrix's entry, a resident window's witness row — so it is seen to
+        where the walk stops."""
         creator = event.creator()
         col = self._coord_col[creator]
+        width = self._coord_width
         index = event.index()
         chains = self._chains
         chains[col][index] = event
-        steps = 0
+        steps = misses = 0
         for c, i in enumerate(event.last_ancestors.tolist()):
             if i < 0 or c == col:
                 continue
-            chain = chains[c]
+            at = chains[c].get
             while True:
-                a = chain.get(i)
+                a = at(i)
                 if a is None:
                     break  # the store let it go (or never had it)
                 fd = a.first_descendants
                 if col >= len(fd):
-                    fd.extend([_FD_MISSING] * (self._coord_width - len(fd)))
+                    fd.extend([_FD_MISSING] * (width - len(fd)))
                 elif fd[col] != _FD_MISSING:
                     break
                 fd[col] = index
                 steps += 1
                 # Stop at witnesses so the walk doesn't descend to the
                 # bottom of the graph (reference: hashgraph.go:503-512).
-                try:
-                    stop = self.witness(a.hex())
-                except StoreError:
-                    stop = None  # not known: no stop, but it may have a row
+                stop = a.witness
+                if stop is None:
+                    misses += 1
+                    try:
+                        stop = self.witness(a.hex())
+                    except StoreError:
+                        # not known: no stop, but it may have a row
+                        stop = None
                 if stop is not False:
                     self._witness_gained_descendant(a, creator, index)
                     if stop:
                         break
                 i -= 1
         self.fd_walk_steps += steps
+        self.fd_walk_flag_misses += misses
 
     def _witness_gained_descendant(self, a: Event, creator: str,
                                    index: int) -> None:
@@ -992,6 +1003,9 @@ class Hashgraph:
 
         event.topological_index = self.topological_index
         self.topological_index += 1
+        # the witness flag is set where this hashgraph sets the round; one
+        # the event carries from elsewhere is not this hashgraph's
+        event.witness = None
 
         if set_wire_info:
             self.set_wire_info(event)
@@ -1022,6 +1036,7 @@ class Hashgraph:
         self._timestamp_cache.add(event.hex(), frame_event.lamport_timestamp)
 
         event.set_round(frame_event.round)
+        event.set_witness(frame_event.witness)
         event.set_lamport_timestamp(frame_event.lamport_timestamp)
 
         try:
@@ -1109,6 +1124,7 @@ class Hashgraph:
                     fresh_round = True
             is_witness = self.witness(hash_)
             ev.set_round(round_number)
+            ev.set_witness(is_witness)
             update_event = True
 
             if (

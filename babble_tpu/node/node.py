@@ -685,11 +685,14 @@ class Node(StateManager):
                 "peer_set_waits": self.core.hg.peer_set_waits,
                 # DivideRounds' per-round witness matrices: entries and
                 # rows written in place, and matrices built at lookup;
-                # entries the first-descendant walk wrote, and how often
-                # the repertoire outgrew the coordinate rows' width
+                # entries the first-descendant walk wrote (of them, those
+                # whose event carried no witness flag, so the walk asked
+                # the witness cache), and how often the repertoire outgrew
+                # the coordinate rows' width
                 "round_ctx_patches": self.core.hg.round_ctx_patches,
                 "round_ctx_rebuilds": self.core.hg.round_ctx_rebuilds,
                 "fd_walk_steps": self.core.hg.fd_walk_steps,
+                "fd_walk_flag_misses": self.core.hg.fd_walk_flag_misses,
                 "coord_row_regrows": self.core.hg.coord_row_regrows,
                 # the durable store (0 with an InmemStore): SQLite
                 # transactions its writes committed (of them: event rows

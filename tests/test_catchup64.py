@@ -59,6 +59,8 @@ def test_the_validator_equals_the_reference_at_64(caught_up):
     assert audit.missing_events == 0
     assert audit.blocks >= 1 and audit.differing_blocks == 0, audit.note
     assert core.get_consensus_events_count() == audit.ordered > 0
+    # the first-descendant walk found every ancestor's witness flag
+    assert core.hg.fd_walk_steps > 0 and core.hg.fd_walk_flag_misses == 0
 
 
 def test_the_prewarm_list_covers_a_64_ring_and_keeps_the_16_list(caught_up):

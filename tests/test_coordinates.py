@@ -18,6 +18,12 @@ laggard that mints witnesses into old rounds, joins and leaves at-round and
 eager, a hashgraph that prunes, ``reset`` + ``insert_frame_event``), a
 churn script that crosses a row's capacity, a bounded store, and a
 ``PersistentStore`` reopened mid-stream.
+
+The walk stops at a witness by the flag the ancestor got with its round
+(``Event.witness``); four DAGs run twice, the second time with no flag
+ever set, so that every step asks ``Hashgraph.witness``, and end the same.
+A flag once set is what ``witness`` says, after a bootstrap replay and a
+``reset`` too.
 """
 
 from __future__ import annotations
@@ -25,8 +31,9 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from babble_tpu.hashgraph import Hashgraph, InmemStore
+from babble_tpu.hashgraph import Event, Hashgraph, InmemStore
 from babble_tpu.hashgraph.event import EventCoordinates, FrameEvent
+from babble_tpu.hashgraph.frame import Frame
 from babble_tpu.hashgraph.hashgraph import _FD_MISSING, _LA_MISSING
 from babble_tpu.hashgraph.persistent_store import PersistentStore
 from babble_tpu.peers.peer_set import PeerSet
@@ -489,6 +496,175 @@ def test_a_cache_shorter_than_the_window_orders_as_one_that_holds_it_all(
                 hg.read_wire_info(we), set_wire_info=True)
     small.store.close()
     assert _state(small)["blocks"] == _state(whole)["blocks"]
+
+
+# -- the walk's stop test: the flag an event carries, or witness() ------------
+
+def _fed(hg, wires, sweep_every: int = 1) -> None:
+    """The host path's inserts, the voting pass after every
+    ``sweep_every``-th of them and once at the end."""
+    for k, we in enumerate(wires, 1):
+        hg.insert_event(hg.read_wire_info(we), set_wire_info=True)
+        hg.divide_rounds()
+        if k % sweep_every == 0:
+            hg.run_consensus_sweep()
+    hg.run_consensus_sweep()
+
+
+def _sixteen(gossip16):
+    _keys, peers, wires = gossip16
+
+    def feed():
+        hg = _fresh(peers)
+        _fed(hg, wires, 4)
+        return hg, 0
+    return feed
+
+
+def _sixty_four(_gossip16):
+    """``tests/test_catchup64.py``'s backlog: 63 of 64 creating, 3,000
+    events."""
+    keys, peers = _ring(64, SEED)
+    wires = data.backlog_wire_events(
+        keys, peers, list(range(1, 64)), 3000, DAG_SEED, 100)
+
+    def feed():
+        hg = _fresh(peers)
+        _fed(hg, wires, 100)
+        return hg, 0
+    return feed
+
+
+def _a_joiner(_gossip16):
+    """``churn16``'s generator: a join, a leave, a join, the coordinate
+    width growing from round to round."""
+    genesis, wires = _churn_backlog(eager=False)
+
+    def feed():
+        hg, _plus_six = churn.sequential_hashgraph(genesis, len(wires))
+        for we in wires:
+            hg.insert_event_and_run_consensus(
+                hg.read_wire_info(we), set_wire_info=False)
+        assert len(hg._coord_keys) == 6
+        return hg, 0
+    return feed
+
+
+def _a_landing(gossip16):
+    """A fast-forward: ``reset`` onto a Frame (over the wire), then the
+    events past it."""
+    _keys, peers, wires = gossip16
+    h = _fresh(peers)
+    _fed(h, wires[:900])
+    block = h.store.get_block(h.store.last_block_index() // 2)
+    text = h.get_frame(block.round_received()).to_dict()
+    probe = Hashgraph(InmemStore(100000))
+    probe.reset(block, Frame.from_dict(text))
+    rest = []
+    for id_, ct in probe.store.known_events().items():
+        pk = peers.by_id[id_].pub_key_hex
+        rest += [h.store.get_event(x)
+                 for x in h.store.participant_events(pk, ct)]
+    rest = [e.to_wire() for e in sorted(rest, key=lambda e: e.topological_index)]
+
+    def feed():
+        hg = Hashgraph(InmemStore(100000))
+        hg.reset(block, Frame.from_dict(text))
+        _fed(hg, rest)
+        return hg, block.index()
+    return feed
+
+
+def _walk_state(hg: Hashgraph, first_block: int) -> dict:
+    return {
+        "fd": {e.hex(): list(e.first_descendants) for e in held_events(hg)},
+        "fd_walk_steps": hg.fd_walk_steps,
+        "round_ctx_patches": hg.round_ctx_patches,
+        "blocks": [hg.store.get_block(i).to_dict()
+                   for i in range(first_block, hg.store.last_block_index() + 1)],
+    }
+
+
+@pytest.mark.parametrize("dag", [_sixteen, _sixty_four, _a_joiner, _a_landing],
+                         ids=["16-creators", "64-creators", "a-joiner",
+                              "a-landing"])
+def test_the_walk_stops_where_witness_would_stop_it(dag, gossip16, monkeypatch):
+    """The same DAG twice: as shipped, where a step reads the witness flag
+    the ancestor got with its round, and with no flag ever set, so that
+    every step asks ``witness()``. Every row, the walk's counts and the
+    blocks are the same."""
+    feed = dag(gossip16)
+    shipped, first_block = feed()
+    with monkeypatch.context() as m:
+        m.setattr(Event, "set_witness", lambda self, w: None)
+        asked, _ = feed()
+    assert asked.fd_walk_flag_misses == asked.fd_walk_steps > 0
+    state = _walk_state(shipped, first_block)
+    assert state == _walk_state(asked, first_block)
+    assert len(state["blocks"]) > 1 and state["fd_walk_steps"] > 0
+    # every ancestor the walk reaches was divided (or came with a Frame's
+    # verdict) before: no step of the shipped walk lacks a flag
+    assert shipped.fd_walk_flag_misses == 0
+
+
+def _flags_sound(hg: Hashgraph) -> int:
+    """Every flag set equals witness() and the round's record. Returns how
+    many were set."""
+    n = 0
+    for ev in held_events(hg):
+        if ev.witness is not None:
+            h = ev.hex()
+            assert ev.witness is hg.witness(h), h
+            assert ev.witness is hg.store.get_round(
+                ev.round).created_events[h].witness, h
+            n += 1
+    return n
+
+
+def test_a_flag_once_set_is_what_witness_says(tmp_path, gossip16):
+    """After an ingest, a bootstrap replay of a ``PersistentStore``, and a
+    ``reset`` onto the hashgraph's own Frame, whose events are the very
+    objects it held, and onto one over the wire."""
+    _keys, peers, wires = gossip16
+    path = str(tmp_path / "babble.db")
+    hg = Hashgraph(PersistentStore(100000, path))
+    hg.init(peers)
+    _fed(hg, wires[:700])
+    assert _flags_sound(hg) == len(held_events(hg)) == 700
+    block = hg.store.get_block(hg.store.last_block_index() // 2)
+    text = hg.get_frame(block.round_received()).to_dict()
+    hg.store.close()
+
+    replayed = Hashgraph(PersistentStore(100000, path))
+    replayed.init(peers)
+    replayed.bootstrap()
+    assert replayed.bootstrap_events_replayed == 700
+    assert _flags_sound(replayed) == len(held_events(replayed))
+    _fed(replayed, wires[700:900])
+    assert _flags_sound(replayed) == len(held_events(replayed))
+    replayed.store.close()
+
+    own = _fresh(peers)
+    _fed(own, wires[:700])
+    old = sorted(held_events(own), key=lambda e: e.topological_index)
+    own.reset(block, own.get_frame(block.round_received()))
+    assert _flags_sound(own) == len(held_events(own)) > 0
+    # the events past the Frame, the same objects again: each comes back
+    # with the round it had, so divide_rounds sets it no flag, and the
+    # walk asks witness() where it reaches one
+    known = own.store.known_events()
+    again = [e for e in old
+             if e.index() > known[peers.by_pub_key[e.creator()].id]]
+    for ev in again:
+        own.insert_event_and_run_consensus(ev)
+    assert len(again) > 300 and all(ev.witness is None for ev in again)
+    assert own.fd_walk_flag_misses > 0
+    assert _flags_sound(own) == len(held_events(own)) - len(again)
+    landed = Hashgraph(InmemStore(100000))
+    landed.reset(block, Frame.from_dict(text))
+    assert _flags_sound(landed) == len(held_events(landed))
+    # frame events keep the frame's verdict: witnesses among them
+    assert any(ev.witness for ev in held_events(landed))
 
 
 def test_the_sentinels_never_compare_as_seen():

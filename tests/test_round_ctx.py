@@ -336,6 +336,8 @@ def test_the_counters_are_in_the_nodes_snapshot(gossip16):
         # the coordinate rows' counters ride beside them: the entries the
         # first-descendant walk wrote, and a ring of 16 fills rows of 16
         assert snap["fd_walk_steps"] == hg.fd_walk_steps > 5 * len(wires)
+        # every ancestor the walk reached carried its witness flag
+        assert snap["fd_walk_flag_misses"] == hg.fd_walk_flag_misses == 0
         assert snap["coord_row_regrows"] == hg.coord_row_regrows == 0
         assert "peer_set_waits" in snap
         shadow_check(hg)
